@@ -139,7 +139,7 @@ def main():
         only = os.environ.get("ATTN_ONLY", "")
         impls = (only,) if only else ("xla", "pallas")
         # xla attention materializes [B,H,S,S] f32 scores; refuse shapes
-        # that can't fit rather than crash the relay's compile helper.
+        # that can't fit in HBM.
         score_gb = LM_BATCH * 12 * LM_SEQ * LM_SEQ * 4 / 1e9
         if "xla" in impls and score_gb > 4:
             log(f"skipping xla attention: scores ~{score_gb:.0f}GB")

@@ -212,10 +212,9 @@ def lm_tp_realistic():
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh)
         if hasattr(s, "shape") else s, state, shardings,
         is_leaf=lambda l: isinstance(l, jax.ShapeDtypeStruct))
-    dmesh = fsdp_lib.auto_mesh(mesh)
     ids = jax.ShapeDtypeStruct(
         (8, 2048), jnp.int32,
-        sharding=NamedSharding(dmesh, mesh_lib.batch_spec()))
+        sharding=NamedSharding(mesh, mesh_lib.batch_spec()))
     step = step_lib.make_train_step(loss_fn, tx, mesh, donate=True,
                                     state_shardings=shardings)
     log("compiling TP LM (tp4 x data2, b=8 s=2048)...")
@@ -298,10 +297,9 @@ def lm_moe_realistic():
         lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh)
         if hasattr(s, "shape") else s, state, shardings,
         is_leaf=lambda l: isinstance(l, jax.ShapeDtypeStruct))
-    dmesh = fsdp_lib.auto_mesh(mesh)
     ids = jax.ShapeDtypeStruct(
         (8, 2048), jnp.int32,
-        sharding=NamedSharding(dmesh, mesh_lib.batch_spec()))
+        sharding=NamedSharding(mesh, mesh_lib.batch_spec()))
     step = step_lib.make_train_step(loss_fn, tx, mesh, donate=True,
                                     state_shardings=shardings)
     log("compiling MoE LM (ep4 x data2, 8 experts, b=8 s=2048)...")

@@ -149,11 +149,6 @@ def main():
     sweeps()
     offline_ab_rows()
     json_files()
-    census = os.path.join(RES, "hlo_dump.err")
-    if os.path.exists(census) and os.path.getsize(census):
-        print("\n### hlo_dump (byte census) log tail\n```")
-        print("\n".join(open(census).read().strip().splitlines()[-30:]))
-        print("```")
 
 
 if __name__ == "__main__":

@@ -1,0 +1,107 @@
+"""Compile-only guards for the chip — no chip needed.
+
+The TPU's compiler is installed beside jax and compiles for a chip that is
+described, not attached (``jax.experimental.topologies``).  These cases run
+the whole compiler — Mosaic kernel codegen included — on the kernels of the
+main path at the widths ``chip_smoke.py`` runs them, about two seconds
+each, in this process.  What the compiler refuses here it refuses on the
+chip, and costs no chip time.  Nothing executes: results and times still
+need ``chip_smoke.py`` on a chip.
+
+The slower whole-program compiles (ResNet-50 dp4 step, the sweeps' CLI)
+stay in ``tests/test_aot_tpu_compile.py`` behind ``slow``.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_topology):
+    """One described v5e device (of a 2x2), as a sharding for shapes."""
+    return SingleDeviceSharding(v5e_topology.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def chip_target(monkeypatch, no_persistent_compile_cache):
+    """Compile as the chip would: its generation named (the process sees a
+    CPU) and the tuning DB left out."""
+    monkeypatch.setenv("TPUFRAME_TUNE_GEN", "v5e")
+    monkeypatch.setenv("TPUFRAME_TUNE_DB", "off")
+
+
+def _compile_grad(loss, *args):
+    c = jax.jit(jax.grad(loss, argnums=tuple(range(len(args))))).lower(
+        *args).compile()
+    assert "tpu_custom_call" in c.as_text(), "no Mosaic kernel in the program"
+    return c
+
+
+@pytest.mark.parametrize("shape", [
+    (8, 2048, 12, 64),   # the 124M LM's step at b8 x 2048 (chip_smoke)
+    (1, 8192, 12, 64),   # the 8k cell XLA attention cannot compile
+])
+@pytest.mark.parametrize("with_lse", [False, True],
+                         ids=["flash_mha", "flash_mha_lse"])
+def test_flash_fwd_bwd_compiles_for_v5e(v5e, shape, with_lse):
+    from tpuframe.ops.flash_attention import flash_mha, flash_mha_lse
+
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=v5e)
+
+    def loss(q, k, v):
+        if with_lse:  # the ring-stage variant: lse out, its cotangent in
+            out, lse = flash_mha_lse(q, k, v, causal=True, interpret=False)
+            return out.astype(jnp.float32).sum() + (lse * 0.5).sum()
+        return flash_mha(q, k, v, causal=True,
+                         interpret=False).astype(jnp.float32).sum()
+
+    c = _compile_grad(loss, q, q, q)
+    m = c.memory_analysis()
+    assert m.temp_size_in_bytes < 1 << 30  # no S x S scores in HBM
+
+
+def test_flash_row_stats_are_lane_major_for_v5e(v5e):
+    """The layout the chip takes (PERF.md §12.2): [bn, 1, s] residuals, not
+    the [bn, s, 1] one that pads 128x in HBM."""
+    from tpuframe.ops import flash_attention as fa
+
+    assert fa._lse_lane_major()
+    q = jax.ShapeDtypeStruct((8, 2048, 12, 64), jnp.bfloat16, sharding=v5e)
+    txt = jax.jit(lambda q: fa.flash_mha_lse(
+        q, q, q, causal=True, interpret=False)).lower(q).as_text()
+    assert "96x1x2048xf32" in txt and "96x2048x1xf32" not in txt
+
+
+def test_fused_conv_bn_bwd_compiles_for_v5e(v5e):
+    """The largest ResNet-50 1x1 conv at b256 that ``supported()`` admits
+    (layer1: 56x56, 256 -> 128; layer4's 1024 -> 2048 it refuses)."""
+    from tpuframe.ops import fused_conv_bn as fcb
+
+    b, h, w, k, c_out = 256, 56, 56, 256, 128
+    assert fcb.supported(h, w, b, k, c_out)
+    assert not fcb.supported(7, 7, b, 1024, 2048)
+    a = jax.ShapeDtypeStruct((b, h, w, k), jnp.bfloat16, sharding=v5e)
+    wt = jax.ShapeDtypeStruct((k, c_out), jnp.float32, sharding=v5e)
+    g = jax.ShapeDtypeStruct((c_out,), jnp.float32, sharding=v5e)
+    cfg = (1e-5, fcb.DEFAULT_BLOCK_ROWS, False)  # interpret=False -> Mosaic
+
+    def loss(a, w, gamma, beta):
+        y, _, _ = fcb.conv1x1_bn_train(cfg, a, w, gamma, beta)
+        return y.astype(jnp.float32).sum()
+
+    _compile_grad(loss, a, wt, g, g)
+
+
+def test_decode_attention_compiles_for_v5e(v5e):
+    """LMEngine's decode attention at the 124M width: 4 slots, a 512-entry
+    ring, 12 heads of 64, bf16 — XLA by design (query length 1)."""
+    from tpuframe.ops import attention as attn_ops
+
+    q = jax.ShapeDtypeStruct((4, 1, 12, 64), jnp.bfloat16, sharding=v5e)
+    kv = jax.ShapeDtypeStruct((4, 512, 12, 64), jnp.bfloat16, sharding=v5e)
+    lengths = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=v5e)
+    c = jax.jit(lambda q, k, v, n: attn_ops.decode_attention(
+        q, k, v, lengths=n, impl="pallas")).lower(q, kv, kv, lengths).compile()
+    assert "tpu_custom_call" not in c.as_text()

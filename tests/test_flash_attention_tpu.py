@@ -6,12 +6,13 @@ lane-broadcast stats) only surface on real hardware.  These tests run the
 kernel through the actual Mosaic compiler and assert numerics against the
 XLA einsum path — fwd AND bwd, causal + padding-mask variants, bf16.
 
-Run on the bench chip (the fixture skips everywhere else):
+Run on a chip through the chip tool (the fixture skips everywhere else):
 
-    TPUFRAME_TPU_TESTS=1 python -m pytest tests/test_flash_attention_tpu.py -v
+    chiprun -- env TPUFRAME_TPU_TESTS=1 python -m pytest \
+        tests/test_flash_attention_tpu.py -v -o addopts=
 
 The conftest honors TPUFRAME_TPU_TESTS=1 by not forcing the CPU backend.
-Measured numbers from this chip live in BASELINE.md (pallas-vs-xla table).
+``chip_smoke.py`` makes the same comparison at the 124M LM's own shape.
 """
 
 import jax

@@ -34,16 +34,14 @@ def _setup(mesh, use_fsdp):
     shardings = None
     if mesh is not None:
         from jax.sharding import NamedSharding
-        data_mesh = mesh
         if use_fsdp:
             shardings = fsdp_lib.state_shardings(state, mesh)
             state = jax.tree.map(jax.device_put, state, shardings)
-            data_mesh = fsdp_lib.auto_mesh(mesh)
         else:
             state = step_lib.replicate_state(state, mesh)
         batch = jax.tree.map(
             lambda x: jax.device_put(
-                x, NamedSharding(data_mesh, mesh_lib.batch_spec())), batch)
+                x, NamedSharding(mesh, mesh_lib.batch_spec())), batch)
     step = step_lib.make_train_step(loss_fn, tx, mesh, donate=False,
                                     state_shardings=shardings)
     return state, step, batch
@@ -119,10 +117,9 @@ class TestTensorParallel:
             rules = tp_lib.rules_for_model("transformer-lm")
             shardings = fsdp_lib.state_shardings(state, mesh, tp_rules=rules)
             state = jax.tree.map(jax.device_put, state, shardings)
-            dmesh = fsdp_lib.auto_mesh(mesh)
             batch = jax.tree.map(
                 lambda x: jax.device_put(
-                    x, NamedSharding(dmesh, mesh_lib.batch_spec())), batch)
+                    x, NamedSharding(mesh, mesh_lib.batch_spec())), batch)
         step = step_lib.make_train_step(loss_fn, tx, mesh, donate=False,
                                         state_shardings=shardings)
         return state, step, batch

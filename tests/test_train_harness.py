@@ -144,3 +144,10 @@ class TestEndToEnd:
         assert metrics["step"] == 4
         out = capsys.readouterr().out
         assert "[tpuframe] done" in out
+        # The run's own threads are stopped and joined before it returns
+        # (a prefetch worker caught inside device_put when the interpreter
+        # exits aborts the process after "done").
+        import threading
+        left = [t.name for t in threading.enumerate()
+                if t.name.startswith("tpuframe-")]
+        assert left == [], left

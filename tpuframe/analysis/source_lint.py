@@ -20,8 +20,8 @@ Each rule institutionalizes a defect class rounds 4-5 found by hand:
   TF104  ``pallas_call`` without an explicit ``interpret=`` decision —
          the silent-interpret failure mode: a kernel that never went
          through Mosaic presenting itself as a TPU kernel.  Every call
-         site must say how it decides (the ``_auto_interpret()``
-         pattern).
+         site must say how it decides
+         (``ops.kernel_impl.interpret_default()``).
   TF105  resilience bypass — (a) a raw GCS client call
          (``download_as_bytes``/``upload_from_string``/``list_blobs``/
          ...) anywhere outside ``data/gcs.py``: every storage op must go
@@ -856,7 +856,8 @@ def _tf_call_rules(ctx: FileContext, node, fn):
             kw.arg == "interpret" for kw in node.keywords):
         ctx.emit("TF104", node,
                  "pallas_call without interpret= — decide "
-                 "Mosaic-vs-interpret explicitly (_auto_interpret())",
+                 "Mosaic-vs-interpret explicitly "
+                 "(ops.kernel_impl.interpret_default())",
                  fn)
     if ctx.serve_scope and (
             tail in _SERVE_COMPILE_TAILS

@@ -25,10 +25,11 @@ import numpy as np
 _LOCK = threading.Lock()
 _LIB: ctypes.CDLL | None = None
 _LOAD_FAILED = False
+_LOAD_ERROR: str | None = None
 
 
 def _load() -> ctypes.CDLL | None:
-    global _LIB, _LOAD_FAILED
+    global _LIB, _LOAD_FAILED, _LOAD_ERROR
     if _LIB is not None or _LOAD_FAILED:
         return _LIB
     with _LOCK:
@@ -48,13 +49,21 @@ def _load() -> ctypes.CDLL | None:
                                       ctypes.c_uint32]
             lib.tf_crc32c.restype = ctypes.c_uint32
             _LIB = lib
-        except Exception:  # noqa: BLE001 — any failure → Python fallback
+        except Exception as e:  # noqa: BLE001 — any failure → Python fallback
             _LOAD_FAILED = True
+            _LOAD_ERROR = f"{type(e).__name__}: {e}"
     return _LIB
 
 
 def available() -> bool:
     return _load() is not None
+
+
+def load_error() -> str | None:
+    """Why the library is unavailable (build or load failure), else None —
+    the fallback is silent, so whoever must not run on it asks here."""
+    _load()
+    return _LOAD_ERROR
 
 
 def gather_rows(src: np.ndarray, indices: np.ndarray,

@@ -28,7 +28,8 @@ Event types (see ``REQUIRED_FIELDS`` for the per-type contract):
   ============== ========================================================
   run_start      run manifest: config name+hash, mesh/topology, jax
                  version, tune-DB fingerprint, TPUFRAME_XLA_OPTS,
-                 resume step
+                 resume step, device_kind and the generation MFU is
+                 priced at with its source (device|env|assumed)
   step           step index, host wall ms, loss, examples processed
   compile        a compilation observed (first-step wall, or a
                  persistent-cache hit/miss from utils/compile_cache)
@@ -53,6 +54,9 @@ Event types (see ``REQUIRED_FIELDS`` for the per-type contract):
                  from (canonical spec string, resolution source)
   elastic_resize world size changed across a relaunch boundary (n_from,
                  n_to, rescale policy + source, old/new batch and LR)
+  kernel_impl    which implementation a Pallas-backed op resolved to in
+                 this run (op, impl mosaic|interpret|xla, why) — once
+                 per (op, impl), from ops/kernel_impl.py
   run_end        final step, wall s, goodput buckets, MFU, counters,
                  peak HBM per device
   trace_start    a jax.profiler trace window opened (step, artifact path)
@@ -147,6 +151,7 @@ REQUIRED_FIELDS: dict[str, tuple[str, ...]] = {
     "fusion_threshold": ("threshold", "source"),
     "pspec": ("spec", "source"),
     "elastic_resize": ("n_from", "n_to", "policy"),
+    "kernel_impl": ("op", "impl", "why"),
     "run_end": ("final_step", "wall_s", "goodput"),
     "trace_start": ("step", "path"),
     "trace_end": ("step", "path"),

@@ -51,8 +51,6 @@ from tpuframe.tune import roofline
 BUCKETS = ("init", "compile", "productive", "input", "ckpt", "eval",
            "stall", "other")
 
-DEFAULT_GENERATION = "v5e"
-
 
 class GoodputMeter:
     """Live bucket accounting for one attempt (train.py's half).
@@ -110,7 +108,7 @@ class GoodputMeter:
 
 
 def mfu(flops_per_step: float, step_time_s: float, *,
-        generation: str = DEFAULT_GENERATION, n_devices: int = 1) -> float:
+        generation: str, n_devices: int = 1) -> float:
     """Model FLOPs Utilization of one step against the roofline peak.
 
     ``flops_per_step`` is the whole-program count (XLA ``cost_analysis``
@@ -126,8 +124,7 @@ def mfu(flops_per_step: float, step_time_s: float, *,
 
 
 def hbm_util(bytes_per_step: float, step_time_s: float, *,
-             generation: str = DEFAULT_GENERATION,
-             n_devices: int = 1) -> float:
+             generation: str, n_devices: int = 1) -> float:
     """HBM-roofline utilization ("bytes-MFU") of one step: the compiled
     program's ``cost_analysis`` bytes accessed over what the slice's HBM
     could stream in that time.  The bandwidth twin of :func:`mfu` — for
@@ -314,8 +311,10 @@ def from_events(events: list[dict], *,
                      None)
         times = step_times_ms(events)
         if start and times:
+            # Logs from before run_start carried a generation were all
+            # priced at roofline.ASSUMED_GENERATION.
             gen = (generation or start.get("generation")
-                   or DEFAULT_GENERATION)
+                   or roofline.ASSUMED_GENERATION)
             mean_s = sum(times) / len(times) / 1e3
             n_dev = int(start.get("devices", 1))
             if mfu_productive is None and start.get("flops_per_step"):

@@ -14,7 +14,6 @@ var, else ``xla``.
 from __future__ import annotations
 
 import os
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -33,16 +32,19 @@ def multihead_attention(
 ) -> jax.Array:
     impl = impl or os.environ.get("TPUFRAME_ATTN_IMPL", "xla")
     if impl == "pallas":
-        try:
-            from tpuframe.ops import flash_attention
-        except ImportError:
-            warnings.warn("pallas flash attention unavailable; using xla impl")
-            flash_attention = None
-        if (flash_attention is not None and dropout_rate == 0.0
-                and flash_attention.supported(q, k)
-                and (mask is None or mask.ndim == 2)):
+        from tpuframe.ops import flash_attention, kernel_impl
+
+        if dropout_rate != 0.0:
+            why = "dropout"
+        elif not flash_attention.supported(q, k):
+            why = f"shapes q={q.shape} k={k.shape} do not tile"
+        elif mask is not None and mask.ndim != 2:
+            why = "mask is not a [B, S] key mask"
+        else:
             return flash_attention.flash_mha(q, k, v, mask=mask, causal=causal)
-        impl = "xla"  # dropout / unsupported shapes / missing kernel fall back
+        # The XLA composition stands in; said once, never silently.
+        kernel_impl.record("flash_attention", "xla", why)
+        impl = "xla"
     if impl != "xla":
         raise ValueError(f"unknown attention impl {impl!r}")
     if causal:

@@ -34,7 +34,7 @@ LAYOUT CONTRACT (the round-5 lesson, measured): XLA:TPU lays ResNet
 conv activations out as ``{3,0,2,1}`` — physically C on the 128 lanes,
 N on the 8 sublanes, spatial dims major.  A naive ``reshape(N*H*W, C)``
 before a pallas call demands a different physical order, and the
-relayout copies it forces cost MORE than the fusion saves (measured
+re-layout copies it forces cost MORE than the fusion saves (measured
 136.3 vs 81.4 GB at b=256 for the first cut of this kernel).  So:
 
   * the FORWARD is a plain ``lax.conv_general_dilated`` + folded BN —
@@ -88,24 +88,13 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpuframe.ops import kernel_impl
+
 # Row budget per grid step (spatial-tile x batch-tile rows): 2048 rows of
 # up-to-2048-wide bf16 activations keeps the worst ResNet-50 1x1 shape
 # near ~10 MB of VMEM including the f32 dW accumulator (see _pick_tiles).
 DEFAULT_BLOCK_ROWS = 2048
 _VMEM_BUDGET = 10 * 1024 * 1024
-
-
-def _auto_interpret() -> bool:
-    import os
-
-    # TPUFRAME_PALLAS_INTERPRET overrides the backend check: the offline
-    # AOT census compiles FOR a TPU topology FROM a CPU host, where the
-    # backend heuristic would silently swap Mosaic kernels for
-    # interpreter while-loops (perf/_common.ensure_cpu_backend sets 0).
-    env = os.environ.get("TPUFRAME_PALLAS_INTERPRET")
-    if env is not None:
-        return env == "1"
-    return jax.default_backend() != "tpu"
 
 
 def supported(h: int, w: int, n: int, k: int, c: int,
@@ -153,7 +142,7 @@ def _bwd_kernel(a_ref, w_ref, x_ref, dy_ref, coef_ref,
     """Grid is (H, N/tn), sequential (dW carries).  coef rows: 0=s, 1=u,
     2=c (f32).  Blocks are [1, W, tn, channels] — one spatial row of the
     [H, W, N, C] view per step; the collapse to [W*tn, channels] rows is
-    a sublane-group stack, not a relayout.  g = s*dy - u*x + c is
+    a sublane-group stack, not a re-layout.  g = s*dy - u*x + c is
     computed in f32 in VMEM, used by both dots, and never written back;
     dW accumulates in f32 scratch and is emitted once at the last step.
     """
@@ -417,16 +406,22 @@ class FusedConvBN(nn.Module):
             bb = bias.astype(jnp.float32) - mean * aa
             y = xx * aa.astype(self.dtype) + bb.astype(self.dtype)
         else:
-            interpret = (_auto_interpret() if self.interpret is None
-                         else self.interpret)
-            if supported(h, w_sp, b, k_in, self.features,
-                         self.block_rows) and not self.is_initializing():
+            fits = supported(h, w_sp, b, k_in, self.features,
+                             self.block_rows)
+            if fits and not self.is_initializing():
+                interpret = kernel_impl.resolve_interpret(
+                    "fused_conv_bn", self.interpret)
                 cfg = (float(self.epsilon), int(self.block_rows),
                        bool(interpret))
                 y, mean, var = conv1x1_bn_train(cfg, x, w2d, scale, bias)
             else:
                 # Shape outside the kernel's tiling (or init pass): the
                 # reference composition, identical numerics.
+                if not fits:
+                    kernel_impl.record(
+                        "fused_conv_bn", "xla",
+                        f"shape {(b, h, w_sp, k_in, self.features)} "
+                        f"outside the kernel's tiling")
                 y, mean, var = conv1x1_bn_reference(
                     x, w2d, scale, bias, eps=self.epsilon)
             if not self.is_initializing():

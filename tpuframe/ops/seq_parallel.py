@@ -140,6 +140,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     impl = impl or os.environ.get("TPUFRAME_ATTN_IMPL", "xla")
     if impl == "pallas":
         from tpuframe.ops import flash_attention as fa
+        from tpuframe.ops import kernel_impl
 
         # Interpreter guard: the pallas HLO interpreter's internal
         # slicing trips shard_map's vma check (see the CPU tests'
@@ -150,11 +151,16 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         # flash path.  TPUFRAME_RING_FLASH_INTERPRET=1 forces the flash
         # stages under the interpreter (the kernel tests do, with
         # check_vma=False shard_maps).
-        interpreting = fa._auto_interpret()
+        interpreting, why = kernel_impl.interpret_default()
         forced = os.environ.get("TPUFRAME_RING_FLASH_INTERPRET") == "1"
         if fa.supported(q, k) and (mask is None or mask.ndim == 2) \
                 and (not interpreting or forced):
             return _ring_flash(q, k, v, axis=axis, mask=mask, causal=causal)
+        kernel_impl.record(
+            "ring_attention", "xla",
+            f"flash stages would interpret ({why})" if interpreting
+            else f"shapes q={q.shape} k={k.shape} / mask do not fit the "
+                 f"flash stages")
         impl = "xla"
     elif impl != "xla":
         raise ValueError(f"unknown ring attention impl {impl!r}")

@@ -35,24 +35,6 @@ AxisName = str | Sequence[str]
 PyTree = Any
 
 
-if not hasattr(lax, "axis_size"):
-    # jax < 0.4.38 never shipped ``lax.axis_size``.  ``psum`` of the literal
-    # ``1`` over an axis is the classic static-size idiom: it folds to a plain
-    # ``int`` at trace time and raises the same ``NameError`` on unbound names
-    # that the modern API does, so ``_bound_axes``'s probe keeps working.
-    # Installed on ``lax`` once so every caller in this package (fusion,
-    # seq_parallel, pp, zero1) resolves the same way on legacy jax.
-    def _legacy_axis_size(axis_name: AxisName) -> int:
-        if isinstance(axis_name, (tuple, list)):
-            n = 1
-            for a in axis_name:
-                n *= _legacy_axis_size(a)
-            return n
-        return lax.psum(1, axis_name)
-
-    lax.axis_size = _legacy_axis_size
-
-
 def _bound_axes(axis: AxisName) -> tuple[str, ...]:
     """The subset of ``axis`` names bound by an enclosing shard_map/pmap trace.
 
@@ -172,19 +154,9 @@ def allgather(x: jax.Array, axis: AxisName = "data", *, tiled: bool = True) -> j
     return lax.all_gather(x, bound, axis=0, tiled=tiled)
 
 
-# jax >= 0.6 vma machinery (mirrors zero1._HAS_VMA): all_gather_invariant
-# exists and can mark a gather's result replication-invariant.
-_HAS_VMA = hasattr(jax, "typeof") and hasattr(lax, "pcast")
-
-
 def _leaf_vma(g, names):
-    """The axes ``g`` is varying over, for the gradient-reduce routing.
-    On the pre-vma legacy shard_map (check_rep=False) nothing tracks
-    replication, and every leaf arrives local — i.e. varying over every
-    bound axis — so the compat answer is ``names`` itself."""
-    if _HAS_VMA:
-        return jax.typeof(g).vma
-    return frozenset(names)
+    """The axes ``g`` is varying over, for the gradient-reduce routing."""
+    return jax.typeof(g).vma
 
 
 def allgather_invariant(x: jax.Array, axis: AxisName = "data", *,
@@ -193,13 +165,14 @@ def allgather_invariant(x: jax.Array, axis: AxisName = "data", *,
     this jax can express it: every replica gathers the identical full
     array, so the output is legal under a replicated out_spec (the zero1
     param regather and the quantwire int8 gather both rely on this).
-    Falls back to a plain ``lax.all_gather`` on legacy jax, where
-    check_rep=False tracks nothing anyway.  Unmapped: identity."""
+    jax 0.9.0 has no ``lax.all_gather_invariant``, so there the result is
+    a plain (varying) ``lax.all_gather`` — ROADMAP D6.  Unmapped:
+    identity."""
     bound = _bound_axes(axis)
     if not bound:
         return x
     gather = getattr(lax, "all_gather_invariant", None)
-    if gather is not None and _HAS_VMA:
+    if gather is not None:
         return gather(x, bound, axis=gather_axis, tiled=tiled)
     return lax.all_gather(x, bound, axis=gather_axis, tiled=tiled)
 

@@ -23,31 +23,9 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax.sharding import AxisType
-except ImportError:  # older jax: no sharding-in-types; all axes are Auto
-    AxisType = None
-
 PyTree = Any
 
 MIN_SHARD_ELEMENTS = 1024  # below this, sharding overhead beats the savings
-
-
-def auto_mesh(mesh: Mesh) -> Mesh:
-    """An Auto-axis-typed twin of ``mesh``.
-
-    ``jax.make_mesh`` yields Explicit axes (sharding-in-types), under which
-    auto-SPMD propagation refuses ambiguous ops (e.g. embedding gathers from
-    an fsdp-sharded table).  The FSDP path wants classic GSPMD propagation,
-    so its shardings are built on an Auto twin of the same device layout."""
-    if AxisType is None or not hasattr(mesh, "axis_types"):
-        return mesh  # pre-AxisType jax: every mesh already propagates Auto
-    if all(t == AxisType.Auto for t in mesh.axis_types):
-        return mesh
-    # Axis-type-only rewrap of an existing seam-built mesh: devices and
-    # axis names pass through unchanged.
-    return Mesh(mesh.devices, mesh.axis_names,  # tf-lint: ok[TF119]
-                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 def choose_spec(shape: tuple[int, ...], fsdp_size: int,
@@ -75,7 +53,6 @@ def state_shardings(state: PyTree, mesh: Mesh, axis: str = "fsdp",
     """
     size = mesh.shape[axis]
     axis_sizes = dict(mesh.shape) if tp_rules else None
-    amesh = auto_mesh(mesh)
     flat, treedef = jax.tree_util.tree_flatten_with_path(state)
 
     def path_str(path) -> str:
@@ -99,7 +76,7 @@ def state_shardings(state: PyTree, mesh: Mesh, axis: str = "fsdp",
             base = tp_lib.match_spec(path_str(path), shape, axis_sizes,
                                      tp_rules)
         spec = _add_fsdp(shape, base, size, axis)
-        out.append(NamedSharding(amesh, spec))
+        out.append(NamedSharding(mesh, spec))
     return jax.tree_util.tree_unflatten(treedef, out)
 
 

@@ -16,7 +16,7 @@ from dataclasses import dataclass
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # Canonical axis order. Data-parallel outermost so its collectives ride the
 # slowest-varying physical dimension (and DCN when a mesh spans slices);
@@ -97,6 +97,13 @@ def make_mesh(
     reference's (only) topology, SURVEY.md §3c.  ``jax.make_mesh`` internally
     reorders devices to match the physical ICI torus when running on real TPU
     slices, so collectives over the trailing axes map to neighbor links.
+
+    Every axis of every mesh is ``AxisType.Auto``.  The step programs here
+    are either ``shard_map`` bodies (axes Manual inside) or ``jit`` programs
+    whose collectives GSPMD propagation inserts from the in/out shardings;
+    none is written for sharding-in-types.  ``jax.make_mesh``'s own default
+    is Explicit, under which the same programs raise ``ShardingTypeError``
+    on the first ambiguous op, so the type is stated, not inherited.
     """
     spec = spec or MeshSpec()
     all_devices = jax.devices()
@@ -104,12 +111,13 @@ def make_mesh(
     sizes = spec.sizes(len(devices))
     axes = spec.axis_names()
     shape = tuple(sizes[a] for a in axes)
+    auto = (AxisType.Auto,) * len(axes)
     if [d.id for d in devices] == [d.id for d in all_devices]:
         # Full-device meshes go through jax.make_mesh, which reorders devices
         # to match the physical ICI torus on real TPU slices.
-        return jax.make_mesh(shape, axes, devices=devices)
+        return jax.make_mesh(shape, axes, devices=devices, axis_types=auto)
     dev_array = np.asarray(devices).reshape(shape)
-    return Mesh(dev_array, axes)
+    return Mesh(dev_array, axes, axis_types=auto)
 
 
 def best_effort_mesh(max_devices: int | None = None) -> Mesh:

@@ -30,22 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpuframe.parallel import mesh as mesh_lib
 
-_LEGACY_SHARD_MAP = not hasattr(jax, "shard_map")
-if not _LEGACY_SHARD_MAP:
-    _shard_map = jax.shard_map
-else:  # older jax: jax.experimental.shard_map, no vma types
-    from jax.experimental.shard_map import shard_map as _shard_map_legacy
-
-    def _shard_map(f, *, mesh, in_specs, out_specs):
-        # The legacy static replication checker cannot infer through the
-        # step body (no vma types), so it is disabled — which ALSO
-        # disables the psum-transpose rewrite that the pmean-of-loss
-        # gradient path relies on.  _grad_step compensates by taking
-        # local gradients and reducing them explicitly when
-        # _LEGACY_SHARD_MAP is set (verified against the single-device
-        # step; see tests/test_analysis.py).
-        return _shard_map_legacy(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
+_shard_map = jax.shard_map
 
 PyTree = Any
 
@@ -125,8 +110,7 @@ def _grad_step(loss_fn: LossFn, tx: optax.GradientTransformation,
     # ZeRO-1 weight-update sharding consumes LOCAL grads too: the sharded
     # update's reduce-scatter IS the step's gradient reduction, so the
     # implicit pmean-of-loss transpose (which would all-reduce) must not
-    # run.  On new jax the params are pcast varying like the explicit
-    # path; on legacy shard_map local grads come free (below).
+    # run: the params are pcast varying like the explicit path.
     zero1 = bool(axes) and weight_update == "zero1"
     # A quantized wire on the plain-DP path ALSO needs LOCAL grads: the
     # per-replica gradients are what gets block-quantized before the
@@ -141,22 +125,15 @@ def _grad_step(loss_fn: LossFn, tx: optax.GradientTransformation,
     # other explicit wire pattern.  The zero1 tail runs its own
     # two-stage scatter/gather and already takes local grads.
     hier_local = bool(axes) and hier == "hier" and not zero1
-    # Legacy shard_map (check_rep=False) has no psum-transpose rewrite:
-    # differentiating the pmean-ed loss there yields LOCAL grads with no
-    # implicit reduction, so the reduction must be explicit.
-    legacy_local = bool(axes) and _LEGACY_SHARD_MAP and not explicit
+    local_grads = explicit or zero1 or wire_local or hier_local
     diff_params = state.params
-    if (explicit or zero1 or wire_local or hier_local) \
-            and not _LEGACY_SHARD_MAP:
-        # Legacy shard_map needs no pcast (and has none): check_rep=False
-        # already differentiates to LOCAL grads with no implicit psum.
+    if local_grads:
         diff_params = jax.tree.map(
             lambda p: lax.pcast(p, axes, to="varying"), state.params)
 
     def global_loss(params, model_state, batch, rng):
         loss, aux = loss_fn(params, model_state, batch, rng)
-        if (axes and not explicit and not legacy_local and not zero1
-                and not wire_local and not hier_local):
+        if axes and not local_grads:
             loss = lax.pmean(loss, axes)
         return loss, aux
 
@@ -167,8 +144,7 @@ def _grad_step(loss_fn: LossFn, tx: optax.GradientTransformation,
                              weight_update, wire_format, hier,
                              wire_format_dcn, state,
                              grads, loss, metrics, model_state,
-                             reduce_grads=(explicit or legacy_local or zero1
-                                           or wire_local or hier_local))
+                             reduce_grads=local_grads)
 
 
 def _reduce_and_apply(tx, axes, fusion_threshold, grad_reduce, weight_update,
@@ -548,8 +524,7 @@ def make_train_step(
 
     if state_shardings is not None:
         mode = "jit"  # sharded state is an auto-SPMD placement decision
-        # All shardings must live on one mesh; the fsdp tree is built on an
-        # Auto-typed twin (see tpuframe.parallel.fsdp.auto_mesh).
+        # All shardings of one program must live on one mesh: the state's.
         any_leaf = jax.tree.leaves(state_shardings)[0]
         repl = NamedSharding(any_leaf.mesh, P())
         batch_sh = NamedSharding(any_leaf.mesh, batch_part)

@@ -5,12 +5,13 @@ continuous batching and prints the summary stats (writing obs v2 events
 when ``TPUFRAME_EVENTS_DIR``/``--events-dir`` is set)::
 
     python -m tpuframe.serve --model tiny-lm --steps 100
+    python -m tpuframe.serve --model lm-124m --requests 12 --steps 2000
 
 ``--selfcheck`` is the CI/acceptance entry: golden-logits parity on
 every bucket, a full loadgen run with events, an ``obs summarize``
 subprocess proving the TTFT/TPOT/tokens-per-sec reporting path, a BERT
-single-shot classification smoke, and the persistent-cache safety
-assertion — all on CPU, no accelerator required.
+single-shot classification smoke and a fleet smoke — all on CPU, no
+accelerator required.
 """
 
 from __future__ import annotations
@@ -22,19 +23,32 @@ import sys
 import tempfile
 
 
+def model_config(model: str):
+    """``--model`` name -> ``LMConfig``: the toy, or the default 124M LM
+    (models/transformer_lm.py) in bf16 — the width the trainer's LM
+    configs run at."""
+    from tpuframe.models.transformer_lm import LMConfig
+
+    models = {"tiny-lm": LMConfig.tiny,
+              "lm-124m": lambda: LMConfig(dtype="bfloat16")}
+    if model not in models:
+        raise SystemExit(f"unknown --model {model!r} "
+                         f"(have: {', '.join(sorted(models))})")
+    return models[model]()
+
+
 def _build_engine(model: str, *, slots: int, buckets, decode_block,
                   max_context):
-    from tpuframe.models.transformer_lm import LMConfig
     from tpuframe.serve.engine import LMEngine
 
-    if model != "tiny-lm":
-        raise SystemExit(f"unknown --model {model!r} (have: tiny-lm)")
-    cfg = LMConfig.tiny()
-    return LMEngine(cfg, slots=slots, prompt_buckets=buckets,
-                    decode_block=decode_block, max_context=max_context)
+    return LMEngine(model_config(model), slots=slots,
+                    prompt_buckets=buckets, decode_block=decode_block,
+                    max_context=max_context)
 
 
-def cmd_run(args) -> int:
+def run(args) -> dict:
+    """Build the engine for ``args.model`` and drive the seeded loadgen
+    through the scheduler; returns ``run_loadgen``'s stats."""
     from tpuframe.obs import events as obs_events
     from tpuframe.serve import loadgen
 
@@ -46,7 +60,7 @@ def cmd_run(args) -> int:
           f"(slots={args.slots}) ...", flush=True)
     engine = _build_engine(args.model, slots=args.slots, buckets=None,
                            decode_block=None, max_context=None)
-    n_requests = max(1, args.steps // 4)
+    n_requests = args.requests or max(1, args.steps // 4)
     reqs = loadgen.synthetic_requests(
         n_requests, buckets=engine.prompt_buckets,
         vocab_size=engine.cfg.vocab_size, seed=args.seed,
@@ -60,14 +74,16 @@ def cmd_run(args) -> int:
         print(f"[serve] {stats['unfinished']} request(s) still in flight "
               f"at the --steps cap")
     obs_events.close()
+    return stats
+
+
+def cmd_run(args) -> int:
     # The step cap bounds the run, not its correctness — fail only when
     # the engine served nothing at all.
-    return 0 if stats["requests"] > 0 else 1
+    return 0 if run(args)["requests"] > 0 else 1
 
 
 def cmd_selfcheck(args) -> int:
-    import jax
-
     from tpuframe.models.bert import BertConfig
     from tpuframe.models.transformer_lm import LMConfig
     from tpuframe.obs import events as obs_events
@@ -75,7 +91,6 @@ def cmd_selfcheck(args) -> int:
     from tpuframe.serve import loadgen
     from tpuframe.serve.engine import (BertClassifier, LMEngine,
                                        golden_parity_check)
-    from tpuframe.utils import compile_cache
 
     failures = []
     buckets = (16, 32)
@@ -142,14 +157,7 @@ def cmd_selfcheck(args) -> int:
                         f"probs_sum={float(probs.sum()):.4f}")
     print(f"[serve] bert classify: label={label} ok")
 
-    # 5. Persistent-cache safety of the decode outputs (int32 tokens +
-    #    f32 cache only — no typed PRNG keys).
-    out_avals = jax.eval_shape(lambda: engine._tokens)
-    if not compile_cache.outputs_cache_safe(out_avals):
-        failures.append("decode outputs flagged cache-unsafe")
-    print("[serve] compile-cache safety: ok")
-
-    # 6. Fleet smoke: 2 fake-engine replica subprocesses behind the
+    # 5. Fleet smoke: 2 fake-engine replica subprocesses behind the
     #    router, seeded loadgen, one replica_crash mid-run — the
     #    zero-loss drain/redispatch contract on every CI run (the full
     #    3-replica latency proof lives in tests/test_chaos.py).
@@ -181,13 +189,16 @@ def cmd_selfcheck(args) -> int:
     return 1 if failures else 0
 
 
-def main(argv=None) -> int:
+def parse_args(argv=None):
     ap = argparse.ArgumentParser(
         prog="python -m tpuframe.serve",
         description="tpuframe serving loadgen / selfcheck")
-    ap.add_argument("--model", default="tiny-lm")
+    ap.add_argument("--model", default="tiny-lm",
+                    help="tiny-lm | lm-124m")
     ap.add_argument("--steps", type=int, default=100,
                     help="max scheduler steps for the loadgen run")
+    ap.add_argument("--requests", type=int, default=None,
+                    help="synthetic requests to send (default steps/4)")
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new-tokens", type=int, default=8)
     ap.add_argument("--seed", type=int, default=0)
@@ -196,7 +207,11 @@ def main(argv=None) -> int:
                          "TPUFRAME_EVENTS_DIR)")
     ap.add_argument("--selfcheck", action="store_true",
                     help="run the CPU acceptance selfcheck and exit")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
     if args.selfcheck:
         return cmd_selfcheck(args)
     return cmd_run(args)

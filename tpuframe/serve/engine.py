@@ -28,9 +28,7 @@ forbidden (lint TF109) from calling ``jit``/``.apply`` themselves — a
 novel shape reaching the compiler mid-serving is a silent multi-second
 stall, the serving analogue of the TF106 dead-env-write footgun.
 
-Greedy argmax sampling keeps the engine deterministic (and its compiled
-programs free of typed PRNG-key outputs, so they are persistent-cache
-safe on every jax — ``utils.compile_cache.outputs_cache_safe``).
+Greedy argmax sampling keeps the engine deterministic.
 """
 
 from __future__ import annotations
@@ -176,16 +174,6 @@ class LMEngine:
             cache_sds, sds((slots,), i32), sds((slots, 1), i32),
             pcache_sds, sds((), i32), sds((), i32), sds((), i32)).compile()
 
-        # Cache-safety contract (ISSUE 6 satellite): none of the serving
-        # programs may output typed PRNG keys, so the persistent cache is
-        # safe for them even on jax < 0.6 (safe_for_key_outputs() False).
-        out = jax.eval_shape(decode_fn, p_sds,
-                             sds((slots, 1), i32), sds((slots,), i32),
-                             cache_sds)
-        if not compile_cache.outputs_cache_safe(out):
-            raise RuntimeError(
-                "decode step outputs an extended dtype — persistent-cache "
-                "unsafe on this jax; keep PRNG keys out of serve programs")
         self.reset()
 
     # --- state -------------------------------------------------------------
@@ -404,13 +392,13 @@ def swap_parity_check(cfg, *, buckets, decode_tokens: int = 4,
 # Golden-logits parity — the correctness contract of the whole cache path.
 # ---------------------------------------------------------------------------
 
-def golden_parity_check(cfg, *, buckets, capacity: int,
-                        decode_tokens: int = 4, seed: int = 0,
-                        atol: float = 2e-5) -> list:
-    """Prefill-then-decode must reproduce the training forward's logits
-    position-by-position, for every prompt bucket (both a full bucket
-    and a ragged prompt that exercises the length mask).  Returns
-    problem strings; [] means parity holds.
+def golden_parity_diffs(cfg, *, buckets, capacity: int,
+                        decode_tokens: int = 4, seed: int = 0) -> list:
+    """``(bucket, prompt_len, max |logit diff|)`` of prefill-then-decode
+    against the training forward, position by position, for every prompt
+    bucket (both a full bucket and a ragged prompt that exercises the
+    length mask).  The diff is None where prompt + decode overruns
+    ``capacity``.
 
     Uses raw ``model.apply`` on purpose — this file is the sanctioned
     compile seam, and the reference side must be the *training* path,
@@ -422,14 +410,13 @@ def golden_parity_check(cfg, *, buckets, capacity: int,
     from tpuframe.models.transformer_lm import TransformerLM
 
     model = TransformerLM(cfg)
-    problems = []
+    rows = []
     params = None
     for bucket in buckets:
-        for prompt_len in {bucket, max(2, bucket - 3)}:
+        for prompt_len in sorted({bucket, max(2, bucket - 3)}):
             total = prompt_len + decode_tokens
             if total > capacity:
-                problems.append(f"bucket {bucket}: prompt+decode {total} "
-                                f"exceeds capacity {capacity}")
+                rows.append((bucket, prompt_len, None))
                 continue
             ids = jax.random.randint(jax.random.key(seed + bucket),
                                      (1, total), 0, cfg.vocab_size)
@@ -455,9 +442,27 @@ def golden_parity_check(cfg, *, buckets, capacity: int,
                 outs.append(lg)
                 length = length + 1
             got = jnp.concatenate(outs, axis=1)
-            diff = float(jnp.max(jnp.abs(ref - got)))
-            if diff > atol:
-                problems.append(
-                    f"bucket {bucket} prompt_len {prompt_len}: max "
-                    f"|logit diff| {diff:.2e} > {atol:.0e}")
+            rows.append((bucket, prompt_len,
+                         float(jnp.max(jnp.abs(ref - got)))))
+    return rows
+
+
+def golden_parity_check(cfg, *, buckets, capacity: int,
+                        decode_tokens: int = 4, seed: int = 0,
+                        atol: float = 2e-5) -> list:
+    """:func:`golden_parity_diffs` held to ``atol`` (the f32 default; a
+    bf16 config needs a bf16 tolerance).  Returns problem strings; []
+    means parity holds."""
+    problems = []
+    for bucket, prompt_len, diff in golden_parity_diffs(
+            cfg, buckets=buckets, capacity=capacity,
+            decode_tokens=decode_tokens, seed=seed):
+        if diff is None:
+            problems.append(f"bucket {bucket}: prompt+decode "
+                            f"{prompt_len + decode_tokens} exceeds "
+                            f"capacity {capacity}")
+        elif not diff <= atol:
+            problems.append(
+                f"bucket {bucket} prompt_len {prompt_len}: max "
+                f"|logit diff| {diff:.2e} > {atol:.0e}")
     return problems

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import argparse
 import ast
+import contextlib
 import hashlib
 import itertools
 import os
@@ -278,16 +279,10 @@ def build_harness(cfg: TrainConfig) -> Harness:
                       f"{elastic_resize['base_lr_from']:g}→{new_lr:g})",
                       flush=True)
     # Sharded-state (auto-SPMD) mode: ZeRO/FSDP over the fsdp axis and/or
-    # Megatron-style TP over the model axis — both are placement decisions
-    # living on the Auto-typed mesh twin (tpuframe.parallel.fsdp.auto_mesh).
+    # Megatron-style TP over the model axis — both are placement decisions.
     use_sharded_state = mesh is not None and (
         mesh.shape["fsdp"] > 1 or mesh.shape["model"] > 1
         or mesh.shape["expert"] > 1)
-    data_mesh = mesh
-    if use_sharded_state:
-        from tpuframe.parallel import fsdp as fsdp_lib
-
-        data_mesh = fsdp_lib.auto_mesh(mesh)
 
     dtype = jnp.dtype(cfg.compute_dtype)
     model = models.get_model(cfg.model, dtype=dtype, **cfg.model_kwargs)
@@ -314,10 +309,10 @@ def build_harness(cfg: TrainConfig) -> Harness:
     # model's first op would cast them on device anyway; bf16 halves
     # infeed bytes — same rounding, same losses).
     cast = dtype if dtype != jnp.float32 else None
-    train_loader = ShardedLoader(train_ds, cfg.global_batch, data_mesh,
+    train_loader = ShardedLoader(train_ds, cfg.global_batch, mesh,
                                  seed=cfg.seed, partition=loader_part,
                                  cast_floats=cast)
-    eval_loader = ShardedLoader(eval_ds, cfg.global_batch, data_mesh,
+    eval_loader = ShardedLoader(eval_ds, cfg.global_batch, mesh,
                                 shuffle=False, partition=loader_part,
                                 cast_floats=cast)
 
@@ -880,17 +875,24 @@ def train(cfg: TrainConfig, *, trace_dir: str | None = None,
 
     Thin shell around the real loop: any escaping exception first dumps
     the flight recorder's ring (``obs/flight.py``) so the postmortem has
-    the last-N events even when the JSONL log's tail was torn."""
-    try:
-        return _train_impl(cfg, trace_dir=trace_dir, log_file=log_file)
-    except SystemExit:
-        raise  # clean exits (preemption rc 14) are not crashes
-    except BaseException:
-        flight_lib.dump("exception")
-        raise
+    the last-N events even when the JSONL log's tail was torn.  However
+    the loop ends, the threads it started (prefetch workers, heartbeat,
+    device-memory sampler) are stopped and joined before this returns: a
+    daemon thread still inside a jax call when the interpreter finalizes
+    aborts the process after the run has succeeded."""
+    with contextlib.ExitStack() as threads:
+        try:
+            return _train_impl(cfg, threads, trace_dir=trace_dir,
+                               log_file=log_file)
+        except SystemExit:
+            raise  # clean exits (preemption rc 14) are not crashes
+        except BaseException:
+            flight_lib.dump("exception")
+            raise
 
 
-def _train_impl(cfg: TrainConfig, *, trace_dir: str | None = None,
+def _train_impl(cfg: TrainConfig, threads: contextlib.ExitStack, *,
+                trace_dir: str | None = None,
                 log_file: str | None = None) -> dict:
     # Preemption contract (resilience/preempt.py): installed before the
     # harness so a SIGTERM during compile/restore is already caught; the
@@ -918,27 +920,21 @@ def _train_impl(cfg: TrainConfig, *, trace_dir: str | None = None,
     # Persistent compilation cache (utils/compile_cache): a relaunch or
     # crash-loop restart of the same program compiles from the on-disk
     # cache instead of from scratch — hit/miss counters land in the final
-    # metrics below next to the retry.* counters.  Gated: the train step
-    # returns typed PRNG keys (state.rng), which jax 0.4.x cannot serve
-    # from the cache without a hard C++ abort.
+    # metrics below next to the retry.* counters.
     from tpuframe.utils import compile_cache
 
-    if compile_cache.safe_for_key_outputs():
-        compile_cache.enable()
-    else:
-        # Disarm, don't just decline: an in-process LMEngine (colocated
-        # serving, the swap-seam tests) enables the cache for its own
-        # key-free programs, and a cache hit on the train step's keyed
-        # outputs would abort.
-        compile_cache.disable()
-        print("[tpuframe] compile cache: disabled (this jax aborts on "
-              "cached executables with typed-PRNG-key outputs)",
-              file=sys.stderr)
+    compile_cache.enable()
     # Re-parse TPUFRAME_FAULTS per run: in-process callers (tests) invoke
     # train() repeatedly under different envs, and restore-time gcs reads
     # inside build_harness already pass through the seams.
     faults_lib.reset_from_env()
+    # Each run reports its own Pallas kernel resolutions, once.
+    from tpuframe.ops import kernel_impl
+
+    kernel_impl.reset()
     h = build_harness(cfg)
+    threads.callback(h.train_loader.close)
+    threads.callback(h.eval_loader.close)
     # An elastic resize may have rescaled global_batch/base_lr inside
     # build_harness — everything below reads the config the harness was
     # actually built with.
@@ -964,7 +960,7 @@ def _train_impl(cfg: TrainConfig, *, trace_dir: str | None = None,
     # Mutable run facts the event-emitting closures need (filled in once
     # the harness/flops model is known; read from the watchdog thread).
     run_info: dict = {"flops": None, "flops_source": None, "bytes": None,
-                      "generation": goodput_lib.DEFAULT_GENERATION,
+                      "generation": None, "generation_source": None,
                       "devmem": None, "step": h.start_step}
 
     def _emit_run_end(final_step: int) -> None:
@@ -996,6 +992,8 @@ def _train_impl(cfg: TrainConfig, *, trace_dir: str | None = None,
             extra.update(run_info["devmem"].peak_summary())
         events_lib.emit("run_end", final_step=final_step,
                         wall_s=summary["wall_s"], goodput=summary,
+                        generation=run_info["generation"],
+                        generation_source=run_info["generation_source"],
                         counters=obs_metrics.counters(), **extra)
 
     def _on_stall(idle: float) -> None:
@@ -1028,6 +1026,7 @@ def _train_impl(cfg: TrainConfig, *, trace_dir: str | None = None,
     heartbeat = Heartbeat(timeout_s=stall_timeout, poll_s=stall_poll,
                           on_stall=_on_stall,
                           arm_after_first_beat=True).start()
+    threads.callback(heartbeat.stop)
 
     # Live telemetry plane (obs/exporter.py): /metrics + /healthz, env-
     # gated.  The health probe is the heartbeat watchdog — a run that
@@ -1104,12 +1103,14 @@ def _train_impl(cfg: TrainConfig, *, trace_dir: str | None = None,
         # the step once (no compile); the analytic 6·N·D estimate is the
         # fallback — either way run_start records a nonzero flops_per_step
         # so MFU is recomputable offline even from a crashed log.
-        from tpuframe.tune import db as tune_db
+        from tpuframe.tune import roofline
 
         n_params = sum(int(np.prod(p.shape))
                        for p in jax.tree.leaves(h.state.params))
-        run_info["generation"] = (tune_db.target_generation()
-                                  or goodput_lib.DEFAULT_GENERATION)
+        # The chip the MFU/HBM rows are priced against, and where that
+        # came from (device / env / assumed — a CPU run is `assumed`).
+        run_info["generation"], run_info["generation_source"] = \
+            roofline.device_generation()
         if step < cfg.total_steps:
             first = next(data_iter)
             flops, nbytes, src = _step_costs(h.train_step, state, first)
@@ -1132,6 +1133,8 @@ def _train_impl(cfg: TrainConfig, *, trace_dir: str | None = None,
             start_step=h.start_step, total_steps=cfg.total_steps,
             global_batch=cfg.global_batch, n_params=n_params,
             generation=run_info["generation"],
+            generation_source=run_info["generation_source"],
+            device_kind=jax.devices()[0].device_kind,
             flops_per_step=flops, flops_source=src,
             bytes_per_step=nbytes)
         # The chosen remat policy as its own typed record: joinable with
@@ -1189,6 +1192,7 @@ def _train_impl(cfg: TrainConfig, *, trace_dir: str | None = None,
         run_info["devmem"] = devmem_lib.DevmemSampler(
             interval_s=float(os.environ.get("TPUFRAME_DEVMEM_INTERVAL_S",
                                             "30"))).start()
+        threads.callback(run_info["devmem"].stop)
         meter.charge("init", meter.wall_s())
     # Profiler trace window.  ``TPUFRAME_TRACE_STEPS="<start>:<count>"``
     # (absolute step indices) captures a jax.profiler trace of exactly

@@ -14,7 +14,7 @@ Turns the ad-hoc perf/ census scripts into a first-class autotuner:
     env override > measured > predicted > default.
 
 ``python -m tpuframe.tune sweep --topology v5e:2x2`` runs the whole thing
-CPU-only — no TPU, no relay.
+CPU-only — no TPU attached.
 
 This package root is import-light on purpose: ``db``/``roofline`` are pure
 stdlib so the flash-attention import-time lookup and the analysis-gate

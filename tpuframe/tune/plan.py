@@ -3,7 +3,7 @@
 Closes ROADMAP's "turn strategy choice into a static analysis pass":
 enumerate the valid ``tpuframe.parallel.pspec`` layouts for a model ×
 device count × slice count, AOT-compile each on a compile-only TPU
-topology (no chip, no relay — the PERF §7 trick), run every shardflow
+topology (no chip — the PERF §7 trick), run every shardflow
 structural detector as an ADMISSIBILITY gate, and rank the survivors by
 the analysis-v3 cost stack:
 

@@ -1,10 +1,11 @@
 """Roofline scorer: compiled-program cost/memory analysis -> predicted step
 time lower bound, binding-resource verdict, and a fits/OOM check.
 
-Per-generation hardware tables.  The v5e numbers are the ones every PERF.md
-roofline uses (197 TFLOPs bf16, 0.81 TB/s HBM, 15.75 GB usable HBM) and the
-v4/v5p/v6e peak-flops column matches bench.py's ``BF16_PEAK_FLOPS`` table so
-the two can never disagree on MFU.
+Per-generation hardware tables — the one peak table of the repo (bench.py,
+obs.goodput and the tuner all read it).  The v5e numbers are the ones every
+PERF.md roofline uses (197 TFLOPs bf16, 0.81 TB/s HBM, 15.75 GB usable HBM).
+:func:`device_generation` maps the attached device's ``device_kind`` to a
+row of it.
 
 Honesty caveats carried from PERF.md:
 
@@ -17,13 +18,15 @@ Honesty caveats carried from PERF.md:
     ResNet-50 step sits at ~81% of the HBM roofline (scheduling gap), so a
     predicted 177 ms means "not faster than 177 ms", never "177 ms".
 
-Pure stdlib — no jax import.  ``score_compiled`` takes the compiled object
-duck-typed (anything with ``cost_analysis``/``memory_analysis``/``as_text``).
+Pure stdlib at import (``device_generation`` imports jax when called).
+``score_compiled`` takes the compiled object duck-typed (anything with
+``cost_analysis``/``memory_analysis``/``as_text``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import os
 
 GiB = 1024 ** 3
 
@@ -42,7 +45,7 @@ class Hardware:
 
 # Sources: v5e column = PERF.md §2 (197e12 / 0.81e12 / 15.75 GB, the values
 # every recorded roofline in this repo was computed against).  Peak-flops
-# column for v4/v5p/v6e = bench.py BF16_PEAK_FLOPS.  v4 HBM = 1.23 TB/s /
+# column for v4/v5p/v6e = public TPU spec sheets.  v4 HBM = 1.23 TB/s /
 # 32 GB, v5p = 2.76 TB/s / 95 GB, v6e = 1.64 TB/s / 32 GB (public TPU
 # system specs; only the v5e row is pinned by recorded measurements here).
 # ICI column: aggregate interchip bandwidth per chip from the same public
@@ -60,6 +63,49 @@ HARDWARE = {
     "v5p": Hardware("v5p", 459e12, 2.76e12, 95.0 * 1e9, 600e9, 6.25e9),
     "v6e": Hardware("v6e", 918e12, 1.64e12, 32.0 * 1e9, 448e9, 6.25e9),
 }
+
+
+# ``jax.devices()[0].device_kind`` -> HARDWARE key.  A TPU whose kind is
+# not listed is an error (device_generation), never a default.
+DEVICE_KINDS = {
+    "TPU v4": "v4",
+    "TPU v5 lite": "v5e",
+    "TPU v5": "v5p",
+    "TPU v5p": "v5p",
+    "TPU v6 lite": "v6e",
+}
+
+GEN_ENV = "TPUFRAME_TUNE_GEN"
+# What a CPU run prices MFU/HBM rows against (the event-log tests run
+# there); always labelled ``assumed``, never reported as a device fact.
+ASSUMED_GENERATION = "v5e"
+
+
+def device_generation(device=None) -> tuple[str, str]:
+    """``(generation, source)`` for the device a program runs on.
+
+    ``source`` says where the generation came from: ``env`` —
+    ``TPUFRAME_TUNE_GEN``, the explicit override (the compile-only tools
+    target a chip that is described, not attached); ``device`` — the
+    attached TPU's ``device_kind`` looked up in :data:`DEVICE_KINDS`;
+    ``assumed`` — no TPU attached (CPU runs), :data:`ASSUMED_GENERATION`.
+    A TPU whose kind is not in the table raises: its peaks are unknown,
+    and a default would price it as another chip."""
+    env = os.environ.get(GEN_ENV, "").strip().lower()
+    if env:
+        return generation_from_topology(env), "env"
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    if device.platform != "tpu":
+        return ASSUMED_GENERATION, "assumed"
+    kind = device.device_kind
+    if kind not in DEVICE_KINDS:
+        raise KeyError(f"unknown TPU device_kind {kind!r}; have "
+                       f"{sorted(DEVICE_KINDS)} — add it to "
+                       f"tune/roofline.py with its peaks, or set {GEN_ENV}")
+    return DEVICE_KINDS[kind], "device"
 
 
 def generation_from_topology(topology: str) -> str:

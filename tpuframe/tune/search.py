@@ -3,7 +3,7 @@
 The sweep compiles every candidate on a compile-only TPU topology
 (``jax.experimental.topologies.get_topology_desc``, PERF.md §7) on the CPU
 host — real XLA:TPU lowering, real ``cost_analysis``/``memory_analysis``,
-no chip, no relay — scores each with the roofline tables, and writes the
+no chip — scores each with the roofline tables, and writes the
 ranked results into the persistent tuning DB plus a human-readable report.
 
 Candidate axes:

@@ -1,10 +1,9 @@
 """Shared persistent-compilation-cache startup helper.
 
-The JAX persistent compile cache was wired only into bench.py; this moves
-it into one helper used by ``train.py``, ``launch/launcher.py`` and
-``bench.py`` — so PR 2's preemption relaunches and crash-loop restarts
-stop recompiling every program from scratch.  Cache traffic is surfaced
-as process-wide counters in ``obs.metrics``:
+One helper for ``train.py``, ``serve``, ``launch/launcher.py`` and
+``bench.py`` — so preemption relaunches and crash-loop restarts stop
+recompiling every program from scratch.  Cache traffic is surfaced as
+process-wide counters in ``obs.metrics``:
 
     compile_cache.hits    — programs served from the on-disk cache
     compile_cache.misses  — fresh compiles written to it
@@ -12,59 +11,29 @@ as process-wide counters in ``obs.metrics``:
 (train.py folds both into its final metrics next to the ``retry.*``
 counters, so a warm restart is visible in the run log.)
 
+Where the cache lives: ``JAX_COMPILATION_CACHE_DIR`` if set — jax reads
+it itself, and this module then sets no directory in code — else the
+fixed ``<repo>/.xla_cache``.  The path is part of the cache key, so it
+never moves by pid, time or temp name.
+
 Knobs:
-    TPUFRAME_COMPILE_CACHE        cache dir; "" / "0" / "off" disables
-                                  (default <repo>/.xla_cache — bench.py's
-                                  long-standing location)
+    TPUFRAME_COMPILE_CACHE        "0" / "off" disables; nothing else
     TPUFRAME_COMPILE_CACHE_MIN_S  min compile seconds worth persisting
-                                  (default 1.0, bench.py's value)
+                                  (default 1.0)
 """
 
 from __future__ import annotations
 
 import os
 
-_ENV_DIR = "TPUFRAME_COMPILE_CACHE"
+_ENV_OFF = "TPUFRAME_COMPILE_CACHE"
+_ENV_STD = "JAX_COMPILATION_CACHE_DIR"
 _ENV_MIN_S = "TPUFRAME_COMPILE_CACHE_MIN_S"
 _OFF = ("", "0", "off", "none")
 
 _LISTENER_INSTALLED = False
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MISS_EVENT = "/jax/compilation_cache/cache_misses"
-
-
-def safe_for_key_outputs() -> bool:
-    """Whether this jax can serve programs whose OUTPUTS are typed PRNG
-    keys (e.g. the train step's ``TrainState.rng``) from the persistent
-    cache.  jax 0.4.x hard-aborts (C++ CHECK in the key result handler)
-    when such an executable is deserialized over a mesh — unprobeable at
-    runtime, so gate on the same jax>=0.6 capability marker the analysis
-    strategies use.  bench-style programs without key outputs are safe on
-    every version and need no gate."""
-    import jax
-
-    return hasattr(jax, "typeof")
-
-
-def outputs_cache_safe(out_avals) -> bool:
-    """Whether a program with these output avals (a pytree from
-    ``jax.eval_shape``) is persistent-cache safe on THIS jax.  On
-    jax>=0.6 everything is; on older jax only programs whose outputs
-    carry no extended dtype (typed PRNG keys) are — exactly the check
-    the serving engine runs on its decode step, whose donated KV buffers
-    make an executable-deserialization abort extra expensive."""
-    if safe_for_key_outputs():
-        return True
-    import jax
-
-    extended = getattr(jax.dtypes, "extended", None)
-    for leaf in jax.tree_util.tree_leaves(out_avals):
-        dtype = getattr(leaf, "dtype", None)
-        if dtype is None:
-            continue
-        if extended is not None and jax.numpy.issubdtype(dtype, extended):
-            return False
-    return True
 
 
 def reset_cache() -> bool:
@@ -81,44 +50,41 @@ def reset_cache() -> bool:
         return False
 
 
-def disable() -> None:
-    """Actively disarm the persistent cache for this process: clear the
-    configured dir and drop the latched singleton so the next compile
-    re-initializes cacheless.  Callers that merely *decline* to enable()
-    are not safe — another in-process component (an LMEngine built by a
-    colocated-serving test, say) may have enabled the cache already, and
-    a cache hit on a keyed-output executable is a hard C++ abort on
-    jax < 0.6."""
-    import jax
-
-    jax.config.update("jax_compilation_cache_dir", None)
-    reset_cache()
-
-
 def default_cache_dir() -> str:
     return os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".xla_cache")
 
 
-def enable(cache_dir: str | None = None, *,
-           min_compile_secs: float | None = None,
+def location() -> tuple[str | None, str]:
+    """``(directory, source)`` of the cache :func:`enable` would use:
+    source is ``env`` (the standard variable), ``default`` (the fixed
+    in-checkout path) or ``off``."""
+    off = os.environ.get(_ENV_OFF)
+    if off is not None and off.strip().lower() in _OFF:
+        return None, "off"
+    std = os.environ.get(_ENV_STD, "").strip()
+    if std:
+        return std, "env"
+    return default_cache_dir(), "default"
+
+
+def enable(*, min_compile_secs: float | None = None,
            min_entry_size_bytes: int | None = None) -> str | None:
     """Turn on the persistent compilation cache + hit/miss counters.
 
     Returns the cache dir, or None when disabled via env.  Call before
     the first compile; safe to call more than once (jax.config updates
     are idempotent, the monitoring listener installs once).  jax is
-    imported lazily so stdlib-only callers (bench.py module level) can
-    import this module freely.
+    imported lazily so stdlib-only callers can import this module freely.
     """
-    env = os.environ.get(_ENV_DIR)
-    if env is not None and env.strip().lower() in _OFF:
+    cache_dir, source = location()
+    if cache_dir is None:
         return None
-    cache_dir = cache_dir or env or default_cache_dir()
 
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if source == "default":
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     if min_compile_secs is None:
         min_compile_secs = float(os.environ.get(_ENV_MIN_S, "1.0"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
@@ -140,9 +106,8 @@ def _install_listener() -> None:
         return
     import jax
 
-    from tpuframe.obs import metrics
-
     from tpuframe.obs import events as obs_events
+    from tpuframe.obs import metrics
 
     def _on_event(event: str, **kwargs) -> None:
         if event == _HIT_EVENT:
