@@ -135,17 +135,6 @@ def _set_args(sets) -> list[str]:
     return [a for s in sets for a in ("--set", s)]
 
 
-def _with_model_kwargs(sets, **extra) -> list[str]:
-    """``sets`` with ``extra`` merged into its one ``model_kwargs=`` entry
-    (a second ``--set model_kwargs=`` would replace the first)."""
-    import ast
-
-    key = "model_kwargs="
-    merged = {**ast.literal_eval(next(
-        (s[len(key):] for s in sets if s.startswith(key)), "{}")), **extra}
-    return [*(s for s in sets if not s.startswith(key)), f"{key}{merged!r}"]
-
-
 def _events(events_dir: str) -> list[dict]:
     from tpuframe.obs import events
 
@@ -366,7 +355,8 @@ def phase_lm(*, config: str = "lm_long", sets=LM_SETS,
               "no Mosaic custom call (tpu_custom_call) in the compiled step")
     freed = _free_device_memory()
 
-    xla_sets = _with_model_kwargs(sets, attn_impl="xla")
+    # a repeated --set model_kwargs= merges into the first (train._parse_set)
+    xla_sets = [*sets, 'model_kwargs={"attn_impl": "xla"}']
     _, xla_records = _run_trainer(config, xla_sets,
                                   _fresh_dir("lm124m", "events_xla"))
     xla_report = _step_report(xla_records)

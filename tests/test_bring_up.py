@@ -151,23 +151,22 @@ class TestKernelImplReport:
         recs = [r for r in events.merge(str(tmp_path))
                 if r["type"] == "kernel_impl"]
         assert len(recs) == 1 and events.validate_record(recs[0]) == []
-        assert (recs[0]["op"], recs[0]["impl"]) == ("flash_attention",
-                                                    "interpret")
-        assert fresh.resolved() == {
-            "flash_attention": {"interpret": "backend=cpu"}}
+        assert (recs[0]["op"], recs[0]["impl"], recs[0]["why"]) == (
+            "flash_attention", "interpret", "backend=cpu")
 
-    def test_kernel_and_its_fallback_both_say_so(self, fresh):
+    def test_kernel_and_its_fallback_both_say_so(self, fresh, capsys):
         import jax.numpy as jnp
 
         from tpuframe.ops import attention as attn_ops
 
         ok = jnp.zeros((1, 128, 2, 64), jnp.float32)
         attn_ops.multihead_attention(ok, ok, ok, causal=True, impl="pallas")
-        assert fresh.resolved()["flash_attention"] == {
-            "interpret": "backend=cpu"}
+        assert capsys.readouterr().out.splitlines() == [
+            "[tpuframe] kernel flash_attention -> interpret (backend=cpu)"]
         odd = jnp.zeros((1, 100, 2, 64), jnp.float32)  # 100 does not tile
         attn_ops.multihead_attention(odd, odd, odd, impl="pallas")
-        assert "do not tile" in fresh.resolved()["flash_attention"]["xla"]
+        (line,) = capsys.readouterr().out.splitlines()
+        assert "flash_attention -> xla" in line and "do not tile" in line
 
     def test_interpret_override_is_named(self, monkeypatch, fresh):
         monkeypatch.setenv("TPUFRAME_PALLAS_INTERPRET", "0")

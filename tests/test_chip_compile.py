@@ -74,12 +74,20 @@ def test_flash_row_stats_are_lane_major_for_v5e(v5e):
     assert "96x1x2048xf32" in txt and "96x2048x1xf32" not in txt
 
 
-def test_fused_conv_bn_bwd_compiles_for_v5e(v5e):
-    """The largest ResNet-50 1x1 conv at b256 that ``supported()`` admits
-    (layer1: 56x56, 256 -> 128; layer4's 1024 -> 2048 it refuses)."""
+@pytest.mark.parametrize("h,w,k,c_out", [
+    (56, 56, 256, 128),    # most rows: layer2's first conv1
+    (14, 14, 1024, 512),   # layer4's first conv1: the first tiling of this
+                           # shape overflowed the real v5e's VMEM
+    (7, 7, 2048, 512),     # deepest K: layer4's later conv1s
+])
+def test_fused_conv_bn_bwd_compiles_for_v5e(v5e, h, w, k, c_out):
+    """ResNet-50 1x1 convs at b256 that ``supported()`` admits, from the
+    most rows to the heaviest weight block and accumulator in VMEM: the
+    guard that ``_pick_tiles``/``_vmem_est`` keep each inside it.  Layer4's
+    1024 -> 2048 ``supported()`` refuses."""
     from tpuframe.ops import fused_conv_bn as fcb
 
-    b, h, w, k, c_out = 256, 56, 56, 256, 128
+    b = 256
     assert fcb.supported(h, w, b, k, c_out)
     assert not fcb.supported(7, 7, b, 1024, 2048)
     a = jax.ShapeDtypeStruct((b, h, w, k), jnp.bfloat16, sharding=v5e)

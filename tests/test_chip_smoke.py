@@ -47,13 +47,22 @@ def test_all_reduce_group_sizes_reads_both_spellings(smoke):
     assert smoke.all_reduce_group_sizes(hlo) == [4, 4, 2]
 
 
-def test_model_kwargs_merge_into_one_set(smoke):
-    sets = smoke._with_model_kwargs(smoke.LM_SETS, attn_impl="xla")
-    kwargs = [s for s in sets if s.startswith("model_kwargs=")]
-    assert kwargs == [
-        "model_kwargs={'seq_mode': None, 'max_seq': 2048, "
-        "'attn_impl': 'xla'}"]
-    assert len(sets) == len(smoke.LM_SETS)
+def test_a_repeated_set_of_a_dict_field_merges(smoke):
+    """The smoke's XLA-attention run is LM_SETS plus one more
+    ``--set model_kwargs=``: the second adds to the first."""
+    from tpuframe import train
+    from tpuframe.utils import get_config
+
+    kv = train._parse_set([*smoke.LM_SETS,
+                           'model_kwargs={"attn_impl": "xla"}',
+                           "total_steps=2"])
+    assert kv["model_kwargs"] == {"seq_mode": None, "max_seq": 2048,
+                                  "attn_impl": "xla"}
+    assert kv["total_steps"] == 2            # a scalar still replaces
+    cfg = get_config("lm_long").with_overrides(**kv)
+    assert cfg.model_kwargs["attn_impl"] == "xla"
+    assert cfg.model_kwargs["max_seq"] == 2048
+    assert "seq_mode" not in cfg.model_kwargs  # None deletes lm_long's ring
 
 
 # --- rehearsals: every phase at toy size, on the CPU ------------------------

@@ -7,9 +7,9 @@ The choice is made from the backend and the shapes at trace time, so a
 step program can hold any of the three without its config saying so.
 Every dispatch point reports its choice here; the first time an
 ``(op, impl, why)`` is seen in a run it is printed and written to the
-run-event log as a ``kernel_impl`` record, and :func:`resolved` hands
-the table to a caller that must hold a run to it (``chip_smoke.py``
-fails a phase that asked for a kernel and got anything but ``mosaic``).
+run-event log as a ``kernel_impl`` record.  A caller that must hold a run
+to its choice reads those records (``chip_smoke.py`` fails a phase that
+asked for a kernel and got anything but ``mosaic``).
 """
 
 from __future__ import annotations
@@ -55,11 +55,6 @@ def record(op: str, impl: str, why: str) -> None:
     from tpuframe.obs import events
 
     events.emit("kernel_impl", op=op, impl=impl, why=why)
-
-
-def resolved() -> dict[str, dict[str, str]]:
-    """``{op: {impl: why}}`` recorded since the last :func:`reset`."""
-    return {op: dict(impls) for op, impls in _resolved.items()}
 
 
 def reset() -> None:

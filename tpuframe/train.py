@@ -1420,9 +1420,15 @@ def _parse_set(values: list[str]) -> dict:
         if not raw:
             raise ValueError(f"--set needs key=value, got {item!r}")
         try:
-            out[key] = ast.literal_eval(raw)
+            value = ast.literal_eval(raw)
         except (ValueError, SyntaxError):
-            out[key] = raw
+            value = raw
+        # A repeated dict-valued key merges, as with_overrides merges it
+        # into the config's own: a later --set model_kwargs= adds to an
+        # earlier one instead of dropping it.
+        if isinstance(value, dict) and isinstance(out.get(key), dict):
+            value = {**out[key], **value}
+        out[key] = value
     return out
 
 

@@ -26,7 +26,8 @@ Pure stdlib at import (``device_generation`` imports jax when called).
 from __future__ import annotations
 
 import dataclasses
-import os
+
+from tpuframe.tune import db
 
 GiB = 1024 ** 3
 
@@ -75,7 +76,6 @@ DEVICE_KINDS = {
     "TPU v6 lite": "v6e",
 }
 
-GEN_ENV = "TPUFRAME_TUNE_GEN"
 # What a CPU run prices MFU/HBM rows against (the event-log tests run
 # there); always labelled ``assumed``, never reported as a device fact.
 ASSUMED_GENERATION = "v5e"
@@ -91,9 +91,9 @@ def device_generation(device=None) -> tuple[str, str]:
     ``assumed`` — no TPU attached (CPU runs), :data:`ASSUMED_GENERATION`.
     A TPU whose kind is not in the table raises: its peaks are unknown,
     and a default would price it as another chip."""
-    env = os.environ.get(GEN_ENV, "").strip().lower()
+    env = db.target_generation()  # the one reader of TPUFRAME_TUNE_GEN
     if env:
-        return generation_from_topology(env), "env"
+        return env, "env"
     if device is None:
         import jax
 
@@ -104,7 +104,8 @@ def device_generation(device=None) -> tuple[str, str]:
     if kind not in DEVICE_KINDS:
         raise KeyError(f"unknown TPU device_kind {kind!r}; have "
                        f"{sorted(DEVICE_KINDS)} — add it to "
-                       f"tune/roofline.py with its peaks, or set {GEN_ENV}")
+                       f"tune/roofline.py with its peaks, or set "
+                       f"TPUFRAME_TUNE_GEN")
     return DEVICE_KINDS[kind], "device"
 
 
