@@ -139,9 +139,13 @@ def test_timeline_through_harness(tmp_path):
     assert out.returncode == 0, out.stderr[-1500:]
     trace = json.load(open(path))
     names = {e["name"] for e in trace["traceEvents"]}
-    assert {"data_wait", "train_step", "eval"} <= names
-    steps = [e for e in trace["traceEvents"] if e["name"] == "train_step"]
+    assert {"train.data_wait", "train.step", "train.eval"} <= names
+    # the loader's worker is on the same timeline, on a thread of its own
+    assert {"loader.gather", "loader.put", "loader.wait"} <= names
+    steps = [e for e in trace["traceEvents"] if e["name"] == "train.step"]
     assert len(steps) == 6
+    gathers = [e for e in trace["traceEvents"] if e["name"] == "loader.gather"]
+    assert {e["tid"] for e in gathers}.isdisjoint({e["tid"] for e in steps})
 
 
 # ---------------------------------------------------------------------------

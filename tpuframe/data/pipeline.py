@@ -24,6 +24,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding
 
 from tpuframe.data.datasets import ArrayDataset
+from tpuframe.obs import metrics, timeline
 from tpuframe.parallel import mesh as mesh_lib
 
 
@@ -142,11 +143,19 @@ class ShardedLoader:
             return False
 
         def worker():
+            # One gather, cast and put span per batch, then queue_full
+            # for as long as the batch is ready and nobody wants it.
             try:
-                for lo in starts:
+                for n, lo in enumerate(starts, start=skip):
                     idx = order[lo:lo + self.host_batch]
-                    if not put(self._to_device(self.dataset[idx])):
+                    with timeline.span("loader.gather", batch=n):
+                        rows = self.dataset[idx]
+                    item = self._to_device(rows, n)
+                    with timeline.span("loader.queue_full", batch=n):
+                        wanted = put(item)
+                    if not wanted:
                         return  # consumer gone
+                    metrics.bump("loader.batches")
                 put(sentinel)
             except BaseException as e:  # noqa: BLE001 — surface to consumer
                 put(e)
@@ -157,7 +166,8 @@ class ShardedLoader:
         t.start()
         try:
             while True:
-                item = q.get()
+                with timeline.span("loader.wait"):
+                    item = q.get()
                 if item is sentinel:
                     break
                 if isinstance(item, BaseException):
@@ -201,12 +211,21 @@ class ShardedLoader:
         """Infinite stream across epochs (step-based training loops)."""
         return self.from_step(0)
 
-    def _to_device(self, batch: dict) -> dict:
+    def _to_device(self, rows: dict, n: int) -> dict:
         if self._cast_floats is not None:
-            batch = {k: (v.astype(self._cast_floats)
-                         if k in self._cast_keys
-                         and np.issubdtype(v.dtype, np.floating) else v)
-                     for k, v in batch.items()}
+            with timeline.span("loader.cast", batch=n):
+                rows = {k: (v.astype(self._cast_floats)
+                            if k in self._cast_keys
+                            and np.issubdtype(v.dtype, np.floating) else v)
+                        for k, v in rows.items()}
+        # host side only: device_put returns before the transfer ends
+        with timeline.span("loader.put", batch=n):
+            batch = self._put(rows)
+        metrics.bump("loader.bytes_put",
+                     sum(v.nbytes for v in jax.tree.leaves(rows)))
+        return batch
+
+    def _put(self, batch: dict) -> dict:
         if self._sharding is None:
             return jax.tree.map(jax.device_put, batch)
         # Host rows are this host's slice of the global batch; device_put with

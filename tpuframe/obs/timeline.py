@@ -5,13 +5,27 @@ TPU the equivalent visibility comes from the XLA/jax profiler: a perfetto/
 TensorBoard trace of the compiled step, including the all-reduce ops and
 their overlap with compute.  ``TPUFRAME_TRACE_DIR`` env or config triggers a
 trace of steps [start, start+count) in the harness.
+
+The host side of that picture is ``span()``: one primitive for every host
+seam of the program (the loader's worker, the train loop, ``Scheduler``,
+``LMEngine``).  A span is a ``jax.profiler.TraceAnnotation`` named
+``tpuframe:<name>``, so a running profiler shows it on its caller's
+thread, on one clock with the device ops; and it is a record in one
+process-wide bounded ring on ``time.monotonic`` — the clock of
+``Scheduler`` and of ``Request.arrival_t`` — that ``spans()``,
+``durations_ms()`` and ``self_ms()`` read back, with or without a
+profiler.  ``StepTimeline`` exports the ring as Chrome JSON.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import itertools
 import os
+import threading
 import time
+from typing import NamedTuple
 
 import jax
 
@@ -58,9 +72,129 @@ def profile_trace(log_dir: str):
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named region inside a traced window (maps to a trace event)."""
-    return jax.profiler.TraceAnnotation(name)
+# The ring holds the last RING_SPANS spans of the process, always (no
+# switch): ~300 bytes each, 20 MB when full.  Sized by the busiest
+# producer, the serving loop of benchmark cell lm124m.serve_chat_r80: a
+# scheduler step every 19.5 ms (51.3/s) closes 7 spans (sched.step, two
+# sched.admit, sched.retire, engine.decode and its dispatch and fetch) and
+# each of the 11.2 requests/s 5 more (sched.queue, engine.prefill and its
+# dispatch and fetch, engine.insert): 51.3 * 7 + 11.2 * 5 = 415 spans/s.
+# The readers run after the drain, so the measured window's first span has
+# to outlive the window (20 s), the traced seconds (3) and the longest
+# drain the runner allows (60): 83 s * 415 = 34.5k spans.  2**16 keeps
+# 158 s of that traffic (the 20 s lead-in too); a training loop closes
+# under 40 spans/s.
+RING_SPANS = 1 << 16
+
+ANNOTATION_PREFIX = "tpuframe:"
+
+
+class Span(NamedTuple):
+    """One closed host span.  ``t0``/``t1`` are ``time.monotonic``
+    seconds; ``parent`` is the ``sid`` of the span that was open around
+    it in the same thread (None at the top, and for ``record()``)."""
+
+    name: str
+    t0: float
+    t1: float
+    thread: str
+    parent: int | None
+    args: dict
+    sid: int
+
+    @property
+    def ms(self) -> float:
+        return 1e3 * (self.t1 - self.t0)
+
+
+_ring: collections.deque = collections.deque(maxlen=RING_SPANS)
+_ids = itertools.count(1)
+_local = threading.local()
+
+
+def _open_sids() -> list:
+    try:
+        return _local.open
+    except AttributeError:
+        _local.open = []
+        return _local.open
+
+
+class span:
+    """``with span("loader.gather", batch=3): ...`` — a host span named
+    ``name`` in the caller's thread, nested under the span open around
+    it.  ``set(**args)`` adds arguments known only at the end (the
+    scheduler's per-step counts)."""
+
+    __slots__ = ("name", "args", "sid", "_parent", "_t0", "_annotation")
+
+    def __init__(self, name: str, **args):
+        self.name, self.args = name, args
+
+    def __enter__(self) -> "span":
+        open_sids = _open_sids()
+        self._parent = open_sids[-1] if open_sids else None
+        self.sid = next(_ids)
+        open_sids.append(self.sid)
+        self._annotation = jax.profiler.TraceAnnotation(
+            ANNOTATION_PREFIX + self.name, **self.args)
+        self._annotation.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def set(self, **args) -> None:
+        self.args.update(args)
+        self._annotation.set_metadata(**args)
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.monotonic()
+        self._annotation.__exit__(*exc)
+        _local.open.pop()
+        _local.closed = closed = Span(
+            self.name, self._t0, t1, threading.current_thread().name,
+            self._parent, self.args, self.sid)
+        _ring.append(closed)
+        return False
+
+
+def record(name: str, t0: float, t1: float, **args) -> None:
+    """Add an interval whose ends were read elsewhere on
+    ``time.monotonic`` (a request's queue wait: due to admitted).  It
+    nests under nothing: it may start long before any open span."""
+    _ring.append(Span(name, t0, t1, threading.current_thread().name,
+                      None, args, next(_ids)))
+
+
+def last(name: str) -> Span | None:
+    """The span this thread closed last, if its name is ``name``: how a
+    caller reads the duration of the span its callee just made."""
+    closed = getattr(_local, "closed", None)
+    return closed if closed is not None and closed.name == name else None
+
+
+def spans(name: str | None = None, t0: float | None = None,
+          t1: float | None = None) -> list[Span]:
+    """The ring's spans called ``name`` (all, if None) that started in
+    ``[t0, t1)``, in the order they closed."""
+    return [s for s in tuple(_ring)
+            if (name is None or s.name == name)
+            and (t0 is None or s.t0 >= t0) and (t1 is None or s.t0 < t1)]
+
+
+def durations_ms(name: str, t0: float | None = None,
+                 t1: float | None = None) -> list[float]:
+    return [s.ms for s in spans(name, t0, t1)]
+
+
+def self_ms(name: str, t0: float | None = None,
+            t1: float | None = None) -> list[float]:
+    """Each such span's duration less what the spans nested directly
+    under it cover (they run one after another in its thread)."""
+    covered: dict[int, float] = collections.defaultdict(float)
+    for s in tuple(_ring):
+        if s.parent is not None:
+            covered[s.parent] += s.ms
+    return [s.ms - covered[s.sid] for s in spans(name, t0, t1)]
 
 
 class StepTimeline:
@@ -68,8 +202,10 @@ class StepTimeline:
 
     Horovod's timeline shows per-tensor collective phases; under one-program
     SPMD the interesting host phases are coarser: data wait (input pipeline),
-    step submit/execute, eval, checkpoint.  Events accumulate in memory and
-    flush as a Chrome ``chrome://tracing`` / Perfetto JSON array on close.
+    step submit/execute, eval, checkpoint.  ``close`` writes every span the
+    ring still holds since this object was made — the harness's phases and
+    the loader's worker alike, one ``tid`` per thread — as a Chrome
+    ``chrome://tracing`` / Perfetto JSON array.
 
     Enable via ``TPUFRAME_TIMELINE=/path/trace.json`` (env parity with
     ``HOROVOD_TIMELINE=file.json``) — the harness wires the phases.
@@ -83,40 +219,34 @@ class StepTimeline:
             root, ext = os.path.splitext(path)
             path = f"{root}.proc{jax.process_index()}{ext or '.json'}"
         self.path = path
-        self._events: list[dict] = []
-        self._t0 = time.perf_counter()
+        self._t0 = time.monotonic()
 
     @classmethod
     def from_env(cls) -> "StepTimeline | None":
         path = os.environ.get("TPUFRAME_TIMELINE")
         return cls(path) if path else None
 
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
-
-    @contextlib.contextmanager
-    def phase(self, name: str, **args):
-        start = self._now_us()
-        try:
-            yield
-        finally:
-            self._events.append({
-                "name": name, "ph": "X", "ts": start,
-                "dur": self._now_us() - start,
-                "pid": jax.process_index(), "tid": 0,
-                **({"args": args} if args else {}),
-            })
+    def phase(self, name: str, **args) -> span:
+        return span(name, **args)
 
     def instant(self, name: str, **args) -> None:
-        self._events.append({
-            "name": name, "ph": "i", "ts": self._now_us(), "s": "p",
-            "pid": jax.process_index(), "tid": 0,
-            **({"args": args} if args else {}),
-        })
+        now = time.monotonic()
+        record(name, now, now, **args)
 
     def close(self) -> None:
         import json
 
+        pid, tids, events = jax.process_index(), {}, []
+        for s in sorted(spans(t0=self._t0), key=lambda s: s.t0):
+            ev = {"name": s.name, "ts": (s.t0 - self._t0) * 1e6,
+                  "pid": pid, "tid": tids.setdefault(s.thread, len(tids)),
+                  **({"args": s.args} if s.args else {})}
+            if s.t1 == s.t0:
+                ev.update(ph="i", s="p")
+            else:
+                ev.update(ph="X", dur=(s.t1 - s.t0) * 1e6)
+            events.append(ev)
         with open(self.path, "w") as f:
-            json.dump({"traceEvents": self._events,
-                       "displayTimeUnit": "ms"}, f)
+            json.dump({"traceEvents": events, "displayTimeUnit": "ms",
+                       "threadNames": {str(v): k for k, v in tids.items()}},
+                      f)

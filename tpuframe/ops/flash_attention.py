@@ -221,6 +221,7 @@ def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k, interpret,
     lse_shape = (bn, 1, s_q) if lane else (bn, s_q, 1)
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",   # the op's name in a profiler trace
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -394,6 +395,7 @@ def _flash_bwd(q, k, v, mask, out, lse, do, *, scale, causal,
         functools.partial(kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, n_kv=n_kv,
                           lane_lse=lane, precision=precision),
+        name="flash_bwd_dq",
         grid=(bn, n_q, n_kv),
         in_specs=mspec + [q_spec_qmajor, kv_spec_qmajor, kv_spec_qmajor,
                           q_spec_qmajor, row_spec_qmajor, row_spec_qmajor],
@@ -416,6 +418,7 @@ def _flash_bwd(q, k, v, mask, out, lse, do, *, scale, causal,
         functools.partial(kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, n_q=n_q,
                           lane_lse=lane, precision=precision),
+        name="flash_bwd_dkv",
         grid=(bn, n_kv, n_q),
         in_specs=mspec + [q_spec, kv_spec, kv_spec, q_spec, row_spec,
                           row_spec],

@@ -33,10 +33,9 @@ Greedy argmax sampling keeps the engine deterministic.
 
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
+from tpuframe.obs import timeline
 from tpuframe.serve import kv_cache as kv
 
 
@@ -127,7 +126,6 @@ class LMEngine:
         self.cfg = cfg
         self.model = TransformerLM(cfg)
         self.eos_id = eos_id
-        self.last_prefill_ms = 0.0
         self.decode_block = (decode_block if decode_block is not None
                              else kv.resolve_decode_block())
         buckets = (tuple(prompt_buckets) if prompt_buckets is not None
@@ -241,15 +239,17 @@ class LMEngine:
         bucket = kv.bucket_for(len(ids), self.prompt_buckets)
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :len(ids)] = ids
-        t0 = time.monotonic()
-        tok, pcache = self._prefill[bucket](
-            self.params, jnp.asarray(padded),
-            jnp.asarray([len(ids)], jnp.int32))
-        first = int(tok[0])   # host sync: the first token materializes
-        # Host-observed executable time (through the sync above) — the
-        # scheduler's prefill trace span reports it as ``engine_ms`` so
-        # waterfalls split bucket-dispatch overhead from device work.
-        self.last_prefill_ms = 1e3 * (time.monotonic() - t0)
+        # The span's two children split the bucket's dispatch (the
+        # executable's call returns) from the wait for the device (the
+        # first token materializes on the host).
+        with timeline.span("engine.prefill", bucket=bucket,
+                           tokens=len(ids)):
+            with timeline.span("engine.prefill.dispatch"):
+                tok, pcache = self._prefill[bucket](
+                    self.params, jnp.asarray(padded),
+                    jnp.asarray([len(ids)], jnp.int32))
+            with timeline.span("engine.prefill.fetch"):
+                first = int(tok[0])   # host sync
         return first, pcache, len(ids)
 
     def insert(self, slot: int, pcache, length: int,
@@ -260,18 +260,23 @@ class LMEngine:
         if not 0 <= slot < self.spec.slots:
             raise ValueError(f"slot {slot} out of range "
                              f"[0, {self.spec.slots})")
-        self._layers, self._lengths, self._tokens = self._insert(
-            self._layers, self._lengths, self._tokens, pcache,
-            jnp.asarray(slot, jnp.int32), jnp.asarray(length, jnp.int32),
-            jnp.asarray(first_token, jnp.int32))
+        with timeline.span("engine.insert", slot=slot):
+            self._layers, self._lengths, self._tokens = self._insert(
+                self._layers, self._lengths, self._tokens, pcache,
+                jnp.asarray(slot, jnp.int32), jnp.asarray(length, jnp.int32),
+                jnp.asarray(first_token, jnp.int32))
 
     def decode_step(self) -> np.ndarray:
         """One decode step over every slot.  Returns the new token per
         slot (host numpy [slots]; inactive slots carry garbage the
         scheduler ignores)."""
-        self._tokens, self._lengths, self._layers = self._decode(
-            self.params, self._tokens, self._lengths, self._layers)
-        return np.asarray(self._tokens[:, 0])
+        with timeline.span("engine.decode"):
+            with timeline.span("engine.decode.dispatch"):
+                self._tokens, self._lengths, self._layers = self._decode(
+                    self.params, self._tokens, self._lengths, self._layers)
+                column = self._tokens[:, 0]   # a device slice, enqueued
+            with timeline.span("engine.decode.fetch"):
+                return np.asarray(column)     # host sync
 
 
 # ---------------------------------------------------------------------------

@@ -108,13 +108,10 @@ class FakeEngine:
         self.step_delay_s = step_delay_s
         self.vocab_size = vocab_size
         self._last = [0] * slots
-        self.last_prefill_ms = 0.0
 
     def prefill(self, token_ids):
-        t0 = time.monotonic()
         first = (sum(int(t) for t in token_ids)
                  + 31 * len(token_ids)) % self.vocab_size
-        self.last_prefill_ms = 1e3 * (time.monotonic() - t0)
         return first, ("pcache", len(token_ids)), len(token_ids)
 
     def insert(self, slot, pcache, length, first_token) -> None:

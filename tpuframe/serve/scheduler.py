@@ -22,6 +22,9 @@ Observability rides obs v2: a typed ``serve_step`` event per step and a
 ``serve_request`` event per retirement (TTFT/TPOT, token counts) — the
 offline analyzer (``python -m tpuframe.obs summarize``) computes the
 percentiles and tokens/sec/chip from these, beside the training MFU.
+The step's host phases are ``obs.timeline`` spans (``sched.step`` over
+``sched.admit``, the engine's own and ``sched.retire``; ``sched.queue``
+per request), in the ring and in a profiler's trace.
 
 This file is above the compile seam: it calls only the engine's AOT
 executables (lint TF109 keeps ``jit``/``.apply`` out of here).
@@ -34,7 +37,7 @@ from dataclasses import dataclass, field
 
 from tpuframe.obs import events as obs_events
 from tpuframe.obs import exporter as obs_exporter
-from tpuframe.obs import tracing
+from tpuframe.obs import timeline, tracing
 from tpuframe.obs.goodput import _pct
 
 
@@ -47,6 +50,7 @@ class Request:
     max_new_tokens: int = 16
     arrival_t: float = 0.0            # scheduler clock, seconds
     # -- filled in by the scheduler --
+    admit_t: float | None = None      # popped from pending, before prefill
     first_token_t: float | None = None
     done_t: float | None = None
     tokens: list = field(default_factory=list)   # generated tokens
@@ -144,30 +148,35 @@ class Scheduler:
         step and their first decode token next step.  Without it a
         freed slot idles until the next step's leading admit."""
         t0 = self._clock()
-        admitted = self._admit()
+        with timeline.span("sched.step") as step_span:
+            admitted = self._admit()
 
-        produced = 0
-        if any(r is not None for r in self.active):
-            toks = self.engine.decode_step()
-            now = self._clock()
-            for slot, req in enumerate(self.active):
-                if req is None:
-                    continue
-                tok = int(toks[slot])
-                req.tokens.append(tok)
-                produced += 1
-                if self._finished(req, tok):
-                    req.done_t = now
-                    self._retire(slot)
-        admitted += self._admit()
-        self.step_count += 1
-        self.tokens_generated += produced + admitted
+            produced = 0
+            if any(r is not None for r in self.active):
+                toks = self.engine.decode_step()
+                now = self._clock()
+                with timeline.span("sched.retire"):
+                    for slot, req in enumerate(self.active):
+                        if req is None:
+                            continue
+                        tok = int(toks[slot])
+                        req.tokens.append(tok)
+                        produced += 1
+                        if self._finished(req, tok):
+                            req.done_t = now
+                            self._retire(slot)
+            admitted += self._admit()
+            self.step_count += 1
+            self.tokens_generated += produced + admitted
+            counts = dict(
+                step=self.step_count,
+                active=sum(r is not None for r in self.active),
+                admitted=admitted, produced=produced,
+                queued=len(self.pending))
+            step_span.set(**counts)
         obs_events.emit(
-            "serve_step", step=self.step_count,
-            wall_ms=round(1e3 * (self._clock() - t0), 3),
-            active=sum(r is not None for r in self.active),
-            admitted=admitted, produced=produced,
-            queued=len(self.pending))
+            "serve_step", wall_ms=round(1e3 * (self._clock() - t0), 3),
+            **counts)
         return produced + admitted
 
     # -- internals ----------------------------------------------------------
@@ -177,35 +186,39 @@ class Scheduler:
         finishes at prefill (max_new_tokens=1 or instant EOS) retires in
         place and its slot is reused without advancing — one admit pass
         never leaves a free slot behind while requests wait."""
-        admitted = 0
-        slot = 0
-        while self.pending and slot < self.engine.slots:
-            if self.active[slot] is not None:
-                slot += 1
-                continue
-            req = self.pending.pop(0)
-            t_adm = self._clock()
-            first_tok, pcache, length = self.engine.prefill(req.prompt)
-            self.engine.insert(slot, pcache, length, first_tok)
-            req.first_token_t = self._clock()
-            req.tokens.append(first_tok)
-            if req.trace is not None:
-                # Phase spans share clock reads with the TTFT record:
-                # arrival -> admit is queue, admit -> first token is
-                # prefill, so queue.ms + prefill.ms == ttft_ms exactly
-                # (modulo rounding) — the verify_traces invariant.
-                tracing.span(req.trace, "queue", parent=req.span,
-                             ms=1e3 * (t_adm - req.arrival_t))
-                tracing.span(req.trace, "prefill", parent=req.span,
-                             ms=1e3 * (req.first_token_t - t_adm),
-                             engine_ms=getattr(self.engine,
-                                               "last_prefill_ms", None))
-            self.active[slot] = req
-            admitted += 1
-            if self._finished(req, first_tok):
-                self._retire(slot)
-            else:
-                slot += 1
+        with timeline.span("sched.admit") as admit_span:
+            admitted = 0
+            slot = 0
+            while self.pending and slot < self.engine.slots:
+                if self.active[slot] is not None:
+                    slot += 1
+                    continue
+                req = self.pending.pop(0)
+                req.admit_t = t_adm = self._clock()
+                timeline.record("sched.queue", req.arrival_t, t_adm,
+                                rid=req.rid)
+                first_tok, pcache, length = self.engine.prefill(req.prompt)
+                prefill_span = timeline.last("engine.prefill")
+                self.engine.insert(slot, pcache, length, first_tok)
+                req.first_token_t = self._clock()
+                req.tokens.append(first_tok)
+                if req.trace is not None:
+                    # Phase spans share clock reads with the TTFT record:
+                    # arrival -> admit is queue, admit -> first token is
+                    # prefill, so queue.ms + prefill.ms == ttft_ms exactly
+                    # (modulo rounding) — the verify_traces invariant.
+                    tracing.span(req.trace, "queue", parent=req.span,
+                                 ms=1e3 * (t_adm - req.arrival_t))
+                    tracing.span(req.trace, "prefill", parent=req.span,
+                                 ms=1e3 * (req.first_token_t - t_adm),
+                                 engine_ms=prefill_span and prefill_span.ms)
+                self.active[slot] = req
+                admitted += 1
+                if self._finished(req, first_tok):
+                    self._retire(slot)
+                else:
+                    slot += 1
+            admit_span.set(admitted=admitted)
         return admitted
 
     def _finished(self, req: Request, tok: int) -> bool:

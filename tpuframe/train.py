@@ -45,6 +45,7 @@ from tpuframe.obs import exporter as exporter_lib
 from tpuframe.obs import flight as flight_lib
 from tpuframe.obs import goodput as goodput_lib
 from tpuframe.obs import metrics as obs_metrics
+from tpuframe.obs.timeline import span
 from tpuframe.parallel import bootstrap
 from tpuframe.resilience import faults as faults_lib
 from tpuframe.resilience.preempt import RC_PREEMPTED, PreemptionGuard
@@ -1237,15 +1238,10 @@ def _train_impl(cfg: TrainConfig, threads: contextlib.ExitStack, *,
             trace_window = None  # one window per run
 
         t_step0 = time.perf_counter()
-        if timeline is not None:
-            with timeline.phase("data_wait", step=step):
-                batch = next(data_iter)
-            t_compute0 = time.perf_counter()
-            with timeline.phase("train_step", step=step):
-                state, metrics = h.train_step(state, batch)
-        else:
+        with span("train.data_wait", step=step):
             batch = next(data_iter)
-            t_compute0 = time.perf_counter()
+        t_compute0 = time.perf_counter()
+        with span("train.step", step=step):
             state, metrics = h.train_step(state, batch)
         step += 1
         t_end = time.perf_counter()
@@ -1314,10 +1310,7 @@ def _train_impl(cfg: TrainConfig, threads: contextlib.ExitStack, *,
             h.state = state
             t_eval0 = time.perf_counter()
             with rate.paused():  # eval time isn't training throughput
-                if timeline is not None:
-                    with timeline.phase("eval", step=step):
-                        eval_metrics = evaluate(h, cfg.eval_batches)
-                else:
+                with span("train.eval", step=step):
                     eval_metrics = evaluate(h, cfg.eval_batches)
             meter.charge("eval", time.perf_counter() - t_eval0)
             logger.log(step, eval_metrics, prefix="eval")
@@ -1337,11 +1330,9 @@ def _train_impl(cfg: TrainConfig, threads: contextlib.ExitStack, *,
             will_save = h.manager.should_save(step)
             t_ckpt0 = time.perf_counter()
             with rate.paused():
-                if timeline is not None and will_save:
-                    with timeline.phase("checkpoint", step=step):
+                if will_save:
+                    with span("train.checkpoint", step=step):
                         h.manager.maybe_save(step, state)
-                else:
-                    h.manager.maybe_save(step, state)
                 heartbeat.beat(step)  # a long blocking save is progress too
             if will_save:
                 meter.charge("ckpt", time.perf_counter() - t_ckpt0)
