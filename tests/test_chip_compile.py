@@ -62,6 +62,38 @@ def test_flash_fwd_bwd_compiles_for_v5e(v5e, shape, with_lse):
     assert m.temp_size_in_bytes < 1 << 30  # no S x S scores in HBM
 
 
+@pytest.mark.parametrize("shape,s_kv,dtype,causal,masked", [
+    ((4, 2048, 6, 128), 2048, jnp.bfloat16, True, False),   # head size 128
+    ((1, 8192, 2, 256), 8192, jnp.bfloat16, True, False),   # the widest head
+    ((1, 32768, 2, 64), 32768, jnp.bfloat16, True, False),  # K/V in blocks
+    ((1, 32768, 1, 128), 32768, jnp.float32, True, True),   # f32, key mask
+    ((16, 512, 12, 64), 512, jnp.bfloat16, False, True),    # an encoder
+    ((2, 640, 4, 64), 1152, jnp.bfloat16, True, True),      # 128-only sizes
+])
+def test_flash_rule_choice_compiles_for_v5e(v5e, shape, s_kv, dtype, causal,
+                                            masked):
+    """The tiling ``choose_tiles`` picks — blocks up to 1024 rows, the walked
+    operand whole or in blocks of up to 16k, ``vmem_limit_bytes`` raised
+    where its arithmetic passes 16 MiB — is one Mosaic takes, forward and
+    both backward kernels, across the shapes the rule has to serve."""
+    from tpuframe.ops import flash_attention as fa
+
+    b, _, n, d = shape
+    q = jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    k = jax.ShapeDtypeStruct((b, s_kv, n, d), dtype, sharding=v5e)
+    mask = jax.ShapeDtypeStruct((b, s_kv), jnp.int32, sharding=v5e)
+    assert fa.supported(q, k)
+
+    def loss(q, k, v, mask):
+        return fa.flash_mha(q, k, v, mask=mask if masked else None,
+                            causal=causal,
+                            interpret=False).astype(jnp.float32).sum()
+
+    c = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, k, mask).compile()
+    assert c.as_text().count("tpu_custom_call") >= 3
+
+
 def test_flash_row_stats_are_lane_major_for_v5e(v5e):
     """The layout the chip takes (PERF.md §12.2): [bn, 1, s] residuals, not
     the [bn, s, 1] one that pads 128x in HBM."""

@@ -223,8 +223,8 @@ def test_lse_layout_residual_shape(monkeypatch, gen):
     qf = q.reshape(2, 128, 64)
     out, lse = fa._flash_fwd(qf, k.reshape(2, 128, 64),
                              v.reshape(2, 128, 64), None, scale=64 ** -0.5,
-                             causal=False, block_q=64, block_k=64,
-                             interpret=True)
+                             causal=False, tiles=fa.Tiles(64, 64, 64),
+                             lane=fa._lse_lane_major(), interpret=True)
     assert lse.shape == (2, 128)
     assert out.shape == qf.shape
 
@@ -242,12 +242,13 @@ def test_lse_layouts_numerically_equivalent(monkeypatch):
         monkeypatch.delenv("TPUFRAME_TUNE_GEN", raising=False)
         if gen is not None:
             monkeypatch.setenv("TPUFRAME_TUNE_GEN", gen)
+        t = fa.Tiles(64, 64, 64)
         out, lse = fa._flash_fwd(q, k, v, None, scale=64 ** -0.5,
-                                 causal=True, block_q=64, block_k=64,
-                                 interpret=True)
+                                 causal=True, tiles=t, interpret=True,
+                                 lane=fa._lse_lane_major())
         dq, dk, dv = fa._flash_bwd(q, k, v, None, out, lse, 2 * out,
                                    scale=64 ** -0.5, causal=True,
-                                   block_q=64, block_k=64, interpret=True)
+                                   tiling=(t, t, t), interpret=True)
         return out, lse, dq, dk, dv
 
     sub = run(None)      # sublane-major
@@ -255,3 +256,141 @@ def test_lse_layouts_numerically_equivalent(monkeypatch):
     for a, b, name in zip(sub, lan, ("out", "lse", "dq", "dk", "dv")):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5, err_msg=name)
+
+
+# ---------------------------------------------------------------------------
+# Tiling chosen from the shape (PR 26): the rule, the kernels at its blocks
+# against 128 x 128, and the record of what engaged.
+# ---------------------------------------------------------------------------
+
+_SEQS = (128, 512, 640, 1152, 2048, 8192, 32768)
+
+
+@pytest.mark.parametrize("d", [64, 128, 256])
+@pytest.mark.parametrize("s_q", _SEQS)
+def test_block_rule_tiles_every_shape_inside_its_budget(s_q, d):
+    for s_kv in _SEQS:
+        for itemsize in (2, 4):
+            for kernel in ("fwd", "dq", "dkv"):
+                t = fa.choose_tiles(kernel, s_q, s_kv, d, itemsize)
+                for block, seq in ((t.block_q, s_q), (t.block_k, s_kv)):
+                    assert seq % block == 0, (kernel, t)
+                    assert block % 128 == 0 or block == seq, (kernel, t)
+                walked = t.block_q if kernel == "dkv" else t.block_k
+                assert walked % t.sub == 0 and t.sub % 128 == 0, (kernel, t)
+                assert fa.vmem_bytes(kernel, t, d, itemsize) \
+                    <= fa.VMEM_BUDGET, (kernel, t)
+        # whatever tiled at 128 x 128 still tiles
+        q = jax.ShapeDtypeStruct((1, s_q, 2, d), jnp.bfloat16)
+        k = jax.ShapeDtypeStruct((1, s_kv, 2, d), jnp.bfloat16)
+        assert fa.supported(q, k, 128, 128) and fa.supported(q, k)
+
+
+@pytest.mark.parametrize("s,ok", [(64, True), (100, False), (192, False),
+                                  (200, False), (640, True), (1152, True)])
+def test_block_rule_keeps_what_supported_took(s, ok):
+    q = jax.ShapeDtypeStruct((1, s, 2, 64), jnp.float32)
+    assert fa.supported(q) is ok
+    assert fa.supported(q, None, 128, 128) is ok
+
+
+def _fwd_bwd(q, k, v, mask, causal, tiling):
+    """out, lse, dq, dk, dv of the folded kernels at one tiling."""
+    scale = q.shape[-1] ** -0.5
+    out, lse = fa._flash_fwd(q, k, v, mask, scale=scale, causal=causal,
+                             tiles=tiling[0], lane=fa._lse_lane_major(),
+                             interpret=True)
+    do = jnp.cos(out) + 0.5                    # any cotangent will do
+    dlse = jnp.where(lse > fa.NEG_INF / 2, 0.25, 0.0)
+    dq, dk, dv = fa._flash_bwd(q, k, v, mask, out, lse, do, scale=scale,
+                               causal=causal, tiling=tiling, interpret=True,
+                               dlse=dlse)
+    return out, lse, dq, dk, dv
+
+
+@pytest.mark.parametrize("gen", [None, "v5e"], ids=["sublane", "lane"])
+@pytest.mark.parametrize("s_q,s_kv,causal,masked,tiling", [
+    # K/V (Q/dO in dkv) resident, an inner loop of four that stops at the
+    # diagonal: the LM cell's geometry at a size the interpreter affords
+    (512, 512, True, False, ((256, 512, 128), (256, 512, 128),
+                             (512, 256, 128))),
+    # two grid-level K blocks (clamped index maps above the diagonal), two
+    # sub-blocks in each, a key mask with one batch row fully masked
+    (512, 1024, True, True, ((256, 512, 256), (128, 512, 256),
+                             (256, 512, 128))),
+    # more rows than keys, causal: the last q blocks see every key
+    (1024, 512, True, False, ((512, 256, 128), (256, 512, 256),
+                              (512, 128, 256))),
+    # an encoder's shape: no causal mask, a padding mask, cross lengths
+    (256, 512, False, True, ((256, 512, 128), (128, 256, 128),
+                             (256, 256, 128))),
+])
+def test_kernels_at_large_tiles_equal_128x128(monkeypatch, s_q, s_kv, causal,
+                                              masked, tiling, gen):
+    monkeypatch.delenv("TPUFRAME_TUNE_GEN", raising=False)
+    if gen is not None:
+        monkeypatch.setenv("TPUFRAME_TUNE_GEN", gen)
+    rng = np.random.default_rng(11)
+    mk = lambda s: jnp.asarray(  # noqa: E731
+        rng.normal(0, 0.5, size=(2, s, 64)), jnp.float32)
+    q, k, v = mk(s_q), mk(s_kv), mk(s_kv)
+    mask = None
+    if masked:   # batch row 0: no key attends at all; row 1: a padded tail
+        mask = jnp.stack([jnp.zeros(s_kv, jnp.int32),
+                          (jnp.arange(s_kv) < s_kv - 72).astype(jnp.int32)])
+    small = fa.Tiles(128, 128, 128)
+    want = _fwd_bwd(q, k, v, mask, causal, (small, small, small))
+    got = _fwd_bwd(q, k, v, mask, causal,
+                   tuple(fa.Tiles(*t) for t in tiling))
+    for a, b, name in zip(got, want, ("out", "lse", "dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-5, atol=2e-5, err_msg=name)
+    if masked:   # the masked-row convention survives the tiling
+        out, lse, dq, dk, dv = got
+        assert float(jnp.max(jnp.abs(out[0]))) == 0.0
+        assert bool(jnp.all(lse[0] == fa.NEG_INF))
+        for g in (dq, dk, dv):
+            assert float(jnp.max(jnp.abs(g[0]))) == 0.0
+
+
+@pytest.mark.parametrize("s,with_lse", [(640, False), (1024, True)])
+def test_rule_choice_equals_128x128_through_the_public_api(s, with_lse):
+    # 640 tiles only by 128 beside the whole sequence; 1024 takes the
+    # rule's 512-row blocks with K/V resident.
+    q, k, v = _qkv(b=1, s=s, n=2, d=64, seed=5)
+    assert fa.choose_tiles("fwd", s, s, 64, 4)[:2] != (128, 128)
+
+    def loss(blocks):
+        def f(q, k, v):
+            if with_lse:
+                o, lse = fa.flash_mha_lse(q, k, v, causal=True,
+                                          interpret=True, **blocks)
+                return jnp.sum(o ** 2) + jnp.sum(jnp.sin(lse))
+            return jnp.sum(fa.flash_mha(q, k, v, causal=True,
+                                        interpret=True, **blocks) ** 2)
+        return jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    (l_rule, g_rule) = loss({})
+    (l_128, g_128) = loss(dict(block_q=128, block_k=128))
+    np.testing.assert_allclose(l_rule, l_128, rtol=1e-5)
+    for a, b, name in zip(g_rule, g_128, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-5,
+                                   atol=5e-5, err_msg=f"d{name}")
+
+
+def test_kernel_impl_record_names_blocks_and_grid():
+    from tpuframe.ops import kernel_impl
+
+    kernel_impl.reset()
+    try:
+        q, k, v = _qkv(b=1, s=256, n=2, d=64)
+        fa.flash_mha(q, k, v, causal=True)
+        why = kernel_impl._resolved["flash_attention"]["interpret"]
+        assert why == ("backend=cpu; fwd q256 k256 sub256 grid 2x1x1; "
+                       "dq q256 k256 sub256 grid 2x1x1; "
+                       "dkv q256 k256 sub256 grid 2x1x1")
+        fa.flash_mha(q, k, v, causal=True, block_q=128, block_k=128)
+        why = kernel_impl._resolved["flash_attention"]["interpret"]
+        assert "fwd q128 k128 sub128 grid 2x2x2" in why
+    finally:
+        kernel_impl.reset()

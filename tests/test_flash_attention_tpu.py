@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 
 from tpuframe.ops import attention as attn_ops
+from tpuframe.ops import flash_attention as fa
 from tpuframe.ops.flash_attention import flash_mha
 
 pytestmark = pytest.mark.skipif(
@@ -150,3 +151,31 @@ def test_long_seq_2k_bf16_on_chip():
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                **_tol(jnp.bfloat16))
+
+
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("s", [2048, 8192])
+def test_rule_choice_matches_xla_on_chip(s, d):
+    """The tiling the shape rule picks (PR 26) — large blocks, the walked
+    operand resident or in clamped grid blocks, the inner loop to the
+    diagonal — compiles on the chip and agrees with XLA attention, forward
+    and all three gradients, at the LM cell's length and four times it."""
+    assert fa.choose_tiles("fwd", s, s, d, 2)[:2] != (128, 128)
+    q, k, v = _qkv(b=1, s=s, n=2, d=d, dtype=jnp.bfloat16, seed=s + d)
+
+    def grads(attend):
+        def loss(q, k, v):
+            out = attend(q, k, v)
+            return jnp.sum(out.astype(jnp.float32) ** 2), out
+        (_, out), g = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+        return (out, *g)
+
+    got = grads(lambda q, k, v: flash_mha(q, k, v, causal=True,
+                                          interpret=False))
+    want = grads(lambda q, k, v: _xla_ref(q, k, v, causal=True))
+    for a, b, name in zip(got, want, ("out", "dq", "dk", "dv")):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.isfinite(a).all(), name
+        rel = np.linalg.norm(a - b) / np.linalg.norm(b)
+        assert rel < 0.03, f"{name} at s={s} d={d}: {rel:.4f} from XLA"

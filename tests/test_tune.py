@@ -228,7 +228,8 @@ class TestTuningDB:
 
 
 class TestResolution:
-    """env override > measured > predicted > default — and no DB effect
+    """env override > measured > default (flash blocks; the other resolvers
+    still take a predicted row) — and no DB effect
     at all when the target generation is unknown (the tier-1 guarantee:
     CPU tests always see the hard defaults)."""
 
@@ -251,18 +252,34 @@ class TestResolution:
         monkeypatch.delenv("TPUFRAME_XLA_OPTS", raising=False)
         return db
 
-    def test_no_generation_means_defaults(self, seeded_db):
+    @pytest.fixture
+    def measured_fa(self, seeded_db):
+        """The seeded flash row, run on a chip: only then may it choose."""
+        rec = seeded_db.best(family="flash_attention")
+        seeded_db.upgrade_measured(rec, 7.5, unit="ms", maximize=False)
+        seeded_db.save()
+
+    def test_no_generation_means_defaults(self, seeded_db, measured_fa):
         assert tune_db.resolve_fa_blocks(128, 128) == (128, 128)
         assert tune_db.resolve_xla_opts("bench_resnet50_b256") is None
 
-    def test_db_applies_when_generation_known(self, seeded_db,
+    def test_db_applies_when_generation_known(self, seeded_db, measured_fa,
                                               monkeypatch):
         monkeypatch.setenv("TPUFRAME_TUNE_GEN", "v5e")
         assert tune_db.resolve_fa_blocks(128, 128) == (512, 256)
         assert tune_db.resolve_xla_opts("bench_resnet50_b256") == {
             "xla_opt_x": "1"}
 
-    def test_env_override_beats_db(self, seeded_db, monkeypatch):
+    def test_predicted_fa_row_never_outranks_the_caller(self, seeded_db,
+                                                        monkeypatch):
+        # a flash row nobody ran on a chip is a hypothesis: the caller's
+        # default stands (None in ops/flash_attention.py: its shape rule)
+        monkeypatch.setenv("TPUFRAME_TUNE_GEN", "v5e")
+        assert tune_db.resolve_fa_blocks(None, None) == (None, None)
+        assert tune_db.resolve_fa_blocks(128, 128) == (128, 128)
+
+    def test_env_override_beats_db(self, seeded_db, measured_fa,
+                                   monkeypatch):
         monkeypatch.setenv("TPUFRAME_TUNE_GEN", "v5e")
         monkeypatch.setenv("TPUFRAME_FA_BLOCK_Q", "1024")
         q, k = tune_db.resolve_fa_blocks(128, 128)
@@ -271,14 +288,14 @@ class TestResolution:
         assert tune_db.resolve_xla_opts("bench_resnet50_b256") is None
 
     def test_topology_string_names_the_generation(self, seeded_db,
-                                                  monkeypatch):
+                                                  measured_fa, monkeypatch):
         # TPUFRAME_TUNE_GEN is the one switch that engages the DB; a
         # topology string ("v5e:2x2") names its generation.
         monkeypatch.setenv("TPUFRAME_TUNE_GEN", "v5e:2x2")
         assert tune_db.target_generation() == "v5e"
         assert tune_db.resolve_fa_blocks(128, 128) == (512, 256)
 
-    def test_db_off_switch(self, seeded_db, monkeypatch):
+    def test_db_off_switch(self, seeded_db, measured_fa, monkeypatch):
         monkeypatch.setenv("TPUFRAME_TUNE_GEN", "v5e")
         monkeypatch.setenv("TPUFRAME_TUNE_DB", "off")
         assert tune_db.resolve_fa_blocks(128, 128) == (128, 128)
@@ -519,9 +536,10 @@ class TestShippedDB:
             data = json.load(f)
         assert tune_db.validate(data) == []
         db = tune_db.TuningDB(path, data)
-        # acceptance floor: the FA block grid + >=2 opts sets
-        fa = db.records(family="flash_attention")
-        assert len(fa) >= 4
+        # a flash row is there because a chip ran it: the nine predicted
+        # ones went when the kernel's shape rule came (PR 26; ROADMAP D1)
+        assert all(r.measured for r in db.records(family="flash_attention"))
+        # acceptance floor: >=2 opts sets
         bench = db.records(family="bench_resnet50")
         assert len({r.config.get("opts_name") for r in bench}) >= 2
 

@@ -12,9 +12,21 @@ S×S score matrix out of HBM entirely:
     from the saved logsumexp instead of storing them — the standard
     flash-attention-2 residual scheme (O, logsumexp, delta=rowsum(dO·O)).
 
-Block sizes default to 128 — the MXU tile edge — so every matmul in the loop
-is a full systolic-array issue.  Accumulation is float32 regardless of input
-dtype (bf16 inputs keep bf16 in HBM, f32 in VMEM).
+Tiling.  A grid step costs a v5e about 0.4 µs whatever it computes, and a
+128 × 128 score tile at head size 64 is 21 ns of products: at that tiling
+the kernels ran at 3% of their roofline and the step count was the whole
+of their time (PERF.md §6, PR 26).  So the tiling has two levels and is
+chosen from the shape (:func:`choose_tiles`).  The *block* is what one
+grid step owns and the pipeline moves: a block of query rows, and as much
+of K and V as the VMEM budget allows — the whole of them where they fit,
+so that they are fetched once per (batch, head).  Inside a step a
+``lax.fori_loop`` walks the block in *sub-blocks*, the score tile that is
+live at once; under a causal mask it runs only as far as the diagonal
+needs, so no step and no product is spent above it.  Where the grid still
+holds a step above the diagonal (K/V not resident), its index map is
+clamped to the last block its row needs and the pipeline fetches nothing
+for it.  Accumulation is float32 regardless of input dtype (bf16 inputs
+keep bf16 in HBM, f32 in VMEM).
 
 Used through :func:`tpuframe.ops.attention.multihead_attention` with
 ``impl="pallas"`` (or ``TPUFRAME_ATTN_IMPL=pallas``); CPU tests run the same
@@ -24,21 +36,22 @@ kernel under the Pallas interpreter.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# 128 = the MXU tile edge.  Resolution order (tpuframe.tune):
-# TPUFRAME_FA_BLOCK_Q/K env > tuning-DB measured > tuning-DB predicted >
-# 128 — and the DB tiers only engage when TPUFRAME_TUNE_GEN names the
-# target generation, so plain runs and the fast test tier see 128/128.
 from tpuframe.ops import kernel_impl
 from tpuframe.tune import db as _tune_db  # stdlib-only module
 from tpuframe.tune import roofline as _roofline
 
-DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K = _tune_db.resolve_fa_blocks(128, 128)
+# Blocks a caller did not give: TPUFRAME_FA_BLOCK_Q/K, else a *measured*
+# tuning-DB row (only under TPUFRAME_TUNE_GEN), else None — the shape rule
+# below.  An override is one (block_q, block_k) for all three kernels, as
+# an explicit ``block_q=``/``block_k=`` argument is.
+_BLOCK_Q_OVERRIDE, _BLOCK_K_OVERRIDE = _tune_db.resolve_fa_blocks(None, None)
 NEG_INF = -1e30  # softmax mask fill; finite so (x - x) stays 0, not nan
 
 _LANES = 128  # VMEM lane width: per-row stats are stored lane-broadcast
@@ -64,28 +77,153 @@ def _lse_lane_major() -> bool:
     return source != "assumed" and gen != "v4"
 
 
-def _causal_dispatch(causal, qi, kv, block_q, block_k, compute):
-    """Run ``compute(need_tri)`` for this block's causal region.
+# ---------------------------------------------------------------------------
+# tiling: chosen from the shape
+# ---------------------------------------------------------------------------
 
-    Three regions by block position: strictly ABOVE the diagonal
-    contributes nothing (skip entirely); STRADDLING it needs the
-    per-element tri mask; strictly BELOW needs no tri at all — for long
-    sequences most blocks are below, so skipping the iota/compare/select
-    chain there removes real VPU work.  Non-causal: one unmasked call.
-    """
-    if not causal:
-        compute(False)
-        return
-    first_row, last_row = qi * block_q, qi * block_q + (block_q - 1)
-    first_col, last_col = kv * block_k, kv * block_k + (block_k - 1)
+# What one kernel instance may hold in VMEM by the arithmetic of
+# :func:`vmem_bytes`: a fifth of a v5e core's 128 MiB.  Mosaic's scoped
+# default is 16 MiB; a tiling whose arithmetic passes that gets
+# ``vmem_limit_bytes`` raised to the arithmetic's figure and no further
+# (the arithmetic counts every score-sized tile of an inner step as live
+# and the row statistics at their wider layout, so it errs upward).
+VMEM_BUDGET = 24 * 1024 * 1024
+_SCOPED_DEFAULT = 16 * 1024 * 1024
 
-    @pl.when(first_row >= last_col)
-    def _below():
-        compute(False)
+_KERNELS = ("fwd", "dq", "dkv")
+# (own, sub) caps: the rows (fwd, dq) or columns (dkv) a grid step owns
+# beside the operand its inner loop walks, and how much of that operand one
+# inner step takes — the edge of the score tile live at once.  Read on the
+# v5e at [96, 2048, 64], [24, 8192, 64] and [48, 2048, 128] causal bf16
+# (perf/flash_tile_sweep.py; PERF.md §6, PR 26).  The forward pays two
+# cross-lane reductions (row max, row sum) per row of every inner step,
+# however narrow the step: it wants the widest tile.  The backward kernels
+# have none and are fastest at 512.
+_CAPS = {"fwd": (1024, 1024), "dq": (512, 512), "dkv": (1024, 512)}
 
-    @pl.when(jnp.logical_and(last_row >= first_col, first_row < last_col))
-    def _straddle():
-        compute(True)
+
+class Tiles(NamedTuple):
+    """One kernel's tiling.  ``sub`` divides ``block_k`` (fwd, dq: the
+    inner loop walks K/V) or ``block_q`` (dkv: it walks Q/dO)."""
+    block_q: int
+    block_k: int
+    sub: int
+
+
+# The three kernel launchers below are jitted on their own with everything
+# but the arrays static: a model's layers share one shape, so the kernel is
+# traced and lowered to Mosaic once per program and not once per layer —
+# tracing and lowering are paid at every process start, cached executable
+# or not (PERF.md §6, PR 26: 36 launches cost the LM cell's set-up 8 s).
+# (``lane``, the row statistics' layout, is static too: it is read from the
+# environment, which a jit cache key does not see.)
+_STATIC = ("scale", "causal", "tiles", "lane", "interpret", "precision")
+
+
+def _blocks_of(seq: int) -> list[int]:
+    """The blocks that tile ``seq``, largest first: its divisors that are
+    multiples of 128, the whole sequence among them; a sequence that is no
+    multiple of 128 tiles only as a whole (supported() takes it only when
+    it is shorter than 128)."""
+    if seq % _LANES:
+        return [seq]
+    return [seq // n for n in range(1, seq // _LANES + 1)
+            if seq % n == 0 and (seq // n) % _LANES == 0]
+
+
+def _fits(seq: int, cap: int) -> int:
+    """Largest block of ``seq`` that is at most ``cap``, or the smallest
+    there is."""
+    blocks = _blocks_of(seq)
+    return next((c for c in blocks if c <= cap), blocks[-1])
+
+
+def vmem_bytes(kernel: str, tiles: Tiles, head_dim: int,
+               itemsize: int) -> int:
+    """VMEM one grid step of ``kernel`` holds at ``tiles``: every blocked
+    operand and result twice (the pipeline double-buffers them), the f32
+    accumulators, the row statistics at their wider (sublane-major, 128
+    lanes a row) layout, and the score-sized tiles live inside one inner
+    step — s and p forward, p, dp and ds backward, in f32, plus the copy
+    cast to the operands' dtype for the second product."""
+    bq, bk, sub = tiles
+    d = -(-head_dim // _LANES) * _LANES       # the minor dim pads to lanes
+    q_tile, k_tile = bq * d * itemsize, bk * d * itemsize
+    stat = bq * _LANES * 4
+    if kernel == "fwd":
+        blocked = 2 * q_tile + 2 * k_tile + stat        # q, o; k, v; lse
+        scratch = bq * d * 4 + 2 * stat                 # acc; m, l
+        live = bq * sub * (2 * 4 + itemsize)
+    elif kernel == "dq":
+        blocked = 3 * q_tile + 2 * k_tile + 2 * stat    # q, do, dq; k, v
+        scratch = bq * d * 4
+        live = bq * sub * (3 * 4 + itemsize)
+    else:
+        blocked = 2 * q_tile + 4 * k_tile + 2 * stat    # q, do; k, v, dk, dv
+        scratch = 2 * bk * d * 4
+        live = sub * bk * (3 * 4 + itemsize)
+    return 2 * blocked + scratch + live
+
+
+def _compiler_params(kernel: str, tiles: Tiles, head_dim: int,
+                     itemsize: int) -> pltpu.CompilerParams:
+    """The leading two grid dims carry no state from step to step (the
+    accumulators live on the last): declaring them parallel lets Mosaic
+    schedule and pipeline them freely.  The VMEM limit stays Mosaic's own
+    unless the arithmetic says this tiling needs more."""
+    need = vmem_bytes(kernel, tiles, head_dim, itemsize)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        vmem_limit_bytes=need if need > _SCOPED_DEFAULT else None)
+
+
+def choose_tiles(kernel: str, s_q: int, s_kv: int, head_dim: int,
+                 itemsize: int = 2) -> Tiles:
+    """The tiling of ``kernel`` ("fwd", "dq" or "dkv") for this shape.
+
+    The operand the inner loop walks — K and V forward and in dq, Q and dO
+    in dkv — takes the largest block that divides its sequence and fits
+    :data:`VMEM_BUDGET`: the whole sequence where that fits, so the grid
+    has no steps above the diagonal at all.  The other side takes the
+    largest block up to the kernel's cap in ``_CAPS`` and the loop steps by
+    up to its sub cap; both halve before the walked block does, down to 128.
+    A causal mask does not enter: the inner loop stops at the diagonal
+    whatever the blocks are."""
+    own_len, walked_len = (s_kv, s_q) if kernel == "dkv" else (s_q, s_kv)
+
+    def tiles(own, walked, sub):
+        return (Tiles(walked, own, sub) if kernel == "dkv"
+                else Tiles(own, walked, sub))
+
+    cap_own, cap_sub = _CAPS[kernel]
+    while True:
+        own = _fits(own_len, cap_own)
+        for walked in _blocks_of(walked_len):
+            t = tiles(own, walked, _fits(walked, cap_sub))
+            if vmem_bytes(kernel, t, head_dim, itemsize) <= VMEM_BUDGET:
+                return t
+        if cap_own <= _LANES and cap_sub <= _LANES:
+            return t            # the smallest there is
+        if cap_sub >= cap_own:
+            cap_sub //= 2
+        else:
+            cap_own //= 2
+
+
+def _tiling(s_q: int, s_kv: int, head_dim: int, itemsize: int,
+            block_q: int | None, block_k: int | None) -> tuple:
+    """Tiles of (fwd, dq, dkv).  A block the caller gave (or the override
+    above) holds for all three kernels; the rule fills what is left."""
+    block_q = block_q or _BLOCK_Q_OVERRIDE
+    block_k = block_k or _BLOCK_K_OVERRIDE
+    out = []
+    for kernel in _KERNELS:
+        bq, bk, _ = choose_tiles(kernel, s_q, s_kv, head_dim, itemsize)
+        bq = min(block_q, s_q) if block_q else bq
+        bk = min(block_k, s_kv) if block_k else bk
+        walked = bq if kernel == "dkv" else bk
+        out.append(Tiles(bq, bk, _fits(walked, _CAPS[kernel][1])))
+    return tuple(out)
 
 
 def _sds(like: jax.Array, shape, dtype) -> jax.ShapeDtypeStruct:
@@ -96,19 +234,111 @@ def _sds(like: jax.Array, shape, dtype) -> jax.ShapeDtypeStruct:
 
 
 def supported(q: jax.Array, k: jax.Array | None = None,
-              block_q: int = DEFAULT_BLOCK_Q,
-              block_k: int = DEFAULT_BLOCK_K) -> bool:
+              block_q: int | None = None,
+              block_k: int | None = None) -> bool:
     """True when shapes fit the kernel's static tiling (else caller falls
     back to the XLA einsum path, tpuframe.ops.attention)."""
     if q.ndim != 4:
         return False
     _, s_q, _, d = q.shape
     s_kv = s_q if k is None else k.shape[1]
-    bq, bk = min(block_q, s_q), min(block_k, s_kv)
-    # seq dims must tile into whole blocks and stay sublane-aligned (mult of
-    # 8); head dim beyond 256 would blow the per-block VMEM budget.
-    return (d <= 256 and s_q % bq == 0 and s_kv % bk == 0
-            and s_q % 8 == 0 and s_kv % 8 == 0)
+    # seq dims must tile into whole blocks — multiples of 128, or one block
+    # of a shorter sequence — and stay sublane-aligned (mult of 8); head dim
+    # beyond 256 would blow the per-block VMEM budget.
+    if d > 256 or s_q % 8 or s_kv % 8:
+        return False
+    return all(s % b == 0 and (b < _LANES or b % _LANES == 0)
+               for t in _tiling(s_q, s_kv, d, q.dtype.itemsize, block_q,
+                                block_k)
+               for s, b in ((s_q, t.block_q), (s_kv, t.block_k)))
+
+
+# ---------------------------------------------------------------------------
+# the causal geometry, shared by the three kernels
+# ---------------------------------------------------------------------------
+
+
+def _div(x, n: int):
+    """``x // n`` for a traced ``x`` that is never negative: the truncating
+    divide, without the sign correction ``//`` lowers for every use."""
+    return jax.lax.div(x, jnp.int32(n))
+
+
+def _k_ranges(causal, row0, n_rows, col0, sub, n_sub):
+    """Of the ``n_sub`` sub-blocks of ``sub`` columns from ``col0``, what
+    rows [row0, row0 + n_rows) need: ``(n_plain, n_need)``.  Sub-blocks
+    [0, n_plain) lie wholly on or below the diagonal (no per-element mask),
+    [n_plain, n_need) straddle it, the rest lie above and are never
+    touched.  Non-causal: all plain."""
+    if not causal:
+        return n_sub, n_sub
+    n_plain = jnp.minimum(_div(jnp.maximum(row0 + 1 - col0, 0), sub), n_sub)
+    n_need = jnp.minimum(
+        _div(jnp.maximum(row0 + n_rows - col0, 0) + sub - 1, sub), n_sub)
+    return n_plain, n_need
+
+
+def _q_ranges(causal, col0, n_cols, row0, sub, n_sub):
+    """The dkv kernel's view: of the ``n_sub`` sub-blocks of ``sub`` rows
+    from ``row0``, what columns [col0, col0 + n_cols) reach:
+    ``(t_first, t_plain)``.  Sub-blocks [t_first, t_plain) straddle the
+    diagonal, [t_plain, n_sub) lie wholly on or below it, those before
+    t_first lie above.  Non-causal: all plain."""
+    if not causal:
+        return 0, 0
+    t_first = jnp.minimum(_div(jnp.maximum(col0 - row0, 0), sub), n_sub)
+    t_plain = jnp.minimum(
+        _div(jnp.maximum(col0 + n_cols - 1 - row0, 0) + sub - 1, sub), n_sub)
+    return t_first, t_plain
+
+
+def _sub_loop(lo, hi, body):
+    """``body(t)`` for t in [lo, hi).  Static bounds of no or one iteration
+    leave no loop in the kernel; everything else is a ``fori_loop``, never
+    unrolled (a kernel's compile time is set-up time)."""
+    if isinstance(lo, int) and isinstance(hi, int) and hi - lo <= 1:
+        if hi > lo:
+            body(lo)
+        return
+    jax.lax.fori_loop(lo, hi, lambda t, _: body(t), None)
+
+
+def _at(t, sub):
+    """Offset of sub-block ``t``, with its alignment said."""
+    return t * sub if isinstance(t, int) else pl.multiple_of(t * sub, sub)
+
+
+def _keep(mask_row, need_tri, row0, col0, shape):
+    """[rows, cols] bool of the positions that attend, or None for all."""
+    keep = None
+    if mask_row is not None:
+        keep = jnp.broadcast_to(mask_row != 0, shape)
+    if need_tri:
+        rows = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        tri = row0 + rows >= col0 + cols
+        keep = tri if keep is None else jnp.logical_and(keep, tri)
+    return keep
+
+
+def _k_block_of(causal, block_q, block_k, n_kv):
+    """``f(i, j)``: the K/V block that grid step (q block i, kv step j)
+    fetches.  Above the diagonal that is the last block row i needs, not
+    block j: the pipeline sees an unchanged index and issues no DMA for a
+    step that computes nothing."""
+    if not causal:
+        return lambda i, j: j
+    return lambda i, j: jnp.minimum(
+        j, jnp.minimum(_div((i + 1) * block_q - 1, block_k), n_kv - 1))
+
+
+def _q_block_of(causal, block_q, block_k, n_q):
+    """``f(j, i)``: the dkv kernel's mirror of :func:`_k_block_of` — before
+    the diagonal, the first Q block that reaches K/V block j."""
+    if not causal:
+        return lambda j, i: i
+    return lambda j, i: jnp.maximum(
+        i, jnp.minimum(_div(j * block_k, block_q), n_q - 1))
 
 
 # ---------------------------------------------------------------------------
@@ -119,10 +349,12 @@ def supported(q: jax.Array, k: jax.Array | None = None,
 def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref,  # inputs
                 o_ref, lse_ref,                 # outputs
                 acc_ref, m_ref, l_ref,          # scratch
-                *, scale: float, causal: bool, block_q: int, block_k: int,
+                *, scale: float, causal: bool, tiles: Tiles,
                 n_kv: int, lane_lse: bool = False, precision=None):
+    block_q, block_k, sub = tiles
     qi = pl.program_id(1)
     kv = pl.program_id(2)
+    row0, col0 = qi * block_q, kv * block_k
 
     @pl.when(kv == 0)
     def _init():
@@ -130,21 +362,13 @@ def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref,  # inputs
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def compute(need_tri):
-        q = q_ref[0]                     # [bq, d]
-        k = k_ref[0]                     # [bk, d]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), precision=precision,
-            preferred_element_type=jnp.float32) * scale   # [bq, bk]
-
-        keep = None                                       # [bq, bk] or None
-        if mask_ref is not None:
-            keep = jnp.broadcast_to(mask_ref[0, 0][None, :] != 0, s.shape)
-        if need_tri:
-            rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            tri = qi * block_q + rows >= kv * block_k + cols
-            keep = tri if keep is None else jnp.logical_and(keep, tri)
+    def step(t, need_tri):
+        c = _at(t, sub)
+        s = jax.lax.dot_general(                          # [bq, sub]
+            q_ref[0], k_ref[0, pl.ds(c, sub), :], (((1,), (1,)), ((), ())),
+            precision=precision, preferred_element_type=jnp.float32) * scale
+        keep = _keep(None if mask_ref is None else mask_ref[0, t],
+                     need_tri, row0, col0 + c, s.shape)
         if keep is not None:
             s = jnp.where(keep, s, NEG_INF)
 
@@ -153,7 +377,7 @@ def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref,  # inputs
         m_cur = jnp.max(s, axis=-1, keepdims=True)        # [bq, 1]
         m_new = jnp.maximum(m_prev, m_cur)
         alpha = jnp.exp(m_prev - m_new)                   # rescale factor
-        p = jnp.exp(s - m_new)                            # [bq, bk]
+        p = jnp.exp(s - m_new)                            # [bq, sub]
         if keep is not None:
             # Explicit zeroing (not exp-underflow): a fully-masked row keeps
             # l == 0 and yields zero output + NEG_INF lse, and the backward
@@ -162,12 +386,16 @@ def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref,  # inputs
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
 
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+            p.astype(v_ref.dtype), v_ref[0, pl.ds(c, sub), :],
+            (((1,), (0,)), ((), ())),
             precision=precision, preferred_element_type=jnp.float32)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    _causal_dispatch(causal, qi, kv, block_q, block_k, compute)
+    n_plain, n_need = _k_ranges(causal, row0, block_q, col0, sub,
+                                block_k // sub)
+    _sub_loop(0, n_plain, lambda t: step(t, False))
+    _sub_loop(n_plain, n_need, lambda t: step(t, True))
 
     @pl.when(kv == n_kv - 1)
     def _finalize():
@@ -186,35 +414,40 @@ def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref,  # inputs
         lse_ref[0] = lse.reshape(1, block_q) if lane_lse else lse
 
 
-def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k, interpret,
-               precision=None):
+def _mask_operand(mask, tiles: Tiles, n_heads: int, k_block):
+    """The [B, S_kv] key mask as an operand of a kernel whose inner loop
+    walks K: [B, S_kv / sub, 1, sub], so that sub-block ``t`` of a block is
+    ``ref[0, t]`` — a dynamic index on a leading dim, never a dynamic lane
+    slice.  ``k_block(i, j)`` is the K block of a grid step."""
+    _, bk, sub = tiles
+    spec = pl.BlockSpec(
+        (1, bk // sub, 1, sub),
+        lambda b, i, j: (b // n_heads, k_block(i, j), 0, 0))
+    return spec, mask.reshape(mask.shape[0], mask.shape[1] // sub, 1, sub)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _flash_fwd(q, k, v, mask, *, scale, causal, tiles: Tiles, lane: bool,
+               interpret, precision=None):
     bn, s_q, d = q.shape
     s_kv = k.shape[1]
-    bq, bk = min(block_q, s_q), min(block_k, s_kv)
+    bq, bk, _ = tiles
     n_q, n_kv = s_q // bq, s_kv // bk
-    grid = (bn, n_q, n_kv)
 
-    in_specs = [
-        pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),          # q
-        pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),          # k
-        pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0)),          # v
-    ]
+    k_block = _k_block_of(causal, bq, bk, n_kv)
+    kv_spec = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, k_block(i, j), 0))
+    in_specs = [pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
+                kv_spec, kv_spec]
     args = [q, k, v]
-    lane = _lse_lane_major()
-    if mask is not None:
-        n_heads = bn // mask.shape[0]
-        in_specs.insert(0, pl.BlockSpec(
-            (1, 1, bk), lambda b, i, j, h=n_heads: (b // h, 0, j)))
-        args.insert(0, mask[:, None, :])
-        kernel = functools.partial(
-            _fwd_kernel, scale=scale, causal=causal,
-            block_q=bq, block_k=bk, n_kv=n_kv, lane_lse=lane,
-            precision=precision)
+    kernel = functools.partial(
+        _fwd_kernel, scale=scale, causal=causal, tiles=tiles, n_kv=n_kv,
+        lane_lse=lane, precision=precision)
+    if mask is None:
+        kernel = functools.partial(kernel, None)
     else:
-        kernel = functools.partial(
-            _fwd_kernel, None, scale=scale, causal=causal,
-            block_q=bq, block_k=bk, n_kv=n_kv, lane_lse=lane,
-            precision=precision)
+        spec, arg = _mask_operand(mask, tiles, bn // mask.shape[0], k_block)
+        in_specs.insert(0, spec)
+        args.insert(0, arg)
 
     lse_spec = (pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)) if lane
                 else pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)))
@@ -222,7 +455,7 @@ def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k, interpret,
     out, lse = pl.pallas_call(
         kernel,
         name="flash_fwd",   # the op's name in a profiler trace
-        grid=grid,
+        grid=(bn, n_q, n_kv),
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -237,11 +470,7 @@ def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k, interpret,
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
-        # batch and q-block dims carry no cross-iteration state (the
-        # acc/m/l scratch carry lives on the kv dim only): declaring them
-        # parallel lets Mosaic schedule/pipeline them freely.
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params("fwd", tiles, d, q.dtype.itemsize),
         interpret=interpret,
     )(*args)
     return out, (lse[:, 0, :] if lane else lse[:, :, 0])
@@ -252,56 +481,53 @@ def _flash_fwd(q, k, v, mask, *, scale, causal, block_q, block_k, interpret,
 # ---------------------------------------------------------------------------
 
 
-def _recompute_p(q_ref, k_ref, lse_ref, mask_ref, *, scale, need_tri,
-                 qi, kv, block_q, block_k, lane_lse=False, precision=None):
-    """Rebuild the probability block from saved logsumexp (f32)."""
+def _rows(ref_block, lane_lse):
+    """A block of a row statistic as a [rows, 1] column."""
+    return ref_block.reshape(-1, 1) if lane_lse else ref_block
+
+
+def _recompute_p(q, k, lse, keep, *, scale, precision):
+    """Rebuild the probability tile from the saved logsumexp (f32)."""
     s = jax.lax.dot_general(
-        q_ref[0], k_ref[0], (((1,), (1,)), ((), ())), precision=precision,
+        q, k, (((1,), (1,)), ((), ())), precision=precision,
         preferred_element_type=jnp.float32) * scale
-    keep = None
-    if mask_ref is not None:
-        keep = jnp.broadcast_to(mask_ref[0, 0][None, :] != 0, s.shape)
-    if need_tri:
-        rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-        cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        tri = qi * block_q + rows >= kv * block_k + cols
-        keep = tri if keep is None else jnp.logical_and(keep, tri)
-    lse = lse_ref[0]                           # [bq, 1] (or [1, bq] lane)
-    if lane_lse:
-        lse = lse.reshape(block_q, 1)
-    p = jnp.exp(jnp.where(keep, s, NEG_INF) - lse) if keep is not None \
-        else jnp.exp(s - lse)
-    if keep is not None:
-        p = jnp.where(keep, p, 0.0)                         # see fwd kernel
-    return p                                                # [bq, bk]
+    if keep is None:
+        return jnp.exp(s - lse)
+    p = jnp.exp(jnp.where(keep, s, NEG_INF) - lse)
+    return jnp.where(keep, p, 0.0)                          # see fwd kernel
 
 
 def _bwd_dq_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, dq_acc, *, scale, causal, block_q, block_k, n_kv,
+                   dq_ref, dq_acc, *, scale, causal, tiles: Tiles, n_kv,
                    lane_lse=False, precision=None):
+    block_q, block_k, sub = tiles
     qi = pl.program_id(1)
     kv = pl.program_id(2)
+    row0, col0 = qi * block_q, kv * block_k
 
     @pl.when(kv == 0)
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def compute(need_tri):
-        p = _recompute_p(q_ref, k_ref, lse_ref, mask_ref, scale=scale,
-                         need_tri=need_tri, qi=qi, kv=kv,
-                         block_q=block_q, block_k=block_k,
-                         lane_lse=lane_lse, precision=precision)
-        dp = jax.lax.dot_general(                       # dO @ V^T  [bq, bk]
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+    def step(t, need_tri):
+        c = _at(t, sub)
+        k = k_ref[0, pl.ds(c, sub), :]
+        keep = _keep(None if mask_ref is None else mask_ref[0, t],
+                     need_tri, row0, col0 + c, (block_q, sub))
+        p = _recompute_p(q_ref[0], k, _rows(lse_ref[0], lane_lse), keep,
+                         scale=scale, precision=precision)
+        dp = jax.lax.dot_general(                       # dO @ V^T [bq, sub]
+            do_ref[0], v_ref[0, pl.ds(c, sub), :], (((1,), (1,)), ((), ())),
             precision=precision, preferred_element_type=jnp.float32)
-        delta = (delta_ref[0].reshape(block_q, 1) if lane_lse
-                 else delta_ref[0])
-        ds = p * (dp - delta)                           # [bq, bk]
+        ds = p * (dp - _rows(delta_ref[0], lane_lse))   # [bq, sub]
         dq_acc[...] += scale * jax.lax.dot_general(     # ds @ K    [bq, d]
-            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             precision=precision, preferred_element_type=jnp.float32)
 
-    _causal_dispatch(causal, qi, kv, block_q, block_k, compute)
+    n_plain, n_need = _k_ranges(causal, row0, block_q, col0, sub,
+                                block_k // sub)
+    _sub_loop(0, n_plain, lambda t: step(t, False))
+    _sub_loop(n_plain, n_need, lambda t: step(t, True))
 
     @pl.when(kv == n_kv - 1)
     def _():
@@ -310,35 +536,46 @@ def _bwd_dq_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _bwd_dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc,
-                    *, scale, causal, block_q, block_k, n_q,
+                    *, scale, causal, tiles: Tiles, n_q,
                     lane_lse=False, precision=None):
+    block_q, block_k, sub = tiles
     kv = pl.program_id(1)
     qi = pl.program_id(2)
+    row0, col0 = qi * block_q, kv * block_k
 
     @pl.when(qi == 0)
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def compute(need_tri):
-        p = _recompute_p(q_ref, k_ref, lse_ref, mask_ref, scale=scale,
-                         need_tri=need_tri, qi=qi, kv=kv,
-                         block_q=block_q, block_k=block_k,
-                         lane_lse=lane_lse, precision=precision)
+    def step(t, need_tri):
+        r = _at(t, sub)
+        q = q_ref[0, pl.ds(r, sub), :]
+        do = do_ref[0, pl.ds(r, sub), :]
+        if lane_lse:      # [block_q / sub, 1, sub] blocks: see _flash_bwd_dkv
+            lse, delta = lse_ref[0, t], delta_ref[0, t]
+        else:
+            lse = lse_ref[0, pl.ds(r, sub), :]
+            delta = delta_ref[0, pl.ds(r, sub), :]
+        keep = _keep(None if mask_ref is None else mask_ref[0],
+                     need_tri, row0 + r, col0, (sub, block_k))
+        p = _recompute_p(q, k_ref[0], _rows(lse, lane_lse), keep,
+                         scale=scale, precision=precision)  # [sub, bk]
         dv_acc[...] += jax.lax.dot_general(             # P^T @ dO  [bk, d]
-            p.astype(do_ref.dtype), do_ref[0], (((0,), (0,)), ((), ())),
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             precision=precision, preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(
-            do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+            do, v_ref[0], (((1,), (1,)), ((), ())),
             precision=precision, preferred_element_type=jnp.float32)
-        delta = (delta_ref[0].reshape(block_q, 1) if lane_lse
-                 else delta_ref[0])
-        ds = p * (dp - delta)
+        ds = p * (dp - _rows(delta, lane_lse))
         dk_acc[...] += scale * jax.lax.dot_general(     # ds^T @ Q  [bk, d]
-            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             precision=precision, preferred_element_type=jnp.float32)
 
-    _causal_dispatch(causal, qi, kv, block_q, block_k, compute)
+    n_sub = block_q // sub
+    t_first, t_plain = _q_ranges(causal, col0, block_k, row0, sub, n_sub)
+    _sub_loop(t_first, t_plain, lambda t: step(t, True))
+    _sub_loop(t_plain, n_sub, lambda t: step(t, False))
 
     @pl.when(qi == n_q - 1)
     def _():
@@ -346,19 +583,102 @@ def _bwd_dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _flash_bwd(q, k, v, mask, out, lse, do, *, scale, causal,
-               block_q, block_k, interpret, precision=None, dlse=None):
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _flash_bwd_dq(q, k, v, mask, do, lse, delta, *, scale, causal,
+                  tiles: Tiles, lane: bool, interpret, precision=None):
+    """dq: grid (bn, q blocks, kv blocks), the inner loop walks K/V."""
     bn, s_q, d = q.shape
-    s_kv = k.shape[1]
-    bq, bk = min(block_q, s_q), min(block_k, s_kv)
-    n_q, n_kv = s_q // bq, s_kv // bk
+    bq, bk, _ = tiles
+    n_q, n_kv = s_q // bq, k.shape[1] // bk
 
+    k_block = _k_block_of(causal, bq, bk, n_kv)
+    q_spec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))
+    kv_spec = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, k_block(i, j), 0))
+    if lane:
+        row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
+        rows = [lse[:, None, :], delta[:, None, :]]
+    else:
+        row_spec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
+        rows = [lse[:, :, None], delta[:, :, None]]
+    kernel = functools.partial(
+        _bwd_dq_kernel, scale=scale, causal=causal, tiles=tiles, n_kv=n_kv,
+        lane_lse=lane, precision=precision)
+    mspec, margs = [], []
+    if mask is None:
+        kernel = functools.partial(kernel, None)
+    else:
+        spec, arg = _mask_operand(mask, tiles, bn // mask.shape[0], k_block)
+        mspec, margs = [spec], [arg]
+    return pl.pallas_call(
+        kernel,
+        name="flash_bwd_dq",
+        grid=(bn, n_q, n_kv),
+        in_specs=mspec + [q_spec, kv_spec, kv_spec, q_spec, row_spec,
+                          row_spec],
+        out_specs=q_spec,
+        out_shape=_sds(q, q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=_compiler_params("dq", tiles, d, q.dtype.itemsize),
+        interpret=interpret,
+    )(*margs, q, k, v, do, *rows)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _flash_bwd_dkv(q, k, v, mask, do, lse, delta, *, scale, causal,
+                   tiles: Tiles, lane: bool, interpret, precision=None):
+    """dk/dv: grid (bn, kv blocks, q blocks), the inner loop walks Q/dO."""
+    bn, s_q, d = q.shape
+    bq, bk, sub = tiles
+    n_q, n_kv = s_q // bq, k.shape[1] // bk
+
+    q_block = _q_block_of(causal, bq, bk, n_q)
+    q_spec = pl.BlockSpec((1, bq, d), lambda b, j, i: (b, q_block(j, i), 0))
+    kv_spec = pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))
+    if lane:
+        # [bn, s_q / sub, 1, sub]: sub-block t of a block is ref[0, t],
+        # lane-major as it is used (the key mask's layout in the other two
+        # kernels, for the same reason)
+        row_spec = pl.BlockSpec((1, bq // sub, 1, sub),
+                                lambda b, j, i: (b, q_block(j, i), 0, 0))
+        rows = [x.reshape(bn, s_q // sub, 1, sub) for x in (lse, delta)]
+    else:
+        row_spec = pl.BlockSpec((1, bq, 1),
+                                lambda b, j, i: (b, q_block(j, i), 0))
+        rows = [lse[:, :, None], delta[:, :, None]]
+    kernel = functools.partial(
+        _bwd_dkv_kernel, scale=scale, causal=causal, tiles=tiles, n_q=n_q,
+        lane_lse=lane, precision=precision)
+    mspec, margs = [], []
+    if mask is None:
+        kernel = functools.partial(kernel, None)
+    else:
+        n_heads = bn // mask.shape[0]
+        mspec = [pl.BlockSpec((1, 1, bk),
+                              lambda b, j, i: (b // n_heads, 0, j))]
+        margs = [mask[:, None, :]]
+    return pl.pallas_call(
+        kernel,
+        name="flash_bwd_dkv",
+        grid=(bn, n_kv, n_q),
+        in_specs=mspec + [q_spec, kv_spec, kv_spec, q_spec, row_spec,
+                          row_spec],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[_sds(q, k.shape, k.dtype),
+                   _sds(q, v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
+        compiler_params=_compiler_params("dkv", tiles, d, q.dtype.itemsize),
+        interpret=interpret,
+    )(*margs, q, k, v, do, *rows)
+
+
+def _flash_bwd(q, k, v, mask, out, lse, do, *, scale, causal, tiling,
+               interpret, precision=None, dlse=None):
     # delta_i = rowsum(dO_i * O_i) — tiny elementwise reduce; let XLA fuse
     # it.  The residual arrays (delta, lse) take the generation-conditional
-    # layout (_lse_lane_major): lane-major [bn, 1, s] where the re-layout
-    # compiles, sublane-major [bn, s, 1] on v4/unknown — same tradeoff as
-    # the forward's lse store.
-    lane = _lse_lane_major()
+    # layout (_lse_lane_major): lane-major where the re-layout compiles,
+    # sublane-major [bn, s, 1] on v4/unknown — same tradeoff as the
+    # forward's lse store.
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)
     if dlse is not None:
@@ -368,70 +688,13 @@ def _flash_bwd(q, k, v, mask, out, lse, do, *, scale, causal,
         # (delta_eff = delta - dlse) reuses both backward kernels
         # untouched.
         delta = delta - dlse.astype(jnp.float32)
-    if lane:
-        delta, lse3 = delta[:, None, :], lse[:, None, :]
-    else:
-        delta, lse3 = delta[:, :, None], lse[:, :, None]
-
-    q_spec_qmajor = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))
-    kv_spec_qmajor = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0))
-    row_spec_qmajor = (
-        pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i)) if lane
-        else pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)))
-
-    common = [q, k, v, do, lse3, delta]
-
-    def with_mask(kernel, index_map):
-        if mask is None:
-            return functools.partial(kernel, None), [], []
-        n_heads = bn // mask.shape[0]
-        spec = pl.BlockSpec((1, 1, bk), functools.partial(index_map, n_heads))
-        return kernel, [spec], [mask[:, None, :]]
-
-    # --- dq: grid (bn, q blocks, kv blocks) ---
-    kernel, mspec, margs = with_mask(
-        _bwd_dq_kernel, lambda h, b, i, j: (b // h, 0, j))
-    dq = pl.pallas_call(
-        functools.partial(kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, n_kv=n_kv,
-                          lane_lse=lane, precision=precision),
-        name="flash_bwd_dq",
-        grid=(bn, n_q, n_kv),
-        in_specs=mspec + [q_spec_qmajor, kv_spec_qmajor, kv_spec_qmajor,
-                          q_spec_qmajor, row_spec_qmajor, row_spec_qmajor],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        out_shape=_sds(q, q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(      # dq carry: kv dim only
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(*margs, *common)
-
-    # --- dk/dv: grid (bn, kv blocks, q blocks) ---
-    q_spec = pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0))
-    kv_spec = pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))
-    row_spec = (pl.BlockSpec((1, 1, bq), lambda b, j, i: (b, 0, i)) if lane
-                else pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)))
-    kernel, mspec, margs = with_mask(
-        _bwd_dkv_kernel, lambda h, b, j, i: (b // h, 0, j))
-    dk, dv = pl.pallas_call(
-        functools.partial(kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, n_q=n_q,
-                          lane_lse=lane, precision=precision),
-        name="flash_bwd_dkv",
-        grid=(bn, n_kv, n_q),
-        in_specs=mspec + [q_spec, kv_spec, kv_spec, q_spec, row_spec,
-                          row_spec],
-        out_specs=[pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-                   pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))],
-        out_shape=[_sds(q, k.shape, k.dtype),
-                   _sds(q, v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(      # dk/dv carry: q dim only
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(*margs, *common)
+    _, dq_tiles, dkv_tiles = tiling
+    common = dict(scale=scale, causal=causal, lane=_lse_lane_major(),
+                  interpret=interpret, precision=precision)
+    dq = _flash_bwd_dq(q, k, v, mask, do, lse, delta, tiles=dq_tiles,
+                       **common)
+    dk, dv = _flash_bwd_dkv(q, k, v, mask, do, lse, delta, tiles=dkv_tiles,
+                            **common)
     return dq, dk, dv
 
 
@@ -440,68 +703,82 @@ def _flash_bwd(q, k, v, mask, out, lse, do, *, scale, causal,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash(q, k, v, mask, causal, block_q, block_k, interpret, precision):
-    out, _ = _flash_fwd(q, k, v, mask, scale=q.shape[-1] ** -0.5,
-                        causal=causal, block_q=block_q, block_k=block_k,
-                        interpret=interpret, precision=precision)
-    return out
-
-
-def _flash_vjp_fwd(q, k, v, mask, causal, block_q, block_k, interpret,
-                   precision):
-    out, lse = _flash_fwd(q, k, v, mask, scale=q.shape[-1] ** -0.5,
-                          causal=causal, block_q=block_q, block_k=block_k,
-                          interpret=interpret, precision=precision)
-    return out, (q, k, v, mask, out, lse)
-
-
-def _flash_vjp_bwd(causal, block_q, block_k, interpret, precision, res, do):
-    q, k, v, mask, out, lse = res
-    dq, dk, dv = _flash_bwd(q, k, v, mask, out, lse, do,
-                            scale=q.shape[-1] ** -0.5, causal=causal,
-                            block_q=block_q, block_k=block_k,
-                            interpret=interpret, precision=precision)
-    return dq, dk, dv, None
-
-
-_flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _flash_lse(q, k, v, mask, causal, block_q, block_k, interpret, precision):
+def _fwd(q, k, v, mask, causal, tiling, interpret, precision):
     return _flash_fwd(q, k, v, mask, scale=q.shape[-1] ** -0.5,
-                      causal=causal, block_q=block_q, block_k=block_k,
+                      causal=causal, tiles=tiling[0], lane=_lse_lane_major(),
                       interpret=interpret, precision=precision)
 
 
-def _flash_lse_vjp_fwd(q, k, v, mask, causal, block_q, block_k, interpret,
-                       precision):
-    out, lse = _flash_fwd(q, k, v, mask, scale=q.shape[-1] ** -0.5,
-                          causal=causal, block_q=block_q, block_k=block_k,
-                          interpret=interpret, precision=precision)
+def _bwd(causal, tiling, interpret, precision, res, do, dlse=None):
+    q, k, v, mask, out, lse = res
+    dq, dk, dv = _flash_bwd(q, k, v, mask, out, lse, do,
+                            scale=q.shape[-1] ** -0.5, causal=causal,
+                            tiling=tiling, interpret=interpret,
+                            precision=precision, dlse=dlse)
+    return dq, dk, dv, None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash(q, k, v, mask, causal, tiling, interpret, precision):
+    return _fwd(q, k, v, mask, causal, tiling, interpret, precision)[0]
+
+
+def _flash_vjp_fwd(q, k, v, mask, causal, tiling, interpret, precision):
+    out, lse = _fwd(q, k, v, mask, causal, tiling, interpret, precision)
+    return out, (q, k, v, mask, out, lse)
+
+
+_flash.defvjp(_flash_vjp_fwd, _bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_lse(q, k, v, mask, causal, tiling, interpret, precision):
+    return _fwd(q, k, v, mask, causal, tiling, interpret, precision)
+
+
+def _flash_lse_vjp_fwd(q, k, v, mask, causal, tiling, interpret, precision):
+    out, lse = _fwd(q, k, v, mask, causal, tiling, interpret, precision)
     return (out, lse), (q, k, v, mask, out, lse)
 
 
-def _flash_lse_vjp_bwd(causal, block_q, block_k, interpret, precision, res,
-                       cots):
-    q, k, v, mask, out, lse = res
-    do, dlse = cots
-    dq, dk, dv = _flash_bwd(q, k, v, mask, out, lse, do,
-                            scale=q.shape[-1] ** -0.5, causal=causal,
-                            block_q=block_q, block_k=block_k,
-                            interpret=interpret, precision=precision,
-                            dlse=dlse)
-    return dq, dk, dv, None
+def _flash_lse_vjp_bwd(causal, tiling, interpret, precision, res, cots):
+    return _bwd(causal, tiling, interpret, precision, res, *cots)
 
 
 _flash_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
 
 
+def _prepare(op, q, k, mask, block_q, block_k, interpret):
+    """What both entry points do before the kernels: refuse a shape that
+    does not tile, choose the tiling, resolve the implementation and
+    record it with the tiling and the grids that engaged."""
+    if not supported(q, k, block_q, block_k):
+        raise ValueError(
+            f"{op}: shapes q={q.shape} k={k.shape} do not tile into "
+            f"block_q={block_q}, block_k={block_k} blocks; use "
+            f"tpuframe.ops.attention.multihead_attention for the fallback")
+    b, s_q, n, d = q.shape
+    s_kv = k.shape[1]
+    tiling = _tiling(s_q, s_kv, d, q.dtype.itemsize, block_q, block_k)
+    said = "; ".join(
+        f"{name} q{t.block_q} k{t.block_k} sub{t.sub} grid "
+        f"{b * n}x{s_q // t.block_q}x{s_kv // t.block_k}"
+        for name, t in zip(_KERNELS, tiling))
+    interpret = kernel_impl.resolve_interpret(
+        op.replace("flash_mha", "flash_attention"), interpret, detail=said)
+    mask = None if mask is None else mask.astype(jnp.int32)
+    return mask, tiling, interpret
+
+
+def _fold(x):  # [B, S, N, D] → [B*N, S, D]
+    b, s, n, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b * n, s, d)
+
+
 def flash_mha_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   mask: jax.Array | None = None, causal: bool = False,
-                  block_q: int = DEFAULT_BLOCK_Q,
-                  block_k: int = DEFAULT_BLOCK_K,
+                  block_q: int | None = None,
+                  block_k: int | None = None,
                   interpret: bool | None = None,
                   precision=None) -> tuple[jax.Array, jax.Array]:
     """:func:`flash_mha` that also returns the logsumexp rows.
@@ -513,27 +790,18 @@ def flash_mha_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
     gradient.  Fully-masked rows report ``lse = NEG_INF`` and zero
     output, so they contribute nothing to a merge.
     """
-    if not supported(q, k, block_q, block_k):
-        raise ValueError(
-            f"flash_mha_lse: shapes q={q.shape} k={k.shape} do not tile "
-            f"into block_q={block_q}, block_k={block_k} blocks")
-    interpret = kernel_impl.resolve_interpret("flash_attention_lse",
-                                              interpret)
+    mask, tiling, interpret = _prepare(
+        "flash_mha_lse", q, k, mask, block_q, block_k, interpret)
     b, s_q, n, d = q.shape
-
-    def fold(x):  # [B, S, N, D] → [B*N, S, D]
-        return x.transpose(0, 2, 1, 3).reshape(b * n, x.shape[1], d)
-
-    mask = None if mask is None else mask.astype(jnp.int32)
-    out, lse = _flash_lse(fold(q), fold(k), fold(v), mask, causal,
-                          block_q, block_k, interpret, precision)
+    out, lse = _flash_lse(_fold(q), _fold(k), _fold(v), mask, causal,
+                          tiling, interpret, precision)
     return (out.reshape(b, n, s_q, d).transpose(0, 2, 1, 3),
             lse.reshape(b, n, s_q))
 
 
 def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
               mask: jax.Array | None = None, causal: bool = False,
-              block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K,
+              block_q: int | None = None, block_k: int | None = None,
               interpret: bool | None = None,
               precision=None) -> jax.Array:
     """Flash multi-head attention.
@@ -541,8 +809,12 @@ def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
     Args:
       q, k, v: ``[batch, seq, heads, head_dim]`` (the attention.py layout).
       mask: optional ``[batch, seq_kv]`` key-padding mask, 1 = attend.
-      causal: apply a causal (autoregressive) mask; above-diagonal key/value
-        blocks are skipped entirely, halving the work.
+      causal: apply a causal (autoregressive) mask; what lies above the
+        diagonal is never computed, halving the work.
+      block_q, block_k: what one grid step owns, for all three kernels;
+        left out, :func:`choose_tiles` picks them per kernel from the
+        shape (unless ``TPUFRAME_FA_BLOCK_Q/K`` or a measured tuning-DB row
+        names them).
       interpret: run under the Pallas interpreter (defaults to True off-TPU,
         which is how the CPU test suite executes this kernel).
       precision: forwarded to every dot inside the kernels (fwd, recompute,
@@ -552,19 +824,9 @@ def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     Returns ``[batch, seq, heads, head_dim]`` attention output in q's dtype.
     """
-    if not supported(q, k, block_q, block_k):
-        raise ValueError(
-            f"flash_mha: shapes q={q.shape} k={k.shape} do not tile into "
-            f"block_q={block_q}, block_k={block_k} blocks; use "
-            f"tpuframe.ops.attention.multihead_attention for the fallback")
-    interpret = kernel_impl.resolve_interpret("flash_attention", interpret)
+    mask, tiling, interpret = _prepare(
+        "flash_mha", q, k, mask, block_q, block_k, interpret)
     b, s_q, n, d = q.shape
-    s_kv = k.shape[1]
-
-    def fold(x):  # [B, S, N, D] → [B*N, S, D]
-        return x.transpose(0, 2, 1, 3).reshape(b * n, x.shape[1], d)
-
-    mask = None if mask is None else mask.astype(jnp.int32)
-    out = _flash(fold(q), fold(k), fold(v), mask, causal,
-                 block_q, block_k, interpret, precision)
+    out = _flash(_fold(q), _fold(k), _fold(v), mask, causal,
+                 tiling, interpret, precision)
     return out.reshape(b, n, s_q, d).transpose(0, 2, 1, 3)

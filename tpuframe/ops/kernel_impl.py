@@ -34,12 +34,17 @@ def interpret_default() -> tuple[bool, str]:
     return backend != "tpu", f"backend={backend}"
 
 
-def resolve_interpret(op: str, interpret: bool | None) -> bool:
+def resolve_interpret(op: str, interpret: bool | None,
+                      detail: str = "") -> bool:
     """Whether ``op``'s kernel runs under the interpreter: the caller's
-    explicit choice, else :func:`interpret_default`; recorded either way."""
+    explicit choice, else :func:`interpret_default`; recorded either way,
+    with ``detail`` (what the kernel chose for itself: its tiling and
+    grid) after the reason."""
     why = "explicit"
     if interpret is None:
         interpret, why = interpret_default()
+    if detail:
+        why = f"{why}; {detail}"
     record(op, "interpret" if interpret else "mosaic", why)
     return interpret
 

@@ -16,7 +16,9 @@ Resolution precedence (docs/DESIGN.md "The tuning subsystem"):
 
     env override  >  measured  >  predicted  >  hard default
 
-and DB resolution only engages when the target TPU generation is named
+(flash-attention blocks skip the predicted tier: the kernel's own shape
+rule is their default, and only a row a chip ran outranks it), and DB
+resolution only engages when the target TPU generation is named
 explicitly (``TPUFRAME_TUNE_GEN``) — a plain CPU test run, and a plain
 chip run, see the hard defaults, untouched.  The generation the device
 itself reports (``tune.roofline.device_generation``) prices MFU rows and
@@ -318,17 +320,19 @@ def _open_for_resolution() -> TuningDB | None:
         return None    # a training run; the analysis gate reports it.
 
 
-def resolve_fa_blocks(default_q: int, default_k: int) -> tuple:
-    """Flash-attention block sizes: env > measured > predicted > default.
-    DB tiers only engage when the target generation is known — plain CPU
-    runs (the whole fast test tier) see the hard defaults."""
+def resolve_fa_blocks(default_q, default_k) -> tuple:
+    """Flash-attention block sizes: env > measured > default.  The default
+    is the caller's (``None`` in ``ops/flash_attention.py``: its shape rule
+    decides); a row that was only ever predicted never outranks it.  The
+    DB tier only engages when the target generation is known — plain CPU
+    runs (the whole fast test tier) see the defaults."""
     q, k = default_q, default_k
     gen = target_generation()
     if gen is not None:
         db = _open_for_resolution()
         if db is not None:
             rec = db.best(family="flash_attention", generation=gen)
-            if rec is not None:
+            if rec is not None and rec.measured:
                 q = int(rec.config.get("fa_block_q", q))
                 k = int(rec.config.get("fa_block_k", k))
     env_q = os.environ.get("TPUFRAME_FA_BLOCK_Q")

@@ -42,47 +42,18 @@ from tpuframe.tune import roofline
 # clears compilation while leaving headroom for Mosaic's own spills.
 DEFAULT_VMEM_BUDGET = 16 * 1024 * 1024
 
-_F32 = 4
-
-
-def _padded_bytes(shape, dtype_bytes: int) -> int:
-    """Mosaic VMEM footprint of one block: minor dim pads to 128 lanes,
-    next-minor to 8 sublanes (the (8,128) tile — same rule as
-    perf/_common.hlo_nbytes)."""
-    dims = list(shape)
-    if not dims:
-        return dtype_bytes
-    dims[-1] = (dims[-1] + 127) // 128 * 128
-    if len(dims) > 1:
-        dims[-2] = (dims[-2] + 7) // 8 * 8
-    n = 1
-    for d in dims:
-        n *= d
-    return n * dtype_bytes
-
-
 def fa_vmem_bytes(block_q: int, block_k: int, head_dim: int, *,
                   dtype_bytes: int = 2) -> int:
-    """Worst-kernel VMEM estimate for one (block_q, block_k) tiling of the
-    flash-attention fwd/bwd kernel trio: 2x every grid-blocked operand
-    (Mosaic double-buffers them all) + f32 accumulator scratch.  Block
-    shapes mirror ops/flash_attention.py's BlockSpecs exactly."""
-    bq, bk, d = block_q, block_k, head_dim
+    """Worst-kernel VMEM estimate for one explicit (block_q, block_k) of
+    the flash-attention fwd/bwd kernel trio: the kernels' own arithmetic
+    (``ops/flash_attention.vmem_bytes``) at the sub-blocks they would take,
+    so the sweep prunes by what the kernels hold and not by a copy of it."""
+    from tpuframe.ops import flash_attention as fa
 
-    def kernel(blocked, scratch):
-        dbl = 2 * sum(_padded_bytes(s, b) for s, b in blocked)
-        return dbl + sum(_padded_bytes(s, b) for s, b in scratch)
-
-    q = ((1, bq, d), dtype_bytes)
-    kv = ((1, bk, d), dtype_bytes)
-    row = ((1, bq, 1), _F32)  # lse / delta rows
-    fwd = kernel([q, kv, kv, q, row],
-                 [((bq, d), _F32), ((bq, 128), _F32), ((bq, 128), _F32)])
-    dq = kernel([q, kv, kv, q, row, row, q],
-                [((bq, d), _F32)])
-    dkv = kernel([q, kv, kv, q, row, row, kv, kv],
-                 [((bk, d), _F32), ((bk, d), _F32)])
-    return max(fwd, dq, dkv)
+    tiling = fa._tiling(block_q, block_k, head_dim, dtype_bytes,
+                        block_q, block_k)
+    return max(fa.vmem_bytes(kernel, tiles, head_dim, dtype_bytes)
+               for kernel, tiles in zip(fa._KERNELS, tiling))
 
 
 def fa_block_candidates(seq_len: int, head_dim: int, *,
