@@ -12,6 +12,9 @@ The slower whole-program compiles (ResNet-50 dp4 step, the sweeps' CLI)
 stay in ``tests/test_aot_tpu_compile.py`` behind ``slow``.
 """
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -136,12 +139,84 @@ def test_fused_conv_bn_bwd_compiles_for_v5e(v5e, h, w, k, c_out):
 
 def test_decode_attention_compiles_for_v5e(v5e):
     """LMEngine's decode attention at the 124M width: 4 slots, a 512-entry
-    ring, 12 heads of 64, bf16 — XLA by design (query length 1)."""
+    ring of columns, 12 heads of 64, bf16 — XLA by design (query length
+    1), and nothing ring-sized beside the rings."""
     from tpuframe.ops import attention as attn_ops
 
     q = jax.ShapeDtypeStruct((4, 1, 12, 64), jnp.bfloat16, sharding=v5e)
-    kv = jax.ShapeDtypeStruct((4, 512, 12, 64), jnp.bfloat16, sharding=v5e)
+    kv = jax.ShapeDtypeStruct((4, 12, 64, 512), jnp.bfloat16, sharding=v5e)
     lengths = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=v5e)
     c = jax.jit(lambda q, k, v, n: attn_ops.decode_attention(
-        q, k, v, lengths=n, impl="pallas")).lower(q, kv, kv, lengths).compile()
+        q, k, v, lengths=n)).lower(q, kv, kv, lengths).compile()
     assert "tpu_custom_call" not in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 4 * 12 * 64 * 512 * 2
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((64, 12, 64, 2048), jnp.bfloat16),   # the serving cell's ring
+    ((64, 12, 64, 2048), jnp.float32),    # tiles of 8 rows, not 16
+    ((8, 4, 16, 128), jnp.float32),       # tiny-lm: 4 heads of 16, one block
+    ((4, 4, 16, 256), jnp.bfloat16),      # a head of one bf16 tile
+    ((200, 576, 1024), jnp.bfloat16),     # a latent's row, slots past 128
+])
+def test_ring_store_compiles_for_v5e(v5e, shape, dtype):
+    """Mosaic takes the store across the rings it has to serve — rows of
+    any leading dimensions, 16- and 32-bit — and writes in place."""
+    from tpuframe.ops import ring_store as rs
+
+    ring = jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    rows = jax.ShapeDtypeStruct(shape[:-1], dtype, sharding=v5e)
+    idx = jax.ShapeDtypeStruct(shape[:1], jnp.int32, sharding=v5e)
+    assert rs.supported(ring, rows)
+    c = jax.jit(lambda r, x, i: rs.ring_store(r, x, i, interpret=False),
+                donate_argnums=0).lower(ring, rows, idx).compile()
+    assert c.as_text().count("tpu_custom_call") == 1
+    m = c.memory_analysis()
+    ring_bytes = math.prod(shape) * jnp.dtype(dtype).itemsize
+    assert m.alias_size_in_bytes == ring_bytes
+    assert m.temp_size_in_bytes < ring_bytes // 8
+
+
+def test_serve_decode_step_stores_in_one_pass_for_v5e(v5e, monkeypatch):
+    """The serving cell's decode program (64 slots, ring 2048, 12 layers of
+    12 x 64, vocabulary 50257, bf16): the KV store is 24 kernel calls on
+    the donated rings — no per-slot ``while`` loop (a scatter's expansion:
+    24 loops of 64 iterations before PR 30), no scatter, no ring-sized
+    copy or temporary."""
+    from tpuframe.models.transformer_lm import LMConfig, TransformerLM
+    from tpuframe.serve import engine as engine_lib
+    from tpuframe.serve import kv_cache as kv
+
+    monkeypatch.setenv("TPUFRAME_PALLAS_INTERPRET", "0")   # lower Mosaic
+    cfg = LMConfig(vocab_size=50257, hidden_size=768, num_layers=12,
+                   num_heads=12, intermediate_size=3072, max_seq=2048,
+                   dtype="bfloat16")
+    model = TransformerLM(cfg)
+    spec = kv.spec_for_model(cfg, slots=64, capacity=2048)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    variables = jax.eval_shape(model.init, jax.random.key(0),
+                               jax.ShapeDtypeStruct((1, 8), jnp.int32))
+    params = jax.tree.map(lambda a: sds(a.shape, a.dtype),
+                          variables["params"])
+    ring = sds(spec.layer_shape(), jnp.dtype(spec.dtype))
+    c = jax.jit(engine_lib.make_decode_fn(model),
+                donate_argnums=(1, 2, 3)).lower(
+        params, sds((64, 1), jnp.int32), sds((64,), jnp.int32),
+        ((ring, ring),) * cfg.num_layers).compile()
+
+    text = c.as_text()
+    assert text.count("tpu_custom_call") == 2 * cfg.num_layers
+    assert " while(" not in text and " scatter(" not in text
+    ring_elems = math.prod(spec.layer_shape())
+    for line in text.splitlines():
+        found = re.search(r"= \w+\[([\d,]+)\]\S* copy\(", line)
+        if found:
+            elems = math.prod(int(d) for d in found.group(1).split(","))
+            assert elems < ring_elems, f"a ring-sized copy: {line.strip()}"
+    m = c.memory_analysis()
+    assert spec.total_bytes() == 4_831_838_208
+    assert m.alias_size_in_bytes >= spec.total_bytes()
+    assert m.temp_size_in_bytes < 64 << 20

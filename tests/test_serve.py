@@ -63,7 +63,8 @@ def _decode_compiled(cfg, slots, capacity, donate=True):
 class TestKVCache:
     def test_spec_shapes_and_bytes(self):
         spec = kv.spec_for_model(TINY, slots=4, capacity=64)
-        assert spec.layer_shape() == (4, 64, TINY.num_heads, TINY.head_dim)
+        # a cached token is a column: the capacity axis is minor
+        assert spec.layer_shape() == (4, TINY.num_heads, TINY.head_dim, 64)
         # K + V, all layers, f32
         assert spec.bytes_per_token() == \
             2 * TINY.num_layers * TINY.num_heads * TINY.head_dim * 4
@@ -168,7 +169,8 @@ class TestGoldenParity:
                                  cfg.vocab_size)
         params = model.init(jax.random.key(1),
                             jnp.zeros((1, 8), jnp.int32))["params"]
-        shape = (1, capacity, cfg.num_heads, cfg.head_dim)
+        shape = kv.spec_for_model(cfg, slots=1,
+                                  capacity=capacity).layer_shape()
         layers = tuple((jnp.zeros(shape), jnp.zeros(shape))
                        for _ in range(cfg.num_layers))
         _, layers = model.apply({"params": params}, ids[:, :8],

@@ -97,7 +97,7 @@ class CausalSelfAttention(nn.Module):
     def __call__(self, x, positions, *, train: bool, kv_cache=None,
                  cache_length=None, decode: bool = False):
         from tpuframe.ops import attention as attn_ops
-        from tpuframe.ops import seq_parallel
+        from tpuframe.ops import ring_store, seq_parallel
 
         c = self.cfg
         dense = lambda name: nn.DenseGeneral(  # noqa: E731
@@ -112,37 +112,42 @@ class CausalSelfAttention(nn.Module):
             # keys, so a wrapped ring slot keeps its original absolute
             # position and wraparound degrades to sliding-window
             # attention rather than silent position corruption.
+            # A ring is [slots, heads, head_dim, capacity]: one token is
+            # a column (serve/kv_cache.py).
             k_cache, v_cache = kv_cache
-            cap = k_cache.shape[1]
+            cap = k_cache.shape[-1]
             if decode:
-                # Ring write: one new token per sequence at its own
-                # write index (modulo capacity), then query-length-1
+                # Ring write: every slot's new column at its own index
+                # (modulo capacity), K's and V's each in one pass over
+                # the slots (ops.ring_store), then query-length-1
                 # attention over the valid prefix.
                 idx = (cache_length % cap).astype(jnp.int32)
-
-                def _write(cache, vec, i):
-                    return lax.dynamic_update_slice(cache, vec, (i, 0, 0))
-
-                k_cache = jax.vmap(_write)(k_cache, k, idx)
-                v_cache = jax.vmap(_write)(v_cache, v, idx)
+                k_cache = ring_store.ring_store(
+                    k_cache, k[:, 0].astype(k_cache.dtype), idx)
+                v_cache = ring_store.ring_store(
+                    v_cache, v[:, 0].astype(v_cache.dtype), idx)
                 valid = jnp.minimum(cache_length + 1, cap)
                 y = attn_ops.decode_attention(q, k_cache, v_cache,
-                                              lengths=valid,
-                                              impl=c.attn_impl)
+                                              lengths=valid)
             else:
                 # Prefill: identical math to the training forward
                 # (causal attention over the left-aligned prompt) plus
-                # the cache write at [0:S] — golden-logits parity with
-                # the training path is by construction, not by test
-                # luck (the test still checks it).
+                # the cache write, the prompt's K/V transposed into
+                # columns [0:S] — golden-logits parity with the training
+                # path is by construction, not by test luck (the test
+                # still checks it).
                 s = x.shape[1]
                 if s > cap:
                     raise ValueError(f"prompt bucket {s} exceeds "
                                      f"KV-cache capacity {cap}")
+
+                def columns(t):   # [B, S, N, D] -> [B, N, D, S]
+                    return t.transpose(0, 2, 3, 1)
+
                 k_cache = lax.dynamic_update_slice(
-                    k_cache, k.astype(k_cache.dtype), (0, 0, 0, 0))
+                    k_cache, columns(k).astype(k_cache.dtype), (0, 0, 0, 0))
                 v_cache = lax.dynamic_update_slice(
-                    v_cache, v.astype(v_cache.dtype), (0, 0, 0, 0))
+                    v_cache, columns(v).astype(v_cache.dtype), (0, 0, 0, 0))
                 y = attn_ops.multihead_attention(q, k, v, causal=True,
                                                  impl=c.attn_impl)
             out = nn.DenseGeneral(c.hidden_size, axis=(-2, -1),
@@ -350,12 +355,13 @@ class TransformerLM(nn.Module):
         the lm_head parameters exist.
 
         Serving path (tpuframe.serve): ``kv_cache`` is a per-layer tuple
-        of ``(k, v)`` pairs, each ``[B, capacity, N, D]``; ``cache_length``
+        of ``(k, v)`` pairs, each ``[B, N, D, capacity]``; ``cache_length``
         ``[B]`` counts tokens already cached.  ``decode=False`` prefills a
         left-aligned (padded) prompt — same math as the training forward —
         writing every layer's K/V; ``decode=True`` runs ONE new token per
         sequence through the query-length-1 attention entry
-        (ops.attention.decode_attention) at its own ring write index.
+        (ops.attention.decode_attention) after storing its K/V column at
+        its own ring index (ops.ring_store).
         Returns ``(logits, new_kv_cache)``.  Sequence parallelism and MoE
         do not compose with the cache path (serving shards over batch)."""
         c = self.cfg

@@ -60,34 +60,42 @@ def multihead_attention(
 
 def decode_attention(
     q: jax.Array,        # [B, 1, N, D] — the query-length-1 decode entry
-    k_cache: jax.Array,  # [B, S_kv, N, D] — KV-cache keys (post-RoPE)
-    v_cache: jax.Array,  # [B, S_kv, N, D]
+    k_cache: jax.Array,  # [B, N, D, S_kv] — the ring of post-RoPE keys
+    v_cache: jax.Array,  # [B, N, D, S_kv]
     *,
     lengths: jax.Array,  # [B] int32 — valid cache entries per sequence
-    impl: str | None = None,
 ) -> jax.Array:
-    """Decode-mode attention: one new query token against the KV-cache.
+    """Decode-mode attention: one new query token against the KV ring.
 
     The serving counterpart of :func:`multihead_attention`
-    (tpuframe.serve).  Causality is a *length mask*, not a triangle: the
-    cache holds exactly the tokens the new position may attend, padded to
-    the cache's bucketed capacity, so the mask is ``arange(S_kv) <
-    lengths`` per sequence.  The flash kernel's advantage — keeping the
-    S×S score matrix out of HBM — is moot at query length 1 (scores are
-    [B, N, 1, S_kv], KV-cache-row-sized); the einsum formulation IS the
-    memory-optimal decode program, and every HBM byte the step moves is
-    cache+params, which the serve roofline (tune/roofline.decode_score)
-    models directly.  ``impl`` is accepted for parity with the training
-    entry: pallas falls back to xla here because ``flash_attention
-    .supported`` rejects query length 1 (sublane-unaligned), by design.
+    (tpuframe.serve), on the ring's own layout (serve/kv_cache.py): a
+    cached token is a column, the capacity axis is minor.  Causality is a
+    *length mask*, not a triangle: the ring holds exactly the tokens the
+    new position may attend, padded to its bucketed capacity, so the mask
+    is ``arange(S_kv) < lengths`` per sequence.  The flash kernel has
+    nothing to keep out of HBM at query length 1 (scores are [B, N, 1,
+    S_kv]), so this is the einsum formulation, which XLA runs as two loop
+    fusions a layer that each read one whole ring once, at the chip's
+    bandwidth.  That is all of every ring every step, whatever the slots
+    hold: reading only the occupied part takes a kernel of its own
+    (ROADMAP S9).  Same math as :func:`_xla_attention` with a key mask.
     """
     if q.ndim != 4 or q.shape[1] != 1:
         raise ValueError(f"decode_attention wants q [B, 1, N, D]; "
                          f"got {q.shape}")
-    s_kv = k_cache.shape[1]
-    mask = (jnp.arange(s_kv)[None, :] < lengths[:, None]).astype(jnp.int32)
-    return multihead_attention(q, k_cache, v_cache, mask=mask,
-                               causal=False, impl=impl)
+    b, _, n, d = q.shape
+    s_kv = k_cache.shape[-1]
+    if k_cache.shape != (b, n, d, s_kv) or v_cache.shape != k_cache.shape:
+        raise ValueError(f"decode_attention wants rings [B, N, D, S_kv] = "
+                         f"[{b}, {n}, {d}, S_kv]; got {k_cache.shape}, "
+                         f"{v_cache.shape}")
+    scale = 1.0 / jnp.sqrt(d).astype(q.dtype)
+    scores = jnp.einsum("bqnd,bndk->bnqk", q * scale, k_cache,
+                        preferred_element_type=jnp.float32)
+    mask = jnp.arange(s_kv)[None, :] < lengths[:, None]
+    scores = jnp.where(mask[:, None, None, :], scores, jnp.float32(-1e9))
+    probs = jax.nn.softmax(scores, axis=-1).astype(v_cache.dtype)
+    return jnp.einsum("bnqk,bndk->bqnd", probs, v_cache)
 
 
 def _xla_attention(q, k, v, *, mask, dropout_rate, dropout_rng):

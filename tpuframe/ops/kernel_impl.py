@@ -19,15 +19,21 @@ import os
 _resolved: dict[str, dict[str, str]] = {}
 
 
+def interpret_env() -> bool | None:
+    """What ``TPUFRAME_PALLAS_INTERPRET`` says, or None when it is unset."""
+    env = os.environ.get("TPUFRAME_PALLAS_INTERPRET")
+    return None if env is None else env == "1"
+
+
 def interpret_default() -> tuple[bool, str]:
     """``(interpret, why)`` for a kernel whose caller did not say.
 
     ``TPUFRAME_PALLAS_INTERPRET`` overrides the backend check: compiling
     FOR a described TPU FROM a CPU host must lower Mosaic, where the
     backend alone would pick the interpreter."""
-    env = os.environ.get("TPUFRAME_PALLAS_INTERPRET")
+    env = interpret_env()
     if env is not None:
-        return env == "1", f"TPUFRAME_PALLAS_INTERPRET={env}"
+        return env, f"TPUFRAME_PALLAS_INTERPRET={int(env)}"
     import jax
 
     backend = jax.default_backend()

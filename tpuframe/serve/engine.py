@@ -12,12 +12,16 @@ time against the closed set of bucketed shapes from ``serve.kv_cache``:
               ``length - 1``.
   decode      (params, toks[S, 1], lengths[S], cache) -> updated triple
               one program total — the query-length-1 step over all
-              ``S`` slots at once, ring-writing each slot's KV at its
-              own index (ops.attention.decode_attention).  Cache,
-              lengths and token buffers are DONATED: the executable
-              updates HBM in place, so a decode step's traffic is
-              exactly params + touched KV — the quantity the roofline
-              bound (tune/roofline.decode_score) models.
+              ``S`` slots at once: each layer stores every slot's new
+              K/V column at the slot's own ring index in one pass
+              (ops.ring_store), then attends over the ring
+              (ops.attention.decode_attention).  Cache, lengths and
+              token buffers are DONATED: the executable updates HBM in
+              place.  A step's traffic is the params once, the lane
+              blocks the store touches, and the WHOLE of every ring —
+              attention reads all ``capacity`` entries of all slots
+              whatever they hold, which is more than the touched KV the
+              roofline bound (tune/roofline.decode_score) models.
   insert      (cache, lengths, toks, pcache, slot, len, tok) -> updated
               one program total — copies a finished prefill's
               single-slot cache into the shared decode cache at a
@@ -46,7 +50,7 @@ def make_prefill_fn(model, spec: kv.CacheSpec):
     insertion is a single batch-dim slice copy."""
     import jax.numpy as jnp
 
-    shape = (1, spec.capacity, spec.num_heads, spec.head_dim)
+    shape = (1,) + spec.layer_shape()[1:]
     dtype = jnp.dtype(spec.dtype)
 
     def prefill_fn(params, ids, length):
@@ -64,7 +68,7 @@ def make_prefill_fn(model, spec: kv.CacheSpec):
 
 
 def make_decode_fn(model):
-    """The decode step program: one token for every slot, ring KV write,
+    """The decode step program: one token for every slot, ring KV store,
     greedy argmax.  ``lengths`` advances for every slot (inactive slots
     decode garbage the scheduler ignores — branchless beats a per-slot
     cond on TPU, and the ring write keeps wraparound safe)."""
@@ -430,7 +434,8 @@ def golden_parity_diffs(cfg, *, buckets, capacity: int,
                                     jnp.zeros((1, 8), jnp.int32))["params"]
             ref = model.apply({"params": params}, ids)
 
-            shape = (1, capacity, cfg.num_heads, cfg.head_dim)
+            shape = kv.spec_for_model(cfg, slots=1,
+                                      capacity=capacity).layer_shape()
             layers = tuple(
                 (jnp.zeros(shape, cfg.jnp_dtype),
                  jnp.zeros(shape, cfg.jnp_dtype))

@@ -1,12 +1,21 @@
 """Paged/ring KV-cache for the decode path (tpuframe.serve).
 
 The cache is the serving counterpart of a training batch: per layer one
-``(k, v)`` pair of ``[slots, capacity, num_heads, head_dim]`` arrays plus
-a ``lengths [slots]`` vector counting tokens already cached per slot.
+``(k, v)`` pair of ``[slots, num_heads, head_dim, capacity]`` arrays
+plus a ``lengths [slots]`` vector counting tokens already cached per
+slot.  The capacity axis is minor — one token is a column of
+``num_heads x head_dim`` numbers — which is the layout decode attention
+reads at the chip's bandwidth (its reductions run over whole lanes of
+cached tokens) and the one whose row-major form a Mosaic kernel can take
+as it is (a ring of ``[.., capacity, 12, 64]`` would reach it padded to
+16 x 128, 2.7 times the memory).
 It is deliberately a *plain pytree of arrays*, not an object the model
 mutates: the engine threads it functionally through the AOT-compiled
 prefill/decode executables (arrays in, updated arrays out), which is
-what makes buffer donation — and therefore in-place HBM updates — legal.
+what makes buffer donation legal.  With the buffers donated, insert's
+slot copy and the decode step's store (``ops.ring_store``: every slot's
+new column in one pass over the slots, one lane block in and out per
+slot) update HBM in place; nothing ring-sized is copied.
 
 Ring semantics: the model writes token ``t`` at index ``t % capacity``
 and masks attention to ``min(t + 1, capacity)`` valid entries, so a
@@ -55,7 +64,8 @@ class CacheSpec:
             raise ValueError(f"need at least one slot, got {self.slots}")
 
     def layer_shape(self) -> tuple:
-        return (self.slots, self.capacity, self.num_heads, self.head_dim)
+        """One layer's K (or V) ring: a token is a column."""
+        return (self.slots, self.num_heads, self.head_dim, self.capacity)
 
     def bytes_per_token(self) -> int:
         """HBM bytes one cached token costs across all layers (K + V) —
