@@ -60,7 +60,6 @@ def run(batch_per_chip: int, warmup: int, measure: int) -> dict:
     from tpuframe import elastic, mem, models
     from tpuframe.models import losses
     from tpuframe.parallel import mesh as mesh_lib
-    from tpuframe.parallel import quantwire
     from tpuframe.parallel import step as step_lib
     from tpuframe.parallel import zero1 as zero1_lib
     from tpuframe.tune import db as tune_db
@@ -90,23 +89,18 @@ def run(batch_per_chip: int, warmup: int, measure: int) -> dict:
     # A/B knobs, each env > tuning DB (TPUFRAME_TUNE_GEN only) > default:
     # TPUFRAME_BENCH_STEM=space_to_depth, TPUFRAME_BENCH_BN=folded,
     # TPUFRAME_REMAT_POLICY, TPUFRAME_WEIGHT_UPDATE=zero1,
-    # TPUFRAME_WIRE_FORMAT=int8-block, TPUFRAME_XLA_OPTS="k=v,k=v".
+    # TPUFRAME_XLA_OPTS="k=v,k=v".
     stem = os.environ.get("TPUFRAME_BENCH_STEM", "conv")
     bn = os.environ.get("TPUFRAME_BENCH_BN", "flax")
     remat_policy, remat_source = mem.resolve(
         program=program, family="remat_resnet50")
     weight_update, wu_source = zero1_lib.resolve(
         program=program, family="weight_update_resnet50")
-    wire_format, wf_source = quantwire.resolve(
-        program=program, family="wire_format_resnet50")
-    if mesh is None:
-        # single-chip run: nothing to shard the update over and no wire to
-        # quantize — a DB row must never break a run, but an explicit env
-        # ask gets make_train_step's error.
-        if weight_update == "zero1" and wu_source != "env":
-            weight_update = "replicated"
-        if wire_format != "fp" and wf_source != "env":
-            wire_format = "fp"
+    if mesh is None and weight_update == "zero1" and wu_source != "env":
+        # single-chip run: nothing to shard the update over — a DB row
+        # must never break a run, but an explicit env ask gets
+        # make_train_step's error.
+        weight_update = "replicated"
     try:
         xla_opts = xla_opts_lib.from_env()
     except ValueError as e:
@@ -115,7 +109,7 @@ def run(batch_per_chip: int, warmup: int, measure: int) -> dict:
         xla_opts = tune_db.resolve_xla_opts(
             f"bench_resnet50_b{batch_per_chip}", family="bench_resnet50")
     _log(f"remat={remat_policy} ({remat_source}) weight_update="
-         f"{weight_update} wire_format={wire_format} xla_opts={xla_opts}")
+         f"{weight_update} xla_opts={xla_opts}")
 
     model = models.ResNet50(num_classes=1000, dtype=jnp.bfloat16, stem=stem,
                             bn=bn)
@@ -142,7 +136,7 @@ def run(batch_per_chip: int, warmup: int, measure: int) -> dict:
     train_step = step_lib.make_train_step(
         loss_fn, tx, mesh, donate=True, compiler_options=xla_opts,
         remat_policy=None if remat_policy == "none" else remat_policy,
-        weight_update=weight_update, wire_format=wire_format)
+        weight_update=weight_update)
 
     if mesh is not None:
         if weight_update == "zero1":
@@ -199,8 +193,6 @@ def run(batch_per_chip: int, warmup: int, measure: int) -> dict:
         line["policy"] = remat_policy
     if weight_update != "replicated":
         line["weight_update"] = weight_update
-    if wire_format != "fp":
-        line["wire_format"] = wire_format
     return line
 
 

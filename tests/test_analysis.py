@@ -21,6 +21,7 @@ psum-transpose rewrite; the explicit grad reduction must keep the dp
 step bit-comparable to the single-device step).
 """
 
+import functools
 import pathlib
 import textwrap
 
@@ -830,13 +831,97 @@ def test_shipped_tree_self_lints_clean():
 # ---------------------------------------------------------------------------
 
 
+# One compile per strategy: the budget test and the golden-loss test
+# below share the audit (and with it the AOT executable).
+_audit = functools.cache(strategies.audit_strategy)
+
+
 @pytest.mark.parametrize("name", sorted(strategies.STRATEGIES))
 def test_strategy_step_program_fits_declared_budget(name):
-    audit = strategies.audit_strategy(name)
+    audit = _audit(name)
     if audit.status == "unavailable":
         pytest.skip(audit.reason)
     assert audit.status == "ok", str(audit)
     assert audit.report is not None and audit.budget is not None
+
+
+# Golden loss per surviving training strategy: the audited executable
+# itself, run for real on the 8-device CPU mesh, must reproduce the
+# single-device losses of the same model, init and batch.
+_GOLDEN_STEPS = 3
+
+
+@functools.cache
+def _golden_reference(kind, batch_shape, rows_equal):
+    """(params, tx, batch, single-device losses) for one toy program."""
+    from tpuframe.models import losses
+    from tpuframe.models.transformer_lm import LMConfig, ScanBlockLM
+
+    if kind.startswith("pp"):
+        # _pp_build's model; the caller's shape assert pins the copy.
+        model = ScanBlockLM(LMConfig.tiny(
+            vocab_size=64, hidden_size=32, num_layers=int(kind[2:]),
+            num_heads=2, intermediate_size=64, max_seq=16))
+        tx = optax.adamw(1e-3)
+
+        def loss_fn(params, model_state, b, rng):
+            logits = model.apply({"params": params}, b["input_ids"])
+            return (losses.softmax_cross_entropy(logits, b["labels"]),
+                    ({}, {}))
+    else:
+        pieces = (strategies._moe_pieces if kind == "moe"
+                  else strategies._lm_pieces)
+        model, loss_fn, tx, *_ = pieces()
+    b, s = batch_shape
+    # adasum(g, g) == g: its single-device twin exists only when every
+    # replica holds the same row, so that is the batch it is run on.
+    ids = np.random.default_rng(0).integers(
+        0, 64, size=(1 if rows_equal else b, s + 1))
+    ids = np.broadcast_to(ids, (b, s + 1)).astype(np.int32)
+    batch = {"input_ids": jnp.asarray(ids[:, :-1]),
+             "labels": jnp.asarray(ids[:, 1:])}
+    params = model.init(jax.random.key(0), batch["input_ids"][:1])["params"]
+    step = step_lib.make_train_step(loss_fn, tx, None, donate=False)
+    state = step_lib.TrainState.create(params, tx)
+    want = []
+    for _ in range(_GOLDEN_STEPS):
+        state, m = step(state, batch)
+        want.append(float(m["loss"]))
+    return params, tx, batch, want
+
+
+@pytest.mark.parametrize("name", sorted(
+    n for n in strategies.STRATEGIES if n != "serve-dp-decode"))
+def test_strategy_golden_loss_matches_single_device(name):
+    import dataclasses
+
+    from tpuframe.parallel import pspec
+
+    audit = _audit(name)
+    if audit.status == "unavailable":
+        pytest.skip(audit.reason)
+    builder = strategies.STRATEGIES[name]
+    _, (state_sds, batch_sds), *_ = builder(8)
+    spec = pspec.parse_spec(builder.args[0])
+    kind = (f"pp{spec.pp}" if spec.pp > 1
+            else "moe" if spec.ep > 1 else "lm")
+    params, tx, batch, want = _golden_reference(
+        kind, batch_sds["input_ids"].shape,
+        builder.keywords.get("grad_reduce") == "adasum")
+    assert (jax.tree.map(lambda a: a.shape, params)
+            == jax.tree.map(lambda a: a.shape, state_sds.params))
+    # adamw starts from zeros, so the strategy's own optimizer-state
+    # layout (replicated, or zero1's flat padded vectors) fills as zeros.
+    state = dataclasses.replace(
+        step_lib.TrainState.create(jax.tree.map(jnp.copy, params), tx),
+        opt_state=jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype),
+                               state_sds.opt_state))
+    got = []
+    for _ in range(_GOLDEN_STEPS):
+        state, m = audit.compiled(state, batch)
+        got.append(float(m["loss"]))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    assert want[-1] < want[0], "training should make progress"
 
 
 def test_dp_audit_sees_the_gradient_allreduce():

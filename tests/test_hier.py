@@ -1,14 +1,11 @@
 """The hierarchical-collective seam end to end: resolution precedence
-(env > tuning DB > default, stale rows demote silently) for both the
-hier mode and the per-fabric wire legs, the TF124 slice-axis seam lint,
+(env > tuning DB > default, stale rows demote silently) for the hier
+mode, the TF124 slice-axis seam lint,
 fabric attribution of the compiled two-level lowering (in-slice groups
 on ICI, cross-slice groups on DCN), byte-exact derived-budget pins of
 the 1/n_inner DCN law, golden-loss parity of hier vs flat for both
 weight-update modes, the compose-rejection matrix, the MegaScale
 host-transfer DCN parser, and the compare differ's DCN regression rule.
-
-Numerics use the legacy ``jax.experimental.shard_map`` idiom
-(``check_rep=False``) so the suite runs on pre-vma jax too.
 """
 
 import re
@@ -20,7 +17,7 @@ import pytest
 
 from tpuframe.analysis import collective_graph as cg
 from tpuframe.analysis import hlo_audit, shardflow, source_lint
-from tpuframe.parallel import hier, quantwire, step as step_lib, zero1
+from tpuframe.parallel import hier, step as step_lib, zero1
 from tpuframe.tune import db as tune_db
 
 
@@ -35,17 +32,15 @@ def smesh():
 
 
 # ---------------------------------------------------------------------------
-# Resolution precedence: env > tune_db > default, per knob and per leg.
+# Resolution precedence: env > tune_db > default.
 # ---------------------------------------------------------------------------
 
 
-def _hier_rec(program="train_lm_b8", gen="v5e", mode="hier",
-              fmt_dcn="int8-block"):
+def _hier_rec(program="train_lm_b8", gen="v5e", mode="hier"):
     return {"program": program, "family": "hier_collectives",
             "fingerprint": "fp0", "topology": "v5e:2x2",
             "generation": gen,
-            "config": {"hier": mode, "wire_format_dcn": fmt_dcn,
-                       "batch": 8, "weight_update": "replicated",
+            "config": {"hier": mode, "batch": 8, "weight_update": "replicated",
                        "slices": 2},
             "predicted": {"predicted_ms": 1.0, "bound": "hbm",
                           "fits": True, "vmem_bytes": 0,
@@ -54,7 +49,7 @@ def _hier_rec(program="train_lm_b8", gen="v5e", mode="hier",
 
 @pytest.fixture
 def hier_db(tmp_path, monkeypatch):
-    """A tuning DB with one swept hier/int8-dcn winner, wired into the
+    """A tuning DB with one swept hier winner, wired into the
     env the way the resolution chain reads it; the generation gate is
     left CLOSED (no gen env) — tests open it explicitly."""
     path = str(tmp_path / "tune_db.json")
@@ -63,8 +58,6 @@ def hier_db(tmp_path, monkeypatch):
     db.save()
     monkeypatch.setenv("TPUFRAME_TUNE_DB", path)
     monkeypatch.delenv("TPUFRAME_HIER", raising=False)
-    monkeypatch.delenv("TPUFRAME_WIRE_FORMAT", raising=False)
-    monkeypatch.delenv("TPUFRAME_WIRE_FORMAT_DCN", raising=False)
     monkeypatch.delenv("TPUFRAME_TUNE_GEN", raising=False)
     return path
 
@@ -112,21 +105,6 @@ class TestResolution:
         monkeypatch.setenv("TPUFRAME_TUNE_GEN", "v5e")
         assert hier.resolve("train_lm_b8", "hier_collectives") \
             == ("flat", "default")
-
-    def test_dcn_leg_resolves_from_hier_family(self, hier_db,
-                                               monkeypatch):
-        monkeypatch.setenv("TPUFRAME_TUNE_GEN", "v5e")
-        ici, dcn = quantwire.resolve_legs(
-            "train_lm_b8", family_dcn="hier_collectives")
-        assert ici == ("fp", "default")
-        assert dcn == ("int8-block", "tune_db")
-
-    def test_dcn_env_beats_db(self, hier_db, monkeypatch):
-        monkeypatch.setenv("TPUFRAME_TUNE_GEN", "v5e")
-        monkeypatch.setenv("TPUFRAME_WIRE_FORMAT_DCN", "fp")
-        _ici, dcn = quantwire.resolve_legs(
-            "train_lm_b8", family_dcn="hier_collectives")
-        assert dcn == ("fp", "env")
 
     def test_self_check_clean(self, monkeypatch):
         monkeypatch.delenv(hier.ENV_VAR, raising=False)
@@ -193,9 +171,8 @@ class TestTF124:
 def test_derived_budget_hier_dcn_law():
     """The checked-in derived budgets must show the two-level shape
     exactly: the in-slice reduce-scatter and all-gather carry the full
-    gradient payload, the cross-slice all-reduce carries payload /
-    n_inner (n_inner = 4 on the 2-slice 8-device mesh), and the
-    int8-block DCN leg carries payload / (4 * n_inner)."""
+    gradient payload and the cross-slice all-reduce carries payload /
+    n_inner (n_inner = 4 on the 2-slice 8-device mesh)."""
     flat = shardflow.derived_for("spec:dp=*;slices=2")
     h = shardflow.derived_for("spec:dp=*;slices=2+hier")
     if flat is None or h is None:
@@ -210,22 +187,18 @@ def test_derived_budget_hier_dcn_law():
     flat_ar = flat["kinds"]["all-reduce"]["bytes"]
     assert 2 * ar <= flat_ar, (ar, flat_ar)
 
-    h8 = shardflow.derived_for("spec:dp=*;slices=2+hier+dcn-int8")
-    if h8 is not None:
-        a2a = h8["above_floor"].get("all-to-all", 0)
-        assert a2a > 0 and a2a * 16 == rs, (a2a, rs)
-
 
 def test_derived_budget_zero1_hier_dcn_law():
     z = shardflow.derived_for("spec:dp=*;slices=2+zero1")
-    z8 = shardflow.derived_for("spec:dp=*;slices=2+zero1+hier+dcn-int8")
-    if z is None or z8 is None:
+    zh = shardflow.derived_for("spec:dp=*;slices=2+zero1+hier")
+    if z is None or zh is None:
         pytest.skip("derived budgets not emitted for this jax")
-    rs = z["above_floor"].get("reduce-scatter", 0)
-    a2a = z8["above_floor"].get("all-to-all", 0)
-    # zero1's scatter already pays the full payload once in-slice; the
-    # quantized cross-slice exchange moves 1/16 of it.
-    assert rs > 0 and a2a > 0 and a2a * 16 == rs, (a2a, rs)
+    # The two-stage scatter/gather pay the full padded payload in-slice
+    # plus the 1/n_inner chunk across slices: flat * (1 + 1/4) per kind.
+    for kind in ("reduce-scatter", "all-gather"):
+        flat_b = z["above_floor"].get(kind, 0)
+        assert flat_b > 0, z["above_floor"]
+        assert zh["above_floor"].get(kind, 0) * 4 == flat_b * 5, (kind, zh)
 
 
 # ---------------------------------------------------------------------------
@@ -243,9 +216,8 @@ def _make_loss():
 
 
 def _init_params(key):
-    # w1 is sized so its cross-slice shard (size / n_inner = 2048
-    # elems) clears quantwire's MIN_QUANT_ELEMS floor — smaller leaves
-    # ride the DCN leg in fp by design.
+    # w1 clears hier.MIN_TWO_LEVEL_ELEMS, so it takes the two-level
+    # lowering; the biases keep the flat cross-slice mean by design.
     k1, k2 = jax.random.split(key)
     return {"w1": jax.random.normal(k1, (64, 128)) * 0.1,
             "b1": jnp.zeros((128,)),
@@ -253,7 +225,7 @@ def _init_params(key):
             "b2": jnp.zeros((8,))}
 
 
-def _lower_hlo(mesh, hier_mode, fmt_dcn="fp", weight_update="replicated"):
+def _lower_hlo(mesh, hier_mode, weight_update="replicated"):
     import optax
 
     tx = optax.sgd(0.05)
@@ -266,7 +238,6 @@ def _lower_hlo(mesh, hier_mode, fmt_dcn="fp", weight_update="replicated"):
     train = step_lib.make_train_step(_make_loss(), tx, mesh,
                                      weight_update=weight_update,
                                      hier=hier_mode,
-                                     wire_format_dcn=fmt_dcn,
                                      donate=False)
     x = jnp.zeros((64, 64))
     y = jnp.zeros((64, 8))
@@ -292,11 +263,6 @@ class TestCompiledFabricSplit:
         assert h["ici_bytes"] > 0, h
         assert 2 * h["dcn_bytes"] <= flat["dcn_bytes"], (h, flat)
 
-    def test_int8_dcn_leg_cuts_deeper(self, smesh):
-        h = _split(_lower_hlo(smesh, "hier"))
-        h8 = _split(_lower_hlo(smesh, "hier", fmt_dcn="int8-block"))
-        assert h8["dcn_bytes"] < h["dcn_bytes"], (h8, h)
-
     def test_two_level_replica_groups_materialize(self, smesh):
         # slice-major device order: in-slice groups are the contiguous
         # quads, cross-slice groups the stride-4 pairs.
@@ -309,13 +275,11 @@ class TestCompiledFabricSplit:
 
 
 # ---------------------------------------------------------------------------
-# Golden loss: the two-level mean must track the flat mean exactly, and
-# the int8 DCN leg within the quantized-wire acceptance bound.
+# Golden loss: the two-level mean must track the flat mean exactly.
 # ---------------------------------------------------------------------------
 
 
-def _run(mesh, hier_mode, fmt_dcn="fp", weight_update="replicated",
-         steps=25):
+def _run(mesh, hier_mode, weight_update="replicated", steps=25):
     import optax
 
     tx = optax.sgd(0.05, momentum=0.9)
@@ -328,7 +292,6 @@ def _run(mesh, hier_mode, fmt_dcn="fp", weight_update="replicated",
     train = step_lib.make_train_step(_make_loss(), tx, mesh,
                                      weight_update=weight_update,
                                      hier=hier_mode,
-                                     wire_format_dcn=fmt_dcn,
                                      donate=False)
     key = jax.random.key(2)
     w_true = jax.random.normal(jax.random.key(7), (64, 8))
@@ -353,19 +316,6 @@ def test_golden_loss_hier_matches_flat(smesh, weight_update):
     assert d.max() <= 1e-4, (weight_update, d.max())
 
 
-@pytest.mark.parametrize("weight_update", ["replicated", "zero1"])
-def test_golden_loss_int8_dcn_tracks_flat(smesh, weight_update):
-    """int8 on the DCN leg only: the documented quantized-wire bound
-    (per-step |loss| delta <= 2e-3), same as the program-wide int8 wire
-    it borrows its quantizer from."""
-    l_flat = _run(smesh, "flat", weight_update=weight_update)
-    l_q = _run(smesh, "hier", fmt_dcn="int8-block",
-               weight_update=weight_update)
-    assert l_q[-1] < l_flat[0], "int8-dcn run did not train"
-    d = np.abs(l_q - l_flat)
-    assert d.max() <= 2e-3, (weight_update, d.max())
-
-
 # ---------------------------------------------------------------------------
 # Compose rejections: the matrix is an API contract, not advice.
 # ---------------------------------------------------------------------------
@@ -385,30 +335,6 @@ class TestComposeRejections:
         with pytest.raises(ValueError, match="adasum"):
             step_lib.make_train_step(_make_loss(), optax.sgd(0.1), smesh,
                                      grad_reduce="adasum", hier="hier")
-
-    def test_hier_rejects_program_wide_int8(self, smesh):
-        import optax
-
-        with pytest.raises(ValueError, match="wire_format_dcn"):
-            step_lib.make_train_step(_make_loss(), optax.sgd(0.1), smesh,
-                                     wire_format="int8-block",
-                                     hier="hier")
-
-    def test_dcn_wire_needs_hier(self, smesh):
-        import optax
-
-        with pytest.raises(ValueError, match="hier"):
-            step_lib.make_train_step(_make_loss(), optax.sgd(0.1), smesh,
-                                     wire_format_dcn="int8-block")
-
-    def test_dcn_wire_rejects_fusion(self, smesh):
-        import optax
-
-        with pytest.raises(ValueError, match="fusion_threshold"):
-            step_lib.make_train_step(_make_loss(), optax.sgd(0.1), smesh,
-                                     hier="hier",
-                                     wire_format_dcn="int8-block",
-                                     fusion_threshold=65536)
 
 
 # ---------------------------------------------------------------------------

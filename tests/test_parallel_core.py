@@ -143,6 +143,55 @@ class TestCollectives:
             np.testing.assert_array_equal(np.asarray(fn(x)), np.asarray(x))
 
 
+_GATHER_MESHES = {
+    "data8": mesh_lib.MeshSpec(data=8),
+    "data4-fsdp2": mesh_lib.MeshSpec(data=4, fsdp=2),
+    "slice2-data4": mesh_lib.MeshSpec(data=4, slices=2),
+}
+
+
+class TestAllgatherInvariant:
+    @pytest.mark.parametrize("tiled", [True, False],
+                             ids=["tiled", "stacked"])
+    @pytest.mark.parametrize("gather_axis", [0, 1])
+    @pytest.mark.parametrize("mesh_name", sorted(_GATHER_MESHES))
+    def test_full_array_on_every_replica(self, mesh_name, gather_axis,
+                                         tiled):
+        """Under a replicated out_spec the gather must come back
+        invariant — a varying ``lax.all_gather`` is refused there — and
+        every replica must hold the whole array."""
+        mesh = mesh_lib.make_mesh(_GATHER_MESHES[mesh_name])
+        axes = mesh_lib.batch_axes(mesh)
+        x = np.arange(16.0 * 24).reshape(16, 24).astype(np.float32)
+        in_spec = P(axes) if gather_axis == 0 else P(None, axes)
+
+        def body(block):
+            return collectives.allgather_invariant(
+                block, axes, gather_axis=gather_axis, tiled=tiled)
+
+        out = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_spec,
+                                    out_specs=P()))(x)
+        want = x if tiled else (
+            x.reshape(8, 2, 24) if gather_axis == 0
+            else x.reshape(16, 8, 3))
+        assert len(out.addressable_shards) == 8
+        for shard in out.addressable_shards:
+            np.testing.assert_array_equal(np.asarray(shard.data), want)
+
+    def test_invariant_gather_lives_where_imported(self):
+        """jax 0.9.0 keeps the Varying -> Invariant gather in
+        ``jax._src.lax.parallel`` and does not export it under
+        ``jax.lax``, so collectives.py imports it from there.  When this
+        fails a jax upgrade moved or published the name: point that one
+        import at where it lives now."""
+        import importlib
+
+        home = importlib.import_module("jax._src.lax.parallel")
+        assert collectives._all_gather_invariant is home.all_gather_invariant
+        assert not hasattr(lax, "all_gather_invariant"), \
+            "jax exports it now: import it from jax.lax in collectives.py"
+
+
 class TestHvdFacade:
     def test_size_rank(self):
         hvd.init()
@@ -358,3 +407,28 @@ class TestTrainStep:
         compiled = train.lower(state, batch).compile()
         hlo = compiled.as_text()
         assert "all-reduce" in hlo
+
+
+@pytest.mark.parametrize("spelling", ["make_train_step(wire_format)",
+                                      "pspec.lower(wire_format_dcn)",
+                                      "compression=int8"])
+def test_removed_wire_spellings_are_refused(mesh8, spelling):
+    """The int8-block wire is gone without a shim: its keywords are
+    Python's TypeError and its compression name is an unknown one."""
+    from tpuframe.parallel import pspec
+
+    tx = optax.sgd(0.05)
+    if spelling == "make_train_step(wire_format)":
+        with pytest.raises(TypeError, match="wire_format"):
+            step_lib.make_train_step(_toy_loss, tx, mesh8,
+                                     wire_format="int8-block")
+    elif spelling == "pspec.lower(wire_format_dcn)":
+        spec = pspec.parse_spec("dp=4;slices=2")
+        with pytest.raises(TypeError, match="wire_format_dcn"):
+            pspec.lower(spec, spec.make_mesh(), hier="hier",
+                        wire_format_dcn="int8-block")
+    else:
+        opt = hvd.DistributedOptimizer(tx, compression="int8")
+        grads = {"w": jnp.ones((4,))}
+        with pytest.raises(ValueError, match="unknown compression 'int8'"):
+            opt.update(grads, opt.init(grads))

@@ -157,9 +157,9 @@ class TestLower:
         spec = pspec.parse_spec("dp=8")
         mesh = spec.make_mesh()
         kw = pspec.lower(spec, mesh, weight_update="zero1",
-                         wire_format="int8-block")
+                         fusion_threshold=1 << 20)
         assert kw["weight_update"] == "zero1"
-        assert kw["wire_format"] == "int8-block"
+        assert kw["fusion_threshold"] == 1 << 20
         assert kw["reduce_axes"] == mesh_lib.BATCH_AXES
 
     def test_hierarchical_dp_reduces_over_slice(self):
@@ -212,7 +212,7 @@ class TestLower:
         spec = pspec.parse_spec("dp=4,sp=2")
         mesh = spec.make_mesh()
         for kw in ({"weight_update": "zero1"},
-                   {"wire_format": "int8-block"},
+                   {"hier": "hier"},
                    {"fusion_threshold": 1 << 20},
                    {"grad_reduce": "adasum"}):
             with pytest.raises(pspec.SpecError, match="do not compose"):
@@ -352,9 +352,9 @@ class TestComposedStrategy:
 
     def test_register_spec_strategy_naming(self):
         name = strategies.register_spec_strategy(
-            "dp=*", weight_update="zero1", wire_format="int8-block")
+            "dp=*", weight_update="zero1", hier="hier")
         try:
-            assert name == "spec:dp=*+zero1+int8-block"
+            assert name == "spec:dp=*+zero1+hier"
             assert name in strategies.STRATEGIES
         finally:
             strategies.STRATEGIES.pop(name, None)
@@ -619,7 +619,7 @@ class TestRegistration:
     def test_dp_family_is_spec_lowered(self):
         import functools
 
-        for name in ("dp", "dp-int8", "dp-zero1", "dp-zero1-int8"):
+        for name in ("dp", "dp-zero1", "dp-adasum"):
             builder = strategies.STRATEGIES[name]
             assert isinstance(builder, functools.partial)
             assert builder.func is strategies._build_from_spec

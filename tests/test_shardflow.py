@@ -178,47 +178,6 @@ def test_seeded_wire_dtype_violation():
                                        ignore_below=1 << 20) == []
 
 
-def test_wire_format_allowlist_seam():
-    """A registered quantized wire format exempts its dtype set — the
-    EQuARX registration point."""
-    txt = _module(
-        f"  %p0 = f32[1024] parameter(0)\n"
-        f"  ROOT %ar = f32[1024] all-reduce(%p0), {_GROUPS8}, "
-        f"to_apply=%add")
-    graph = parse_graph(txt)
-    assert shardflow.detect_wire_dtype(graph, "bf16") != []
-    shardflow.register_wire_format("test-blockwise", {"f32", "u8"})
-    try:
-        assert "test-blockwise" in shardflow.registered_wire_formats()
-        assert shardflow.detect_wire_dtype(graph, "bf16") == []
-    finally:
-        shardflow._WIRE_FORMATS.pop("test-blockwise")
-
-
-def test_int8_block_wire_format_registered_at_import():
-    """quantwire's shipped format is registered by the module itself —
-    the gate sees s8 collectives as the declared wire, not a violation,
-    without any per-run setup."""
-    assert shardflow.registered_wire_formats().get("int8-block") \
-        == frozenset({"s8"})
-
-
-def test_seeded_wire_positive_guards_the_gate():
-    """check() must run the seeded wire-dtype positive first: a format
-    registration broad enough to exempt f32 traffic blinds the detector,
-    and the gate has to refuse to run blind."""
-    assert shardflow.seeded_wire_positive() == []
-    shardflow.register_wire_format("test-blind", {"s8", "f32"})
-    try:
-        probs = shardflow.seeded_wire_positive()
-        assert probs and "exempting" in probs[0]
-        # the gate entry point surfaces it even with no audits to run
-        assert any("exempting" in p for p in shardflow.check([]))
-    finally:
-        shardflow._WIRE_FORMATS.pop("test-blind")
-    assert shardflow.seeded_wire_positive() == []
-
-
 def test_seeded_accidental_replication():
     txt = ("HloModule seeded\n\n"
            "ENTRY %main (p0: f32[1024,64]) -> f32[1024,64] {\n"

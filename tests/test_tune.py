@@ -570,7 +570,11 @@ def _plan_report():
     rows = [
         _plan_row("spec:dp=*", "dp=*", 0.03, 300),
         _plan_row("spec:dp=*+zero1", "dp=*", 0.04, 600),
-        _plan_row("spec:dp=*+int8-block", "dp=*", 0.05, 200),
+        _plan_row("spec:dp=*;slices=2", "dp=*;slices=2", 0.09, 400,
+                  slices=2, n_devices=8, t_dcn_ms=0.05, dcn_bytes=400),
+        _plan_row("spec:dp=*;slices=2+hier", "dp=*;slices=2", 0.07, 500,
+                  slices=2, n_devices=8, t_dcn_ms=0.0125, ici_bytes=400,
+                  dcn_bytes=100),
         _plan_row("spec:dp=2,fsdp=2;slices=2", "dp=2,fsdp=2;slices=2",
                   0.06, 1000, slices=2, n_devices=8, t_ici_ms=0.004,
                   t_dcn_ms=0.025, ici_bytes=800, dcn_bytes=200),
@@ -614,7 +618,7 @@ class TestPlanner:
     def test_verdicts_hold_on_synthetic_rows(self):
         v = plan.compute_verdicts(_plan_report()["candidates"])
         assert v["zero1_bytes"]["holds"] is True       # 300 < 600
-        assert v["wire_bytes"]["holds"] is True        # 0.03 < 0.05 totals
+        assert v["hier_dcn"]["holds"] is True          # 100/400 <= 1/2
         assert v["dcn_split"]["holds"] is True         # 0.025>0.004, 200<800
         # missing rows degrade to holds=None, never a crash
         assert plan.compute_verdicts([])["zero1_bytes"]["holds"] is None

@@ -86,7 +86,6 @@ from tpuframe.analysis.shardflow import (  # noqa: F401
     derive_budget,
     derived_for,
     overlap_score,
-    register_wire_format,
     schedule_for,
 )
 from tpuframe.analysis.source_lint import (  # noqa: F401

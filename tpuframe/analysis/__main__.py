@@ -318,16 +318,6 @@ def _run_elastic_check() -> int:
     return len(problems)
 
 
-def _run_quantwire_check() -> int:
-    from tpuframe.parallel import quantwire
-
-    problems = quantwire.check()
-    for p in problems:
-        print(f"QUANTWIRE {p}")
-    print(f"[analysis] quantwire self-check: {len(problems)} problem(s)")
-    return len(problems)
-
-
 def _run_hier_check() -> int:
     from tpuframe.parallel import hier
 
@@ -479,7 +469,6 @@ def main(argv=None) -> int:
         n_findings += _run_zero1_check()
         n_findings += _run_fusion_check()
         n_findings += _run_elastic_check()
-        n_findings += _run_quantwire_check()
         n_findings += _run_hier_check()
         n_findings += _run_pspec_check()
         n_findings += _run_plan_check()

@@ -148,58 +148,6 @@ def zero1_budget(padded_param_bytes: int, name: str = "dp-zero1") -> CommBudget:
     )
 
 
-def dp_int8_budget(param_bytes: int, n_devices: int = 8,
-                   name: str = "dp-int8") -> CommBudget:
-    """Plain DP over the int8-block wire (quantwire, arXiv:2506.17615
-    style): the grad all-reduce is REPLACED by a quantized all-to-all
-    (reduce-scatter phase) plus all-gather, both carrying s8 payloads
-    with f32 per-block scales.  Each leg's ceiling is half the f32 param
-    bytes — 2x headroom over the ~param_bytes/4 s8 payload + scale/pad
-    overhead, and still 4x under :func:`dp_budget`'s 2.0x all-reduce
-    ceiling, so the budget itself documents the wire-byte drop.  Leaves
-    under the quantization floor (quantwire.MIN_QUANT_ELEMS) fall back
-    to fp all-reduce; that residue plus metric reductions gets a small
-    explicit allowance rather than a silent exemption, and the floor
-    drops to 1 KiB so the audit actually sees the quantized ops (the
-    tiny audit model's per-leaf collectives sit below the default
-    floor)."""
-    del n_devices  # wire bytes are per-device; degree cancels out
-    leg = int(0.5 * param_bytes)
-    return CommBudget(
-        name=name,
-        allowed={"all-to-all": leg, "all-gather": leg,
-                 "all-reduce": int(0.25 * param_bytes)},
-        ignore_below=1024,
-        notes="quantized a2a+ag grad path (s8 payload + f32 block "
-              "scales), fp all-reduce residue for sub-floor leaves",
-    )
-
-
-def zero1_int8_budget(padded_param_bytes: int, n_devices: int = 8,
-                      name: str = "dp-zero1-int8") -> CommBudget:
-    """ZeRO-1 over the int8-block wire: the grad reduce-scatter becomes
-    a quantized all-to-all, and the param all-gather becomes a quantized
-    DELTA all-gather (new_shard - old_shard on the wire; masters stay
-    f32).  Each quantized leg is capped at half the padded f32 bytes
-    (2x headroom over the s8 payload) versus :func:`zero1_budget`'s
-    exact 1.0x per leg — the +9%-step-time all-gather PERF §18 charges
-    ZeRO-1 for is the leg this shrinks.  Leaves whose padded size is
-    under the quantization floor keep the fp reduce-scatter/all-gather
-    pair; that residue is small per leaf (< 4 KiB) and gets an explicit
-    quarter-size allowance on the reduce-scatter kind."""
-    del n_devices
-    leg = int(0.5 * padded_param_bytes)
-    return CommBudget(
-        name=name,
-        allowed={"all-to-all": leg, "all-gather": leg,
-                 "reduce-scatter": int(0.25 * padded_param_bytes)},
-        ignore_below=1024,
-        notes="quantized a2a grad-in + s8 delta all-gather param-out; "
-              "fp reduce-scatter residue for sub-floor leaves; "
-              "all-reduce still forbidden above the 1 KiB scalar floor",
-    )
-
-
 def hier_dp_budget(param_bytes: int, n_inner: int,
                    name: str = "dp-hier") -> CommBudget:
     """Plain DP under the two-level lowering (tpuframe.parallel.hier,
@@ -224,30 +172,6 @@ def hier_dp_budget(param_bytes: int, n_inner: int,
     )
 
 
-def hier_dp_int8_budget(param_bytes: int, n_inner: int,
-                        name: str = "dp-hier-int8") -> CommBudget:
-    """Plain DP, two-level lowering, int8-block DCN leg: the cross-slice
-    mean of the 1/``n_inner`` shard rides the quantized wire (s8 payload
-    + f32 block scales over all-to-all + all-gather) while the in-slice
-    legs stay fp — the per-fabric composition PERF §20's "int8 loses at
-    ICI speeds" verdict calls for.  The all-to-all ceiling is the
-    documented DCN-byte crush: ~``param_bytes / (4 * n_inner)`` of s8
-    payload with 4x headroom.  Shards under quantwire's size floor fall
-    back to a fp cross-slice all-reduce; that residue gets the same
-    explicit allowance as :func:`hier_dp_budget`'s."""
-    return CommBudget(
-        name=name,
-        allowed={"reduce-scatter": int(1.5 * param_bytes),
-                 "all-gather": int(1.75 * param_bytes),
-                 "all-to-all": int(1.0 * param_bytes / n_inner),
-                 "all-reduce": int(0.5 * param_bytes)},
-        ignore_below=1024,
-        notes="two-level grad mean with quantized DCN leg: in-slice "
-              "rs+ag fp (ICI), cross-slice s8 a2a+ag on the 1/n_inner "
-              "shard (DCN); fp all-reduce residue for sub-floor shards",
-    )
-
-
 def hier_zero1_budget(padded_param_bytes: int, n_inner: int,
                       name: str = "dp-zero1-hier") -> CommBudget:
     """ZeRO-1 under the two-level lowering: the grad reduce-scatter and
@@ -268,32 +192,6 @@ def hier_zero1_budget(padded_param_bytes: int, n_inner: int,
               "padded*(1+1/n_inner) bytes per kind; only the shard-"
               "sized cross-slice stage rides DCN; all-reduce forbidden "
               "above the 1 KiB scalar floor",
-    )
-
-
-def hier_zero1_int8_budget(padded_param_bytes: int, n_inner: int,
-                           name: str = "dp-zero1-hier-int8") -> CommBudget:
-    """ZeRO-1, two-level lowering, int8-block DCN leg — the composed
-    spec that carries the DCN-crush acceptance: flat ZeRO-1 pays TWO
-    full-size DCN collectives per step (rs in, ag out) and this shape
-    pays two s8 shard-size ones (quantized cross-slice a2a for the
-    grad chunk, quantized cross-slice delta all-gather for the param
-    chunk) — ~``1/(4*n_inner)`` of the bytes each way.  In-slice stages
-    stay fp at exact bytes (the :func:`hier_zero1_budget` ceilings);
-    leaves whose cross-slice chunk is under quantwire's floor keep the
-    fp two-stage pair, so the rs/ag ceilings keep the full
-    ``padded * (1 + 1/n_inner)`` allowance and the all-to-all ceiling
-    prices the quantized grad leg alone."""
-    ceiling = int(padded_param_bytes * (1 + 1 / n_inner))
-    return CommBudget(
-        name=name,
-        allowed={"reduce-scatter": ceiling, "all-gather": ceiling,
-                 "all-to-all": int(0.5 * padded_param_bytes / n_inner)},
-        ignore_below=1024,
-        notes="two-stage zero1 with s8 cross-slice legs: fp in-slice "
-              "rs/ag + quantized a2a grad-in + quantized delta ag "
-              "param-out on the 1/n_inner chunk; fp two-stage residue "
-              "for sub-floor chunks",
     )
 
 
@@ -437,9 +335,7 @@ def strategy_budget(strategy: str, **sizes) -> CommBudget:
     """Budget for a MULTICHIP strategy name from program-derived sizes."""
     builders = {
         "dp": dp_budget,
-        "dp-int8": dp_int8_budget,
         "dp-zero1": zero1_budget,
-        "dp-zero1-int8": zero1_int8_budget,
         "serve-dp-decode": serve_decode_budget,
         "resnet-fsdp": fsdp_budget,
         "lm-seq-parallel": ring_sp_budget,

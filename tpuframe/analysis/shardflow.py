@@ -12,11 +12,7 @@ HLO:
       the sharding-annotation mistake that syncs a gradient twice).
   (b) :func:`detect_wire_dtype` — a floating collective wider than the
       strategy's declared wire dtype (an f32 gradient on a wire the
-      strategy declares bf16 silently doubles every budget).  Quantized
-      wire formats register through :func:`register_wire_format` — the
-      allowlist seam the EQuARX-style compressed collectives (ROADMAP
-      item 2, arXiv:2506.17615) will occupy, so the quantization wire
-      contract is declared here once instead of per-detector.
+      strategy declares bf16 silently doubles every budget).
   (c) :func:`detect_replication` — a tensor the strategy declares
       sharded showing up among the entry parameters at its full
       (replicated) shape above a size floor: the accidental-replication
@@ -104,78 +100,6 @@ _FLOAT_WIDTHS = {"f64": 8, "f32": 4, "bf16": 2, "f16": 2}
 REPLICATION_FLOOR = 4096
 
 # ---------------------------------------------------------------------------
-# The quantized-wire allowlist seam (ROADMAP item 2's registration point).
-# ---------------------------------------------------------------------------
-
-_WIRE_FORMATS: dict[str, frozenset] = {}
-
-
-def register_wire_format(name: str, dtypes) -> None:
-    """Declare a compressed/quantized wire format: collectives carrying
-    only ``dtypes`` are then exempt from the wire-dtype audit regardless
-    of the strategy's declared dtype (EQuARX-style int8/bf16 blocks ride
-    under the name they registered, not under a silent exemption)."""
-    _WIRE_FORMATS[name] = frozenset(dtypes)
-
-
-def registered_wire_formats() -> dict[str, frozenset]:
-    return dict(_WIRE_FORMATS)
-
-
-def _wire_exempt(dtypes: frozenset) -> str | None:
-    """Name of the registered wire format covering ``dtypes``, if any."""
-    for name, allowed in _WIRE_FORMATS.items():
-        if dtypes <= allowed:
-            return name
-    return None
-
-
-# The EQuARX-style block-quantized wire (tpuframe.parallel.quantwire,
-# arXiv:2506.17615): s8 payload collectives are the declared compressed
-# format.  The f32 block scales ride their own small collectives and are
-# deliberately NOT exempted — a registration containing f32 would cover
-# every full-precision collective and blind the detector (the seeded
-# positive below pins that).
-register_wire_format("int8-block", {"s8"})
-
-
-# A minimal optimized-HLO program with one gradient-sized f32 all-reduce.
-# Under a declared bf16 wire this MUST stay a finding even with quantized
-# formats registered — proves registration exempts only its own payload
-# dtype, never full-precision strays.
-_SEEDED_WIRE_HLO = """\
-HloModule seeded_wire_positive
-
-%add (a: f32[], b: f32[]) -> f32[] {
-  %a = f32[] parameter(0)
-  %b = f32[] parameter(1)
-  ROOT %r = f32[] add(f32[] %a, f32[] %b)
-}
-
-ENTRY %main (p0: f32[65536]) -> f32[65536] {
-  %p0 = f32[65536]{0} parameter(0)
-  ROOT %ar = f32[65536]{0} all-reduce(f32[65536]{0} %p0), replica_groups={}, to_apply=%add
-}
-"""
-
-
-def seeded_wire_positive() -> list[str]:
-    """Self-test of the wire-dtype detector: the seeded f32-under-bf16
-    program must yield exactly one finding.  Zero findings means a wire
-    registration (e.g. an int8 format accidentally including f32) has
-    silently blinded the detector; returns problem strings for the gate."""
-    graph = cg.parse_graph(_SEEDED_WIRE_HLO)
-    found = detect_wire_dtype(graph, "bf16")
-    if len(found) != 1:
-        return [f"seeded wire-dtype positive: expected exactly 1 finding "
-                f"for an f32 all-reduce under a declared bf16 wire, got "
-                f"{len(found)} — a registered wire format "
-                f"({sorted(_WIRE_FORMATS)}) is exempting full-precision "
-                f"payloads: {found}"]
-    return []
-
-
-# ---------------------------------------------------------------------------
 # Detectors.  Each takes the graph (plus strategy facts) and returns
 # finding strings; empty list == clean.
 # ---------------------------------------------------------------------------
@@ -251,9 +175,6 @@ def detect_wire_dtype(graph: cg.CollectiveGraph, wire_dtype: str,
                       if _FLOAT_WIDTHS.get(dt, 0) > declared_w)
         if not wide:
             continue
-        fmt = _wire_exempt(node.dtypes)
-        if fmt is not None:
-            continue  # registered quantized wire format
         findings.append(
             f"wire dtype in %{comp.name}: {node.kind} %{node.name} "
             f"carries {'/'.join(wide)} where the strategy declares "
@@ -876,7 +797,7 @@ def comm_split(graph: cg.CollectiveGraph, report, *, mesh_shape: dict,
     and its FULL wire bytes are charged to DCN (conservative: the slow
     hop bounds the op).  Bytes use the census ruler (``hlo_audit`` op
     bytes matched by source line, like :func:`overlap_score`; result
-    bytes as fallback), so quantized wires split at their real payload.
+    bytes as fallback), so every payload splits at its own dtype's width.
     Single-slice meshes attribute everything to ICI by construction.
     ``unattributed`` counts collectives whose iota group spec could not
     be materialized — those are charged to DCN, never dropped."""
@@ -954,8 +875,8 @@ def megascale_split(hlo_text: str) -> dict:
     lowered to paired host-transfer ``send``/``recv`` custom channels
     tagged ``_xla_host_transfer_handler_name="xla_megascale_runtime"``
     — invisible to both the collective graph and ``hlo_audit``.  This
-    counts each such send's payload bytes (s8 payloads count one byte
-    per element — a quantized DCN leg shows its real 4x drop) keyed by
+    counts each such send's payload bytes (at the payload dtype's own
+    width) keyed by
     the collective kind its rendezvous tag names.  Returns
     ``{kind: bytes}``; empty for CPU-compiled or single-slice programs,
     so folding this into a ``comm_split`` DCN column is a no-op there.
@@ -1043,8 +964,7 @@ def check(audits=None, *, n_devices: int = 8,
         audits = strategies.audit_all(n_devices)
     derived_file = load_derived(derived_path)
     schedule_file = load_derived_schedule(schedule_path)
-    problems: list[str] = seeded_wire_positive()
-    problems.extend(seeded_schedule_positive())
+    problems: list[str] = seeded_schedule_positive()
     for audit in audits:
         if audit.status == "unavailable" or audit.compiled is None:
             continue

@@ -186,8 +186,7 @@ Each rule institutionalizes a defect class rounds 4-5 found by hand:
          ``parallel/hier.py``.  The slice axis is the DCN fabric:
          ``hier.py`` owns every collective that crosses it, because
          that is where the two-level lowering (in-slice reduce-scatter
-         → 1/n cross-slice exchange → in-slice all-gather) and the
-         per-fabric wire format (``TPUFRAME_WIRE_FORMAT_DCN``) are
+         → 1/n cross-slice exchange → in-slice all-gather) is
          applied.  A raw ``lax.pmean(g, ("data", "slice"))`` elsewhere
          ships full-size traffic over DCN behind the seam's back —
          exactly the term the hierarchy exists to crush — and is
@@ -250,9 +249,6 @@ RULES = {
     "TF114": "lock-guarded shared state mutated outside `with <lock>:` in "
              "a background-thread module (ckpt/, obs/exporter.py, "
              "obs/flight.py, data/pipeline.py)",
-    "TF115": "raw lax collective (psum/ppermute/all_gather/psum_scatter) "
-             "in the wire-format seam (parallel/step.py, "
-             "parallel/zero1.py) bypassing the resolved wire format",
     "TF116": "world-size read (jax.process_count/device_count/"
              "local_device_count/process_index) cached at module import "
              "outside the elastic/launch/parallel seams — stale after an "
@@ -374,16 +370,6 @@ _MUTATING_METHODS = {
 }
 _CTOR_METHODS = {"__init__", "__post_init__", "__new__"}
 
-# TF115: the wire-format seam.  step.py and zero1.py resolve the wire
-# format (fp vs int8-block) per strategy and must route gradient-path
-# collectives through that dispatch — a raw lax.psum/all_gather here is
-# a call site the quantized wire silently never reaches.  lax.pmean is
-# deliberately NOT in the tails: it IS the fp wire's dispatch target.
-# Sanctioned raw uses (scalar reductions under every wire's size floor)
-# carry ``# tf-lint: ok[TF115]`` and a reason.
-_WIRE_SEAM_SUFFIXES = ("parallel/step.py", "parallel/zero1.py")
-_WIRE_RAW_TAILS = {"psum", "ppermute", "all_gather", "psum_scatter"}
-
 # TF116: the seams sanctioned to read the world size directly — the
 # elastic resolver itself, the launcher (sizes the cluster before jax
 # exists in the children) and parallel/ (mesh construction).  Everywhere
@@ -443,11 +429,11 @@ _SPAN_EVENT_LITERALS = ("span_open", "span_close", "span_note")
 
 # TF124: the hierarchical-collective seam.  hier.py owns every
 # collective that names the ``slice`` (DCN) axis — the two-level
-# lowering and the per-fabric wire format live there; pmean IS in the
-# tails (unlike TF115) because a raw cross-slice pmean is precisely the
-# full-size DCN transfer the seam exists to shrink.  Only the string
-# literal ``"slice"`` is matched: computed axis tuples are how the
-# seam's callers hand their axes down, and those stay untouched.
+# lowering lives there; pmean IS in the tails because a raw
+# cross-slice pmean is precisely the full-size DCN transfer the seam
+# exists to shrink.  Only the string literal ``"slice"`` is matched:
+# computed axis tuples are how the seam's callers hand their axes down,
+# and those stay untouched.
 _HIER_SEAM_SUFFIXES = ("parallel/hier.py",)
 _HIER_COLLECTIVE_TAILS = {
     "psum", "pmean", "pmax", "pmin", "ppermute", "all_gather",
@@ -670,7 +656,6 @@ class FileContext:
         self.swap_scope = norm.endswith(_SWAP_SCOPE_SUFFIXES)
         self.trace_scope = not norm.endswith(_TRACE_SEAM_SUFFIXES)
         self.lock_scope = any(p in norm for p in _LOCK_DISCIPLINE_PARTS)
-        self.wire_scope = norm.endswith(_WIRE_SEAM_SUFFIXES)
         self.hier_scope = not norm.endswith(_HIER_SEAM_SUFFIXES)
         self.world_scope = not any(p in norm
                                    for p in _WORLD_SANCTIONED_PARTS)
@@ -984,21 +969,6 @@ def _tf102_control_flow(ctx: FileContext, node, fn):
             ctx.emit("TF102", node,
                      "Python branch on an array-valued test inside "
                      "traced code — use lax.cond/jnp.where", fn)
-
-
-@_node_rule
-def _tf115_wire_seam(ctx: FileContext, node, fn):
-    if not ctx.wire_scope or not isinstance(node, ast.Call):
-        return
-    callee = _dotted(node.func)
-    if not callee.startswith(("lax.", "jax.lax.")):
-        return
-    if callee.rsplit(".", 1)[-1] in _WIRE_RAW_TAILS:
-        ctx.emit("TF115", node,
-                 f"raw `{callee}` in the wire-format seam bypasses the "
-                 f"resolved wire format — route through the wire "
-                 f"dispatch (quantwire/collectives helpers) or suppress "
-                 f"with tf-lint: ok[TF115] and a reason", fn)
 
 
 @_node_rule

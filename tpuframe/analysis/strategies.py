@@ -51,10 +51,6 @@ class StrategyMeta:
     mesh_shape: tuple[tuple[str, int], ...]
     wire_dtype: str = "f32"
     declared_leaves: tuple = ()    # ((hlo_dtype, full_dims, shard_dims),)
-    #: resolved gradient-path wire format ("fp" | "int8-block") — what
-    #: the roofline comm model reads to pick payload bytes per element,
-    #: instead of guessing from the accumulation dtype.
-    wire_format: str = "fp"
     #: the strategy claims its collectives overlap with compute (async
     #: start/done windows with work inside).  The exposed-communication
     #: detector FAILS a declared-overlapped strategy whose compiled
@@ -111,12 +107,10 @@ _HLO_DTYPES = {
 
 def _meta(mesh, *, wire_dtype: str = "f32",
           declared_leaves: tuple = (),
-          wire_format: str = "fp",
           declared_overlapped: bool = False) -> StrategyMeta:
     return StrategyMeta(
         mesh_shape=tuple((str(a), int(s)) for a, s in mesh.shape.items()),
         wire_dtype=wire_dtype, declared_leaves=declared_leaves,
-        wire_format=wire_format,
         declared_overlapped=declared_overlapped)
 
 
@@ -196,7 +190,7 @@ def _lm_pieces(batch: int = 8, seq: int = 32, **cfg_kw):
 # Every training parallelism strategy is SPEC-LOWERED: one generic
 # builder parses a ``tpuframe.parallel.pspec`` string, builds the
 # declared (possibly ICI×DCN) mesh, and lets ``pspec.lower`` /
-# ``pspec.lower_pp`` pick the step seams — zero1/wire-format/adasum ride
+# ``pspec.lower_pp`` pick the step seams — zero1/fusion/hier/adasum ride
 # as orthogonal modifiers, tp/ep thread the model sharding rules, sp
 # partitions the sequence dim, pp drives the GPipe harness.  The only
 # hand-wired builder left is the serving decode audit, which is a decode
@@ -205,12 +199,11 @@ def _lm_pieces(batch: int = 8, seq: int = 32, **cfg_kw):
 
 
 def _spec_budget(spec, pb: int, n_devices: int, *, weight_update: str,
-                 wire_format: str, padded: int | None, ab: int = 0,
+                 padded: int | None, ab: int = 0,
                  seq_mode: str | None = None,
                  grad_reduce: str | None = None,
                  fusion_threshold: int | None = None,
                  hier: str | None = None,
-                 wire_format_dcn: str | None = None,
                  n_inner: int = 1):
     """The declared CommBudget for a composed spec — the same per-kind
     ceilings the hand-wired family declared, picked by axis/modifier;
@@ -231,22 +224,13 @@ def _spec_budget(spec, pb: int, n_devices: int, *, weight_update: str,
     if grad_reduce == "adasum":
         return budgets_lib.adasum_budget(pb, n_devices)
     if hier == "hier":
-        dcn_int8 = (wire_format_dcn or "fp") == "int8-block"
         if weight_update == "zero1":
-            if dcn_int8:
-                return budgets_lib.hier_zero1_int8_budget(padded, n_inner)
             return budgets_lib.hier_zero1_budget(padded, n_inner)
-        if dcn_int8:
-            return budgets_lib.hier_dp_int8_budget(pb, n_inner)
         return budgets_lib.hier_dp_budget(pb, n_inner)
-    if weight_update == "zero1" and wire_format == "int8-block":
-        return budgets_lib.zero1_int8_budget(padded, n_devices)
     if weight_update == "zero1":
         # Bucketed fusion keeps the exact pad-to-multiple wire bytes —
         # the zero1 ceilings hold unchanged, fused or not.
         return budgets_lib.zero1_budget(padded)
-    if wire_format == "int8-block":
-        return budgets_lib.dp_int8_budget(pb, n_devices)
     if fusion_threshold is not None:
         return budgets_lib.fused_dp_budget(pb)
     return budgets_lib.dp_budget(pb)
@@ -325,12 +309,10 @@ def _pp_build(spec, mesh):
 
 def _build_from_spec(spec_text: str, n_devices: int, *,
                      weight_update: str = "replicated",
-                     wire_format: str | None = None,
                      seq_mode: str | None = None,
                      grad_reduce: str | None = None,
                      fusion_threshold: int | None = None,
                      hier: str | None = None,
-                     wire_format_dcn: str | None = None,
                      declared_overlapped: bool = False,
                      devices=None):
     """Generic spec-lowered builder: ``spec_text`` (the
@@ -342,8 +324,8 @@ def _build_from_spec(spec_text: str, n_devices: int, *,
     picks ring vs Ulysses attention for ``sp`` specs; ``grad_reduce``
     threads the adasum modifier; ``fusion_threshold`` threads the
     bucketed-fusion modifier (tpuframe.parallel.fusion's staged pass);
-    ``hier``/``wire_format_dcn`` thread the two-level cross-slice
-    lowering and its DCN-leg wire (tpuframe.parallel.hier), and
+    ``hier`` threads the two-level cross-slice lowering
+    (tpuframe.parallel.hier), and
     ``declared_overlapped`` signs the overlap contract the
     exposed-comm detector then enforces live."""
     import dataclasses
@@ -361,13 +343,12 @@ def _build_from_spec(spec_text: str, n_devices: int, *,
     if devices is None:
         devices = jax.devices()[:n_devices]
     mesh = spec.make_mesh(devices=devices)
-    wire = wire_format or "fp"
     if spec.pp > 1:
-        if (weight_update != "replicated" or wire != "fp"
+        if (weight_update != "replicated"
                 or seq_mode or grad_reduce or fusion_threshold is not None):
             raise pspec.SpecError(
                 f"spec '{spec.canonical()}': the GPipe lowering takes no "
-                f"modifiers — zero1/wire/seq_mode/adasum/fusion do not "
+                f"modifiers — zero1/seq_mode/adasum/fusion do not "
                 f"compose")
         return _pp_build(spec, mesh)
     if spec.ep > 1:
@@ -392,10 +373,8 @@ def _build_from_spec(spec_text: str, n_devices: int, *,
 
         tp_rules = tp_lib.rules_for_model("transformer-lm")
     kwargs = pspec.lower(spec, mesh, state, weight_update=weight_update,
-                         wire_format=wire, tp_rules=tp_rules,
-                         grad_reduce=grad_reduce,
-                         fusion_threshold=fusion_threshold,
-                         hier=hier, wire_format_dcn=wire_format_dcn)
+                         tp_rules=tp_rules, grad_reduce=grad_reduce,
+                         fusion_threshold=fusion_threshold, hier=hier)
     step = step_lib.make_train_step(loss_fn, tx, mesh, donate=False,
                                     **kwargs)
     # In-slice world size for the two-level budgets: the batch-axis
@@ -408,30 +387,23 @@ def _build_from_spec(spec_text: str, n_devices: int, *,
         n_batch *= int(sizes.get(a, 1))
     n_inner = max(1, n_batch // max(n_slice, 1))
     budget = _spec_budget(spec, pb, n_devices, weight_update=weight_update,
-                          wire_format=wire, padded=padded, ab=ab,
+                          padded=padded, ab=ab,
                           seq_mode=seq_mode, grad_reduce=grad_reduce,
                           fusion_threshold=fusion_threshold,
-                          hier=hier, wire_format_dcn=wire_format_dcn,
-                          n_inner=n_inner)
+                          hier=hier, n_inner=n_inner)
     shardings = kwargs.get("state_shardings")
-    dcn_int8 = (hier == "hier"
-                and (wire_format_dcn or "fp") == "int8-block")
     return (step, (state, batch), budget, pb,
             _meta(mesh,
-                  wire_format="int8-block"
-                  if (wire == "int8-block" or dcn_int8) else "fp",
                   declared_leaves=(_declared_leaves(state, shardings)
                                    if shardings is not None else ()),
                   declared_overlapped=declared_overlapped))
 
 
 def _spec_name(spec_text: str, *, weight_update: str = "replicated",
-               wire_format: str | None = None,
                seq_mode: str | None = None,
                grad_reduce: str | None = None,
                fusion_threshold: int | None = None,
-               hier: str | None = None,
-               wire_format_dcn: str | None = None) -> str:
+               hier: str | None = None) -> str:
     """Canonical strategy name for a composed spec: the spec's canonical
     spelling under a ``spec:`` prefix plus any modifiers — stable, so an
     auto-derived budget can be pinned in ``derived_budgets.json``."""
@@ -440,12 +412,8 @@ def _spec_name(spec_text: str, *, weight_update: str = "replicated",
     name = f"spec:{pspec.parse_spec(spec_text).canonical()}"
     if weight_update != "replicated":
         name += f"+{weight_update}"
-    if wire_format:
-        name += f"+{wire_format}"
     if hier:
         name += f"+{hier}"
-    if wire_format_dcn and wire_format_dcn != "fp":
-        name += "+dcn-int8"
     if seq_mode:
         name += f"+{seq_mode}"
     if grad_reduce:
@@ -457,12 +425,10 @@ def _spec_name(spec_text: str, *, weight_update: str = "replicated",
 
 def register_spec_strategy(spec_text: str, *,
                            weight_update: str = "replicated",
-                           wire_format: str | None = None,
                            seq_mode: str | None = None,
                            grad_reduce: str | None = None,
                            fusion_threshold: int | None = None,
                            hier: str | None = None,
-                           wire_format_dcn: str | None = None,
                            declared_overlapped: bool = False) -> str:
     """Register a composed parallelism spec as a dynamic analysis
     strategy.  The name is the spec's canonical spelling under a
@@ -476,15 +442,12 @@ def register_spec_strategy(spec_text: str, *,
     import functools
 
     name = _spec_name(spec_text, weight_update=weight_update,
-                      wire_format=wire_format, seq_mode=seq_mode,
-                      grad_reduce=grad_reduce,
-                      fusion_threshold=fusion_threshold,
-                      hier=hier, wire_format_dcn=wire_format_dcn)
+                      seq_mode=seq_mode, grad_reduce=grad_reduce,
+                      fusion_threshold=fusion_threshold, hier=hier)
     STRATEGIES[name] = functools.partial(
         _build_from_spec, spec_text, weight_update=weight_update,
-        wire_format=wire_format, seq_mode=seq_mode,
-        grad_reduce=grad_reduce, fusion_threshold=fusion_threshold,
-        hier=hier, wire_format_dcn=wire_format_dcn,
+        seq_mode=seq_mode, grad_reduce=grad_reduce,
+        fusion_threshold=fusion_threshold, hier=hier,
         declared_overlapped=declared_overlapped)
     return name
 
@@ -519,23 +482,6 @@ def _build_zero1(n_devices: int):
     reduce-scatter + all-gather at exactly the pad-to-multiple total)."""
     _warn_legacy("_build_zero1", "dp=*")
     return _build_from_spec("dp=*", n_devices, weight_update="zero1")
-
-
-def _build_dp_int8(n_devices: int):
-    """Deprecated alias: plain DP over the int8-block wire
-    (``wire_format="int8-block"`` on the ``dp=*`` spec) — grad
-    all-reduce becomes a quantized all-to-all + all-gather pair carrying
-    s8 payloads at ~4x fewer wire bytes."""
-    _warn_legacy("_build_dp_int8", "dp=*")
-    return _build_from_spec("dp=*", n_devices, wire_format="int8-block")
-
-
-def _build_zero1_int8(n_devices: int):
-    """Deprecated alias: ZeRO-1 over the int8-block wire — both
-    modifiers composed on the ``dp=*`` spec."""
-    _warn_legacy("_build_zero1_int8", "dp=*")
-    return _build_from_spec("dp=*", n_devices, weight_update="zero1",
-                            wire_format="int8-block")
 
 
 def _build_fsdp(n_devices: int):
@@ -642,13 +588,8 @@ def _build_adasum(n_devices: int):
 #: parallelism).
 STRATEGIES = {
     "dp": functools.partial(_build_from_spec, "dp=*"),
-    "dp-int8": functools.partial(_build_from_spec, "dp=*",
-                                 wire_format="int8-block"),
     "dp-zero1": functools.partial(_build_from_spec, "dp=*",
                                   weight_update="zero1"),
-    "dp-zero1-int8": functools.partial(_build_from_spec, "dp=*",
-                                       weight_update="zero1",
-                                       wire_format="int8-block"),
     "spec:dp=2,fsdp=2;slices=2": functools.partial(
         _build_from_spec, "dp=2,fsdp=2;slices=2"),
     "resnet-fsdp": functools.partial(_build_from_spec, "dp=*,fsdp=2"),
@@ -685,23 +626,17 @@ DP_ZERO1_FUSED = register_spec_strategy(
 
 #: The hierarchical two-level collective family (ISSUE 20): flat/hier
 #: twins on the pure-DP multi-slice spec so the auto-derived budget pins
-#: document the DCN byte column dropping by n_inner (fp cross-slice leg)
-#: and by ~4·n_inner (int8-block DCN leg) against the SAME spec, model
-#: and world.  The zero1 composition is the acceptance carrier: flat
-#: ZeRO-1 pays two full-size DCN collectives per step (rs in, ag out),
-#: the two-level int8 shape two s8 shard-size ones.
+#: document the DCN byte column dropping by n_inner against the SAME
+#: spec, model and world.  The zero1 composition is the acceptance
+#: carrier: flat ZeRO-1 pays two full-size DCN collectives per step (rs
+#: in, ag out), the two-level shape two shard-size ones.
 _HIER_SPEC = "dp=*;slices=2"
 HIER_FLAT = register_spec_strategy(_HIER_SPEC)
 HIER_DP = register_spec_strategy(_HIER_SPEC, hier="hier")
-HIER_DP_INT8 = register_spec_strategy(
-    _HIER_SPEC, hier="hier", wire_format_dcn="int8-block")
 HIER_ZERO1_FLAT = register_spec_strategy(
     _HIER_SPEC, weight_update="zero1")
 HIER_ZERO1 = register_spec_strategy(
     _HIER_SPEC, weight_update="zero1", hier="hier")
-HIER_ZERO1_INT8 = register_spec_strategy(
-    _HIER_SPEC, weight_update="zero1", hier="hier",
-    wire_format_dcn="int8-block")
 
 
 def _overlap_compile_opts(meta) -> dict | None:
@@ -723,12 +658,10 @@ def _overlap_compile_opts(meta) -> dict | None:
 
 def audit_spec(spec_text: str, *, n_devices: int,
                weight_update: str = "replicated",
-               wire_format: str | None = None,
                seq_mode: str | None = None,
                grad_reduce: str | None = None,
                fusion_threshold: int | None = None,
                hier: str | None = None,
-               wire_format_dcn: str | None = None,
                devices=None, name: str | None = None) -> StrategyAudit:
     """Audit an UNREGISTERED spec candidate — the ``tune plan`` seam.
 
@@ -742,18 +675,16 @@ def audit_spec(spec_text: str, *, n_devices: int,
     automatically declared overlapped — the same contract the registered
     fused variants sign."""
     label = name or _spec_name(spec_text, weight_update=weight_update,
-                               wire_format=wire_format, seq_mode=seq_mode,
-                               grad_reduce=grad_reduce,
+                               seq_mode=seq_mode, grad_reduce=grad_reduce,
                                fusion_threshold=fusion_threshold,
-                               hier=hier, wire_format_dcn=wire_format_dcn)
+                               hier=hier)
     try:
         if devices is None:
             _require_devices(n_devices)
         step, example, budget, pb, meta = _build_from_spec(
             spec_text, n_devices, weight_update=weight_update,
-            wire_format=wire_format, seq_mode=seq_mode,
-            grad_reduce=grad_reduce, fusion_threshold=fusion_threshold,
-            hier=hier, wire_format_dcn=wire_format_dcn,
+            seq_mode=seq_mode, grad_reduce=grad_reduce,
+            fusion_threshold=fusion_threshold, hier=hier,
             declared_overlapped=fusion_threshold is not None,
             devices=devices)
         report, compiled = hlo_audit.audit_jitted(

@@ -44,8 +44,8 @@ Event types (see ``REQUIRED_FIELDS`` for the per-type contract):
                  (policy name, resolution source, predicted bytes)
   weight_update  weight-update sharding mode chosen for the step program
                  (mode replicated|zero1, resolution source, shard count)
-  wire_format    gradient-path collective wire format chosen for the
-                 step program (format fp|int8-block, resolution source)
+  hier           gradient-mean lowering chosen for the step program
+                 (mode flat|hier, resolution source)
   fusion_threshold
                  gradient-fusion bucket threshold chosen for the step
                  program (threshold bytes or null for per-leaf,
@@ -147,7 +147,7 @@ REQUIRED_FIELDS: dict[str, tuple[str, ...]] = {
     "devmem": ("devices",),
     "remat_policy": ("policy", "source"),
     "weight_update": ("mode", "source"),
-    "wire_format": ("format", "source"),
+    "hier": ("mode", "source"),
     "fusion_threshold": ("threshold", "source"),
     "pspec": ("spec", "source"),
     "elastic_resize": ("n_from", "n_to", "policy"),

@@ -27,6 +27,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+# The one reach into jax._src: jax 0.9.0 has the Varying -> Invariant gather
+# but does not export it under jax.lax.  A jax that moves it fails this import
+# (tests/test_parallel_core.py::TestAllgatherInvariant says why).
+from jax._src.lax.parallel import all_gather_invariant as _all_gather_invariant
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpuframe.parallel import mesh as mesh_lib
@@ -90,7 +94,7 @@ def average_gradients(grads: PyTree, axis: AxisName = "data") -> PyTree:
         return grads
 
     def _avg(g):
-        vma = _leaf_vma(g, names)
+        vma = jax.typeof(g).vma
         varying = [a for a in names if a in vma]
         presummed = [a for a in names if a not in vma]
         out = lax.pmean(g, varying) if varying else g
@@ -111,7 +115,7 @@ def sum_gradients(grads: PyTree, axis: AxisName = "data") -> PyTree:
         return grads
 
     def _sum(g):
-        vma = _leaf_vma(g, names)
+        vma = jax.typeof(g).vma
         varying = [a for a in names if a in vma]
         return lax.psum(g, varying) if varying else g
 
@@ -134,7 +138,7 @@ def _maybe_fused_reduce(grads: PyTree, names, per_leaf, *, mean: bool) -> PyTree
 
     leaves, treedef = jax.tree.flatten(grads)
     fused_idx = [i for i, g in enumerate(leaves)
-                 if all(a in _leaf_vma(g, names) for a in names)]
+                 if all(a in jax.typeof(g).vma for a in names)]
     out = {i: per_leaf(leaves[i])
            for i in set(range(len(leaves))) - set(fused_idx)}
     if fused_idx:
@@ -154,27 +158,17 @@ def allgather(x: jax.Array, axis: AxisName = "data", *, tiled: bool = True) -> j
     return lax.all_gather(x, bound, axis=0, tiled=tiled)
 
 
-def _leaf_vma(g, names):
-    """The axes ``g`` is varying over, for the gradient-reduce routing."""
-    return jax.typeof(g).vma
-
-
 def allgather_invariant(x: jax.Array, axis: AxisName = "data", *,
                         gather_axis: int = 0, tiled: bool = True) -> jax.Array:
-    """Tiled all-gather whose result is marked replication-INVARIANT where
-    this jax can express it: every replica gathers the identical full
-    array, so the output is legal under a replicated out_spec (the zero1
-    param regather and the quantwire int8 gather both rely on this).
-    jax 0.9.0 has no ``lax.all_gather_invariant``, so there the result is
-    a plain (varying) ``lax.all_gather`` — ROADMAP D6.  Unmapped:
-    identity."""
+    """All-gather whose result is replication-INVARIANT: every replica
+    gathers the identical full array, so the output is legal under a
+    replicated out_spec (the zero1 param regather and the two-level
+    gather rely on this; ``lax.all_gather``'s result is varying and is
+    refused there).  Unmapped: identity."""
     bound = _bound_axes(axis)
     if not bound:
         return x
-    gather = getattr(lax, "all_gather_invariant", None)
-    if gather is not None:
-        return gather(x, bound, axis=gather_axis, tiled=tiled)
-    return lax.all_gather(x, bound, axis=gather_axis, tiled=tiled)
+    return _all_gather_invariant(x, bound, axis=gather_axis, tiled=tiled)
 
 
 def _linear_index(bound: tuple[str, ...]) -> jax.Array:
@@ -408,26 +402,6 @@ def _bcast_sum(sharding: NamedSharding):
     primary_device_put once per leaf; a fresh jit per call would recompile
     the same trivial program hundreds of times per restart."""
     return jax.jit(lambda a: a.sum(axis=0), out_shardings=sharding)
-
-
-def quantized_mean(tree: PyTree, axis: AxisName = "data") -> PyTree:
-    """REMOVED — raises with the replacement spelled out.
-
-    The original shared-scale int16-accumulated psum prototype grew into
-    the block-quantized ``int8-block`` wire format (per-block scales, s8
-    payload over all-to-all + all-gather — arXiv:2506.17615), resolved
-    per strategy through ``TPUFRAME_WIRE_FORMAT`` / the tune DB on the
-    step path.  The warn-once shim rode along for two release cycles;
-    with the spec grammar closed there is exactly one quantized-wire
-    seam, and a silent alias to it hides the per-strategy resolution.
-    """
-    raise RuntimeError(
-        "collectives.quantized_mean was removed: call "
-        "tpuframe.parallel.quantwire.all_reduce_mean(tree, axis, "
-        "min_elems=0) for the old always-quantized semantics, or — the "
-        "supported path — select the wire per strategy via "
-        "TPUFRAME_WIRE_FORMAT='int8-block' / the tune DB on the "
-        "make_train_step path")
 
 
 def host_broadcast(tree: PyTree, mesh: Mesh) -> PyTree:

@@ -44,7 +44,7 @@ psum(concat(gs)) == concat(psum(g) for g in gs) — which the golden-loss test
 asserts against the implicit pmean-of-loss path.
 
 The bucket-size knob resolves through the standard chain
-(:func:`resolve`, mirroring ``zero1.resolve``/``quantwire.resolve``):
+(:func:`resolve`, mirroring ``zero1.resolve``):
 ``TPUFRAME_FUSION_THRESHOLD`` env > generation-gated ``tune_db.json``
 winner (family ``fusion_threshold``, persisted by
 ``python -m tpuframe.tune sweep --fusion``) > default (off).
@@ -71,12 +71,6 @@ ENV_VAR = "TPUFRAME_FUSION_THRESHOLD"
 #: the sweep; Horovod's default is 64 MiB.
 REGISTRY_THRESHOLD = 128 * 1024
 
-# jax >= 0.6 vma machinery (PR 7 compat shim idiom): ``jax.typeof`` carries
-# the varying-manual-axes set concat compatibility must respect.  The floor
-# jax (0.4.37) has neither typeof nor pcast — bucketing keys on dtype alone
-# there (legacy shard_map's check_rep=False tracks no vma anyway).
-_HAS_VMA = hasattr(jax, "typeof") and hasattr(lax, "pcast")
-
 # No jax release exposes an async psum (start/done split at the lax level);
 # probed so the staged pass picks it up the release it appears instead of
 # silently staying synchronous.
@@ -87,10 +81,8 @@ _HAS_BARRIER = hasattr(lax, "optimization_barrier")
 
 def _leaf_kind(leaf) -> tuple:
     """Bucket compatibility key: dtype + vma (concat needs both to match)."""
-    if _HAS_VMA:
-        ty = jax.typeof(leaf)
-        return (ty.dtype, tuple(sorted(getattr(ty, "vma", ()))))
-    return (jnp.dtype(leaf.dtype), ())
+    ty = jax.typeof(leaf)
+    return (ty.dtype, tuple(sorted(ty.vma)))
 
 
 def _bucketize(leaves: Sequence[jax.Array],

@@ -14,18 +14,13 @@ cross-slice gradient mean into three fabric-matched phases:
 
 Only the middle leg crosses the data-center network, and it carries
 ``1/n_inner`` of the payload — the DCN byte column drops by the
-in-slice world size.  Because the DCN leg is its own collective, the
-wire format becomes *per-fabric*: the EQuARX int8-block wire
-(:mod:`tpuframe.parallel.quantwire`), an honest loss at ICI speeds
-(PERF §20), rides the slow leg alone for another ~4x while ICI stays
-full precision.
+in-slice world size.
 
 Numerically the two-level mean equals the flat mean up to float
 reassociation: the in-slice reduce-scatter divides by ``n_inner``, the
 cross-slice mean by ``n_slice``, so every element is the sum over all
 ``N`` replicas divided by ``N`` — the golden-loss tests pin hier ==
-flat to tight tolerance (fp DCN leg) and to the §20 int8 tolerance
-(quantized DCN leg).
+flat to tight tolerance.
 
 Like every other gradient-path modifier, the lowering is resolved per
 program (env ``TPUFRAME_HIER`` > generation-gated tune DB, family
@@ -46,7 +41,6 @@ from jax import lax
 
 from tpuframe.parallel import collectives
 from tpuframe.parallel import mesh as mesh_lib
-from tpuframe.parallel import quantwire
 
 AxisName = str | Sequence[str]
 PyTree = Any
@@ -58,9 +52,10 @@ DB_FAMILY = "hier_collectives"
 
 SLICE_AXIS = mesh_lib.SLICE_AXIS
 
-# Pre-vma jax (< 0.6, legacy shard_map with check_rep=False) tracks no
-# replication state — same compat split as quantwire.
-_HAS_VMA = quantwire._HAS_VMA
+# Leaves smaller than this keep the flat cross-slice mean: the two-level
+# shape triples a sub-KiB bias's collective count for no byte win, and the
+# hier budgets' floors are sized to ignore the flat strays.
+MIN_TWO_LEVEL_ELEMS = 1024
 
 
 # ---------------------------------------------------------------------------
@@ -117,31 +112,16 @@ def split_axes(axes: AxisName) -> tuple[tuple[str, ...], bool]:
     return inner, SLICE_AXIS in bound
 
 
-def _dcn_mean(shard: jax.Array, *, wire_format_dcn: str, block: int,
-              min_elems: int) -> jax.Array:
-    """The cross-slice leg: mean over the slice axis in the resolved
-    DCN wire format.  The int8-block wire keeps quantwire's own size
-    floor — a sub-floor shard stays fp there too."""
-    if wire_format_dcn == "int8-block":
-        return quantwire.all_reduce_mean(shard, SLICE_AXIS, block=block,
-                                         min_elems=min_elems)
-    return lax.pmean(shard, SLICE_AXIS)
-
-
-def hier_mean(tree: PyTree, axes: AxisName, *,
-              wire_format_dcn: str = "fp",
-              block: int = quantwire.DEFAULT_BLOCK,
-              min_elems: int = quantwire.MIN_QUANT_ELEMS) -> PyTree:
+def hier_mean(tree: PyTree, axes: AxisName) -> PyTree:
     """Two-level cross-replica gradient mean over ``axes``.
 
     Per leaf: pad to a multiple of the in-slice world, reduce-scatter
     (mean) over the ICI axes, mean the 1/n_inner shard over the slice
-    axis in ``wire_format_dcn``, all-gather the shard back over ICI,
-    unpad.  Leaves under ``min_elems`` (and any reduction whose inner
-    world is 1) fall back to a flat mean — for a sub-floor leaf the
-    two-level shape doubles the collective count for no byte win, and
-    with ``n_inner == 1`` every byte crosses DCN regardless (the DCN
-    wire format still applies there).
+    axis, all-gather the shard back over ICI, unpad.  Leaves under
+    ``MIN_TWO_LEVEL_ELEMS`` (and any reduction whose inner world is 1)
+    fall back to a flat mean — for a sub-floor leaf the two-level shape
+    triples the collective count for no byte win, and with
+    ``n_inner == 1`` every byte crosses DCN regardless.
 
     The result is invariant over all bound axes, matching
     ``average_gradients``' contract."""
@@ -149,38 +129,34 @@ def hier_mean(tree: PyTree, axes: AxisName, *,
     if not has_slice:
         # Single-slice mesh: nothing crosses DCN, flat is the lowering.
         return collectives.average_gradients(tree, axis=inner)
-    wire_format_dcn = quantwire.validate_format(wire_format_dcn)
 
     def _hmean(g):
-        vma = jax.typeof(g).vma if _HAS_VMA else frozenset((*inner,
-                                                            SLICE_AXIS))
+        vma = jax.typeof(g).vma
         varying_inner = tuple(a for a in inner if a in vma)
         sized = collectives._sized_axes(varying_inner)
-        n_inner = quantwire._axis_prod(sized)
-        if n_inner == 1 or g.size < max(min_elems, 1):
-            out = _dcn_mean(g, wire_format_dcn=wire_format_dcn,
-                            block=block, min_elems=min_elems)
+        n_inner = lax.axis_size(sized)
+        if n_inner == 1 or g.size < MIN_TWO_LEVEL_ELEMS:
+            out = lax.pmean(g, SLICE_AXIS)
             if varying_inner:
                 out = lax.pmean(out, varying_inner)
-            elif _HAS_VMA:
+            else:
                 out = collectives._clear_unit_axes(out, inner)
             return out.astype(g.dtype)
-        flat = quantwire._pad_to(g.astype(jnp.float32).reshape(-1),
-                                 n_inner)
-        if _HAS_VMA:
-            flat = collectives._vary_over(flat, sized)
+        flat = g.astype(jnp.float32).reshape(-1)
+        pad = (-flat.size) % n_inner
+        if pad:
+            flat = jnp.pad(flat, (0, pad))
+        flat = collectives._vary_over(flat, sized)
         # ICI: in-slice reduce-scatter(mean) — divides by n_inner.
         shard = collectives.reduce_scatter(flat, sized, average=True)
         # DCN: mean the 1/n_inner shard across slices — divides by
         # n_slice, completing the /N of the flat mean.
-        shard = _dcn_mean(shard, wire_format_dcn=wire_format_dcn,
-                          block=block, min_elems=min_elems)
+        shard = lax.pmean(shard, SLICE_AXIS)
         # ICI: gather the meaned shard back; tiled concat inverts the
         # scatter's contiguous chunk ownership exactly.
         full = collectives.allgather_invariant(shard, sized)
         out = full[:g.size].reshape(g.shape)
-        if _HAS_VMA:
-            out = collectives._clear_unit_axes(out, (*inner, SLICE_AXIS))
+        out = collectives._clear_unit_axes(out, (*inner, SLICE_AXIS))
         return out.astype(g.dtype)
 
     return jax.tree.map(_hmean, tree)
@@ -192,10 +168,7 @@ def hier_mean(tree: PyTree, axes: AxisName, *,
 
 
 def fused_hier_mean(tree: PyTree, axes: AxisName, *,
-                    threshold_bytes: int,
-                    wire_format_dcn: str = "fp",
-                    block: int = quantwire.DEFAULT_BLOCK,
-                    min_elems: int = quantwire.MIN_QUANT_ELEMS) -> PyTree:
+                    threshold_bytes: int) -> PyTree:
     """Two-level mean with Horovod-style fusion buckets: leaves pack into
     ≤``threshold_bytes`` same-kind buffers (``fusion._bucketize``'s exact
     buckets) and each buffer takes ONE three-phase lowering — rs(mean)
@@ -220,13 +193,10 @@ def fused_hier_mean(tree: PyTree, axes: AxisName, *,
     for bucket in buckets:
         if len(bucket) == 1:
             i = bucket[0]
-            out[i] = hier_mean(leaves[i], axes,
-                               wire_format_dcn=wire_format_dcn,
-                               block=block, min_elems=min_elems)
+            out[i] = hier_mean(leaves[i], axes)
             continue
         flat = jnp.concatenate([leaves[i].reshape(-1) for i in bucket])
-        red = hier_mean(flat, axes, wire_format_dcn=wire_format_dcn,
-                        block=block, min_elems=min_elems)
+        red = hier_mean(flat, axes)
         off = 0
         for i in bucket:
             sz = leaves[i].size
@@ -251,19 +221,16 @@ def linear_index(inner_axes: tuple[str, ...]):
     return collectives._linear_index((*tuple(inner_axes), SLICE_AXIS))
 
 
-def scatter_mean(flat: jax.Array, inner_axes: tuple[str, ...], *,
-                 wire_format_dcn: str = "fp",
-                 block: int = quantwire.DEFAULT_BLOCK) -> jax.Array:
+def scatter_mean(flat: jax.Array,
+                 inner_axes: tuple[str, ...]) -> jax.Array:
     """Two-stage reduce-scatter(mean) of a flat operand padded to a
     multiple of the FULL world ``n_inner * n_slice``: in-slice rs(mean)
     over ICI (divides by n_inner, full bytes on the fast fabric), then
-    cross-slice rs(mean) of the 1/n_inner chunk over DCN in the resolved
-    DCN wire format.  Member (s, j) receives chunk
+    cross-slice rs(mean) of the 1/n_inner chunk over DCN.  Member (s, j)
+    receives chunk
     ``linear_index(inner_axes)`` of the n chunks — zero1's dynamic-slice
     index math works unchanged with that index."""
     chunk = collectives.reduce_scatter(flat, inner_axes, average=True)
-    if wire_format_dcn == "int8-block":
-        return quantwire.reduce_scatter_mean(chunk, SLICE_AXIS, block=block)
     return collectives.reduce_scatter(chunk, SLICE_AXIS, average=True)
 
 
@@ -272,16 +239,6 @@ def gather(shard: jax.Array, inner_axes: tuple[str, ...]) -> jax.Array:
     slice axis FIRST (DCN, 1/n_inner of the bytes, reassembling each
     in-slice chunk), then over the inner axes (ICI, full bytes)."""
     chunk = collectives.allgather_invariant(shard, SLICE_AXIS)
-    return collectives.allgather_invariant(chunk, inner_axes)
-
-
-def gather_delta(delta_shard: jax.Array, inner_axes: tuple[str, ...], *,
-                 block: int = quantwire.DEFAULT_BLOCK) -> jax.Array:
-    """int8-DCN twin of :func:`gather` for zero1's update-delta trick:
-    the cross-slice (DCN) leg gathers the quantized delta shard, the
-    in-slice (ICI) leg stays fp — masters accumulate full precision and
-    only the slow leg pays the one-quantization-step error."""
-    chunk = quantwire.all_gather(delta_shard, SLICE_AXIS, block=block)
     return collectives.allgather_invariant(chunk, inner_axes)
 
 
@@ -376,7 +333,6 @@ def _numeric_problems() -> list:
     fusion gate's psum-linearity idiom).  Skips quietly below 4 devices
     — the analysis child always runs with 8."""
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if jax.device_count() < 4 or jax.device_count() % 2:
@@ -395,10 +351,10 @@ def _numeric_problems() -> list:
     spec = P(axes)
     problems = []
     try:
-        want = jax.jit(shard_map(_flat, mesh=mesh, in_specs=spec,
-                                 out_specs=spec, check_rep=False))(x)
-        got = jax.jit(shard_map(_hier, mesh=mesh, in_specs=spec,
-                                out_specs=spec, check_rep=False))(x)
+        want = jax.jit(jax.shard_map(_flat, mesh=mesh, in_specs=spec,
+                                     out_specs=spec))(x)
+        got = jax.jit(jax.shard_map(_hier, mesh=mesh, in_specs=spec,
+                                    out_specs=spec))(x)
     except Exception as e:  # noqa: BLE001 — report, don't crash CI
         return [f"hier numeric check failed to run: "
                 f"{type(e).__name__}: {e}"]

@@ -203,13 +203,9 @@ def DistributedOptimizer(
     program: the ``pmean`` below is scheduled/overlapped with backward compute
     by the compiler, which is the same overlap Horovod implements by hand.
 
-    ``compression``: None, "bf16" or "int8".  "bf16" mirrors Horovod's fp16
+    ``compression``: None or "bf16", which mirrors Horovod's fp16
     gradient compression (cast down for the wire, restored after
-    reduction); "int8" is the EQuARX-style further step (PAPERS.md:7) —
-    per-block-scaled int8 payloads on an all-to-all + all-gather wire
-    (quantwire.all_reduce_mean; requires ``average=True``).  The same
-    implementation backs ``make_train_step(wire_format="int8-block")``;
-    this knob exists for Horovod API parity.
+    reduction).
 
     ``op=hvd.Adasum`` selects adaptive summation (collectives.adasum) in
     place of the mean — Horovod's scale-insensitive large-batch reduction.
@@ -237,20 +233,6 @@ def DistributedOptimizer(
             updates, inner = tx.update(
                 collectives.adasum(grads, axis=axis), state.inner, params,
                 **extra)
-            return updates, _DistState(inner=inner)
-        if compression == "int8":
-            # Quantized wire path (EQuARX-style): per-block-scaled int8
-            # payloads on an all-to-all + all-gather wire (quantwire) —
-            # structurally different from the cast-reduce-cast flow, so it
-            # replaces the reduction outright.  min_elems=0: this knob is
-            # an explicit per-optimizer ask, no size floor.
-            if not average:
-                raise ValueError("compression='int8' implements a quantized "
-                                 "mean; use average=True")
-            from tpuframe.parallel import quantwire
-
-            grads = quantwire.all_reduce_mean(grads, axis, min_elems=0)
-            updates, inner = tx.update(grads, state.inner, params, **extra)
             return updates, _DistState(inner=inner)
         grads, orig_dtypes = _maybe_compress(grads, compression)
         # vma-aware: reduces varying leaves, passes through already-psum'd

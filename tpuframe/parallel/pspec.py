@@ -9,7 +9,7 @@ the optional ``;slices=N`` tail declares N such slices joined by DCN.
 turns it into the hierarchical :class:`~tpuframe.parallel.mesh.MeshSpec`
 (slice axis outermost, so only genuinely cross-slice collectives ride
 the slow fabric), and :func:`lower` maps it onto the existing
-``make_train_step`` seams — dp/zero1/wire-format/fusion stay orthogonal
+``make_train_step`` seams — dp/zero1/fusion/hier stay orthogonal
 modifiers instead of eight hand-wired strategies (ROADMAP item 2; the
 composition view of arXiv:1909.09756 / arXiv:2011.03641).
 
@@ -183,21 +183,19 @@ def resolve(explicit: str | None = None) -> tuple:
 
 
 def lower(spec: ParallelSpec, mesh, state=None, *,
-          weight_update: str = "replicated", wire_format: str | None = None,
+          weight_update: str = "replicated",
           fusion_threshold: int | None = None, tp_rules=None,
-          grad_reduce: str | None = None, hier: str | None = None,
-          wire_format_dcn: str | None = None) -> dict:
+          grad_reduce: str | None = None, hier: str | None = None) -> dict:
     """Map a spec onto ``make_train_step`` kwargs.
 
     Three lowering classes exist, matching the step factory's own modes:
 
       * pure data-parallel (only ``dp``/``slices`` > 1) lowers to the
-        shard_map path, where ``weight_update`` (zero1), ``wire_format``
-        (int8-block), ``fusion_threshold``, ``grad_reduce``
-        (``"adasum"``) and the two-level lowering (``hier`` +
-        ``wire_format_dcn``, :mod:`tpuframe.parallel.hier`) remain
-        orthogonal modifiers — exactly the knobs ``zero1.resolve`` /
-        ``quantwire.resolve`` / ``hier.resolve`` already feed.  adasum
+        shard_map path, where ``weight_update`` (zero1),
+        ``fusion_threshold``, ``grad_reduce`` (``"adasum"``) and the
+        two-level lowering (``hier``, :mod:`tpuframe.parallel.hier`)
+        remain orthogonal modifiers — exactly the knobs ``zero1.resolve``
+        / ``fusion.resolve`` / ``hier.resolve`` already feed.  adasum
         is its own wire pattern (the ppermute butterfly) and refuses the
         other modifiers, mirroring ``make_train_step``'s rules;
       * sequence-parallel specs (``sp`` > 1, weights replicated) stay on
@@ -205,7 +203,7 @@ def lower(spec: ParallelSpec, mesh, state=None, *,
         the ``seq`` axis and widen the loss reduction to span it —
         activations shard, weights do not, so the shard_map modifiers
         whose byte accounting assumes batch-only sharding (zero1 /
-        int8-block / fusion / adasum) do not compose;
+        fusion / adasum / hier) do not compose;
       * weight-sharded specs (``fsdp``/``tp``/``ep`` > 1) lower to the
         auto-SPMD path via :func:`tpuframe.parallel.fsdp.state_shardings`
         over the declared (possibly hierarchical) mesh — ``state`` (a
@@ -236,16 +234,13 @@ def lower(spec: ParallelSpec, mesh, state=None, *,
             f"spec '{spec.canonical()}': pp does not lower through "
             f"make_train_step — use lower_pp(), which drives the pp_lm "
             f"GPipe harness")
-    wire_format = wire_format or "fp"
     grad_reduce = grad_reduce or "mean"
     hier = hier or "flat"
-    wire_format_dcn = wire_format_dcn or "fp"
     if grad_reduce not in ("mean", "adasum"):
         raise SpecError(f"grad_reduce={grad_reduce!r} — expected 'mean' "
                         f"or 'adasum'")
-    modified = (weight_update != "replicated" or wire_format != "fp"
-                or fusion_threshold is not None or hier != "flat"
-                or wire_format_dcn != "fp")
+    modified = (weight_update != "replicated"
+                or fusion_threshold is not None or hier != "flat")
     if spec.fsdp > 1 or spec.tp > 1 or spec.ep > 1:
         if spec.sp > 1:
             raise SpecError(
@@ -255,7 +250,7 @@ def lower(spec: ParallelSpec, mesh, state=None, *,
         if modified or grad_reduce != "mean":
             raise SpecError(
                 f"spec '{spec.canonical()}': weight-sharded lowering is "
-                f"auto-SPMD — zero1/wire_format/fusion_threshold/adasum/"
+                f"auto-SPMD — zero1/fusion_threshold/adasum/"
                 f"hier are shard_map modifiers and do not compose")
         if (spec.tp > 1 or spec.ep > 1) and tp_rules is None:
             raise SpecError(
@@ -280,14 +275,13 @@ def lower(spec: ParallelSpec, mesh, state=None, *,
         if modified or grad_reduce != "mean":
             raise SpecError(
                 f"spec '{spec.canonical()}': sp shards activations, not "
-                f"weights — zero1/wire_format/fusion_threshold/adasum/"
+                f"weights — zero1/fusion_threshold/adasum/"
                 f"hier assume batch-only sharding and do not compose")
         from jax.sharding import PartitionSpec as P
 
         axes = mesh_lib.batch_axes(mesh)
         return {
             "weight_update": weight_update,
-            "wire_format": wire_format,
             "fusion_threshold": fusion_threshold,
             "reduce_axes": (*axes, "seq"),
             "batch_partition": P(axes, "seq"),
@@ -295,20 +289,13 @@ def lower(spec: ParallelSpec, mesh, state=None, *,
     if grad_reduce == "adasum" and modified:
         raise SpecError(
             f"spec '{spec.canonical()}': adasum's ppermute butterfly is "
-            f"its own wire pattern — zero1/wire_format/fusion_threshold/"
-            f"hier do not compose")
-    if wire_format_dcn != "fp" and hier != "hier":
-        raise SpecError(
-            f"spec '{spec.canonical()}': wire_format_dcn="
-            f"{wire_format_dcn!r} is the DCN leg of the two-level "
-            f"lowering — it needs hier='hier'")
+            f"its own wire pattern — zero1/fusion_threshold/hier do not "
+            f"compose")
     return {
         "weight_update": weight_update,
-        "wire_format": wire_format,
         "fusion_threshold": fusion_threshold,
         "grad_reduce": grad_reduce,
         "hier": hier,
-        "wire_format_dcn": wire_format_dcn,
         "reduce_axes": mesh_lib.batch_axes(mesh),
         "batch_partition": mesh_lib.batch_spec(mesh=mesh),
     }

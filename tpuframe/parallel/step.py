@@ -68,9 +68,7 @@ def _grad_step(loss_fn: LossFn, tx: optax.GradientTransformation,
                accum_steps: int,
                grad_reduce: str,
                weight_update: str,
-               wire_format: str,
                hier: str,
-               wire_format_dcn: str,
                state: TrainState, batch: PyTree):
     """Shared body for both modes. ``axes`` bound ⇒ explicit collectives."""
     step_rng = jax.random.fold_in(state.rng, state.step)
@@ -82,8 +80,7 @@ def _grad_step(loss_fn: LossFn, tx: optax.GradientTransformation,
     if accum_steps > 1:
         return _accum_grad_step(loss_fn, tx, axes, fusion_threshold,
                                 accum_steps, grad_reduce, weight_update,
-                                wire_format, hier, wire_format_dcn,
-                                state, batch, step_rng)
+                                hier, state, batch, step_rng)
 
     # The reference's raison d'être: synchronous gradient averaging.
     # Horovod: per-tensor async NCCL ring-allreduce with fusion buffer.
@@ -112,20 +109,13 @@ def _grad_step(loss_fn: LossFn, tx: optax.GradientTransformation,
     # implicit pmean-of-loss transpose (which would all-reduce) must not
     # run: the params are pcast varying like the explicit path.
     zero1 = bool(axes) and weight_update == "zero1"
-    # A quantized wire on the plain-DP path ALSO needs LOCAL grads: the
-    # per-replica gradients are what gets block-quantized before the
-    # exchange (tpuframe.parallel.quantwire), so the implicit
-    # pmean-of-loss transpose (which would pre-reduce in f32) must not
-    # run.  The zero1 tail already takes local grads; its wire choice
-    # lives inside sharded_update.
-    wire_local = bool(axes) and wire_format != "fp" and not zero1
     # The two-level (hierarchical) lowering restructures the gradient
     # mean itself — rs over ICI → cross-slice mean over DCN → ag back
     # (tpuframe.parallel.hier) — so it consumes LOCAL grads like every
     # other explicit wire pattern.  The zero1 tail runs its own
     # two-stage scatter/gather and already takes local grads.
     hier_local = bool(axes) and hier == "hier" and not zero1
-    local_grads = explicit or zero1 or wire_local or hier_local
+    local_grads = explicit or zero1 or hier_local
     diff_params = state.params
     if local_grads:
         diff_params = jax.tree.map(
@@ -141,19 +131,18 @@ def _grad_step(loss_fn: LossFn, tx: optax.GradientTransformation,
         global_loss, has_aux=True)(diff_params, state.model_state, batch, step_rng)
 
     return _reduce_and_apply(tx, axes, fusion_threshold, grad_reduce,
-                             weight_update, wire_format, hier,
-                             wire_format_dcn, state,
+                             weight_update, hier, state,
                              grads, loss, metrics, model_state,
                              reduce_grads=local_grads)
 
 
 def _reduce_and_apply(tx, axes, fusion_threshold, grad_reduce, weight_update,
-                      wire_format, hier, wire_format_dcn, state, grads,
-                      loss, metrics, model_state, *, reduce_grads: bool):
+                      hier, state, grads, loss, metrics, model_state, *,
+                      reduce_grads: bool):
     """Shared step tail: cross-replica reductions + optimizer update.
 
     ``reduce_grads``: True when ``grads``/``loss`` are still per-replica
-    (explicit-fusion, adasum, zero1, quantized-wire and accumulation
+    (explicit-fusion, adasum, zero1, two-level and accumulation
     paths); False when the pmean-of-loss transpose already reduced them
     (the implicit default)."""
     if weight_update == "zero1" and axes:
@@ -174,8 +163,7 @@ def _reduce_and_apply(tx, axes, fusion_threshold, grad_reduce, weight_update,
                              state.params)
         params, opt_state, grad_norm = zero1_lib.sharded_update(
             tx, axes, state.params, state.opt_state, grads,
-            wire_format=wire_format, fusion_threshold=fusion_threshold,
-            hier=(hier == "hier"), wire_format_dcn=wire_format_dcn)
+            fusion_threshold=fusion_threshold, hier=(hier == "hier"))
         metrics = dict(metrics)
         metrics["loss"] = loss
         metrics["grad_norm"] = grad_norm
@@ -189,27 +177,20 @@ def _reduce_and_apply(tx, axes, fusion_threshold, grad_reduce, weight_update,
             grads = collectives.adasum(grads, axes)
         elif hier == "hier":
             # Two-level cross-slice mean (tpuframe.parallel.hier): full
-            # bytes stay on ICI, only the 1/n_inner shard crosses DCN —
-            # in wire_format_dcn.  fusion_threshold buckets the
-            # lowerings (fp DCN leg only; validated at build time).
+            # bytes stay on ICI, only the 1/n_inner shard crosses DCN.
+            # fusion_threshold buckets the lowerings.
             from tpuframe.parallel import hier as hier_lib
 
             if fusion_threshold is not None:
                 grads = hier_lib.fused_hier_mean(
-                    grads, axes, threshold_bytes=fusion_threshold,
-                    wire_format_dcn=wire_format_dcn)
+                    grads, axes, threshold_bytes=fusion_threshold)
             else:
-                grads = hier_lib.hier_mean(
-                    grads, axes, wire_format_dcn=wire_format_dcn)
+                grads = hier_lib.hier_mean(grads, axes)
         elif fusion_threshold is not None:
             from tpuframe.parallel import fusion
 
             grads = fusion.staged_pmean(grads, axes,
                                         threshold_bytes=fusion_threshold)
-        elif wire_format == "int8-block":
-            from tpuframe.parallel import quantwire
-
-            grads = quantwire.all_reduce_mean(grads, axes)
         else:
             grads = jax.tree.map(lambda g: lax.pmean(g, axes), grads)
         loss = lax.pmean(loss, axes)
@@ -235,8 +216,8 @@ def _reduce_and_apply(tx, axes, fusion_threshold, grad_reduce, weight_update,
 
 
 def _accum_grad_step(loss_fn, tx, axes, fusion_threshold, accum_steps,
-                     grad_reduce, weight_update, wire_format, hier,
-                     wire_format_dcn, state, batch, step_rng):
+                     grad_reduce, weight_update, hier, state, batch,
+                     step_rng):
     """Gradient accumulation — Horovod's ``backward_passes_per_step``
     (DistributedOptimizer option; the reference's recipe for batches that
     exceed device memory).  The local batch is split into ``accum_steps``
@@ -301,8 +282,7 @@ def _accum_grad_step(loss_fn, tx, axes, fusion_threshold, accum_steps,
     metrics = jax.tree.map(lambda m: m / accum_steps, metrics)
 
     return _reduce_and_apply(tx, axes, fusion_threshold, grad_reduce,
-                             weight_update, wire_format, hier,
-                             wire_format_dcn, state,
+                             weight_update, hier, state,
                              grads, loss, metrics, model_state,
                              reduce_grads=True)
 
@@ -323,9 +303,7 @@ def make_train_step(
     compiler_options: dict | None = None,
     remat_policy: str | None = None,
     weight_update: str = "replicated",
-    wire_format: str = "fp",
     hier: str = "flat",
-    wire_format_dcn: str = "fp",
 ):
     """Build the compiled train step.
 
@@ -391,19 +369,6 @@ def make_train_step(
     ``TPUFRAME_WEIGHT_UPDATE`` > tuning DB > default) is the caller's job
     via ``zero1.resolve``.
 
-    ``wire_format``: ``"fp"`` (default — gradient-path collectives move
-    full-precision payloads) or ``"int8-block"``
-    (:mod:`tpuframe.parallel.quantwire`, arXiv:2506.17615): per-replica
-    gradients are block-quantized (s8 payload + per-256-element f32
-    scales, ~4x fewer wire bytes) before the cross-replica exchange; on
-    the zero1 path both the gradient reduce-scatter and the param-delta
-    all-gather take the quantized wire.  shard_map mode with a mesh only
-    (auto-SPMD inserts its own collectives; ``mesh=None`` has no wire,
-    so the format is ignored — the world-of-1 no-op contract); does not
-    compose with ``fusion_threshold``/``adasum`` (each is its own wire
-    pattern).  Resolution (env ``TPUFRAME_WIRE_FORMAT`` > tuning DB >
-    default) is the caller's job via ``quantwire.resolve``.
-
     ``hier``: ``"flat"`` (default — cross-replica means are single
     collectives whose groups may span slices) or ``"hier"``
     (:mod:`tpuframe.parallel.hier`, arXiv:1909.09756): the gradient mean
@@ -413,27 +378,14 @@ def make_train_step(
     single-slice mesh the lowering degenerates to flat.  shard_map mode
     with a mesh only; composes with ``accum_steps``, ``weight_update=
     'zero1'`` (the sharded update's scatter/gather go two-stage) and
-    ``fusion_threshold`` (bucketed lowerings, fp DCN leg only), but not
-    with ``adasum`` (its butterfly is its own wire pattern) or the
-    program-wide ``wire_format='int8-block'`` — PERF §20's verdict is
-    that int8 loses at ICI speeds; quantize the slow leg instead via
-    ``wire_format_dcn``.  Resolution (env ``TPUFRAME_HIER`` > tuning DB
-    > default) is the caller's job via ``hier.resolve``.
-
-    ``wire_format_dcn``: wire format of the cross-slice (DCN) leg of the
-    two-level lowering — ``"fp"`` (default) or ``"int8-block"`` (the
-    quantwire path riding the slow fabric alone, ~4x fewer DCN bytes on
-    top of hier's 1/n_inner).  Needs ``hier='hier'``; flat programs have
-    a single fabric-blind wire (use ``wire_format``).  Resolution (env
-    ``TPUFRAME_WIRE_FORMAT_DCN`` > tuning DB > fp) is the caller's job
-    via ``quantwire.resolve_legs``.
+    ``fusion_threshold`` (bucketed lowerings), but not with ``adasum``
+    (its butterfly is its own wire pattern).  Resolution (env
+    ``TPUFRAME_HIER`` > tuning DB > default) is the caller's job via
+    ``hier.resolve``.
     """
     from tpuframe.parallel import hier as hier_lib
-    from tpuframe.parallel import quantwire
 
-    wire_format = quantwire.validate_format(wire_format)
     hier = hier_lib.validate_mode(hier)
-    wire_format_dcn = quantwire.validate_format(wire_format_dcn)
     if hier == "hier":
         if state_shardings is not None or mode != "shard_map":
             raise ValueError("hier='hier' needs shard_map mode — auto-SPMD "
@@ -442,35 +394,6 @@ def make_train_step(
         if grad_reduce == "adasum":
             raise ValueError("hier='hier' does not compose with adasum — "
                              "the butterfly is its own wire pattern")
-        if wire_format != "fp":
-            raise ValueError(f"hier='hier' does not compose with the "
-                             f"program-wide wire_format={wire_format!r}: "
-                             f"int8 on the ICI legs loses (PERF §20) — "
-                             f"quantize only the DCN leg via "
-                             f"wire_format_dcn")
-    if wire_format_dcn != "fp":
-        if hier != "hier":
-            raise ValueError(f"wire_format_dcn={wire_format_dcn!r} is the "
-                             f"DCN leg of the two-level lowering and needs "
-                             f"hier='hier'; a flat program has one "
-                             f"fabric-blind wire (wire_format)")
-        if fusion_threshold is not None:
-            raise ValueError(f"wire_format_dcn={wire_format_dcn!r} does not "
-                             f"compose with fusion_threshold — the fusion "
-                             f"buffers pack full-precision payloads")
-    if wire_format != "fp":
-        if state_shardings is not None or mode != "shard_map":
-            raise ValueError(f"wire_format={wire_format!r} needs shard_map "
-                             f"mode — auto-SPMD programs have no explicit "
-                             f"collectives to quantize")
-        if grad_reduce == "adasum":
-            raise ValueError(f"wire_format={wire_format!r} does not compose "
-                             f"with adasum — the butterfly is its own wire "
-                             f"pattern")
-        if fusion_threshold is not None:
-            raise ValueError(f"wire_format={wire_format!r} does not compose "
-                             f"with fusion_threshold — the fusion buffers "
-                             f"pack full-precision payloads")
     weight_update = (weight_update or "replicated").strip().lower()
     if weight_update not in ("replicated", "zero1"):
         raise ValueError(f"unknown weight_update {weight_update!r}; "
@@ -503,11 +426,9 @@ def make_train_step(
                          "fusion_threshold — the butterfly is its own wire "
                          "pattern")
     if mesh is None:
-        # World of 1: adasum degrades to identity like every collective,
-        # and there is no wire (or fabric split) for a format to shrink.
+        # World of 1: adasum degrades to identity like every collective.
         body = functools.partial(_grad_step, loss_fn, tx, None, None,
-                                 accum_steps, "mean", "replicated", "fp",
-                                 "flat", "fp")
+                                 accum_steps, "mean", "replicated", "flat")
         return jax.jit(body, donate_argnums=(0,) if donate else (),
                        compiler_options=compiler_options)
 
@@ -534,8 +455,7 @@ def make_train_step(
                              "auto-SPMD has no per-replica grads to combine")
         # Auto-SPMD: annotate shardings, let the partitioner insert collectives.
         body = functools.partial(_grad_step, loss_fn, tx, None, None,
-                                 accum_steps, "mean", "replicated", "fp",
-                                 "flat", "fp")
+                                 accum_steps, "mean", "replicated", "flat")
         state_sh = repl if state_shardings is None else state_shardings
         return jax.jit(
             body,
@@ -549,8 +469,7 @@ def make_train_step(
         raise ValueError(f"unknown step mode {mode!r}")
 
     body = functools.partial(_grad_step, loss_fn, tx, axes, fusion_threshold,
-                             accum_steps, grad_reduce, weight_update,
-                             wire_format, hier, wire_format_dcn)
+                             accum_steps, grad_reduce, weight_update, hier)
     if weight_update == "zero1":
         from tpuframe.parallel import zero1 as zero1_lib
 

@@ -79,11 +79,6 @@ PyTree = Any
 MODES = ("replicated", "zero1")
 ENV_VAR = "TPUFRAME_WEIGHT_UPDATE"
 
-# jax >= 0.6 vma machinery: params must be pcast varying for local grads
-# and gathers can be marked invariant.  On legacy jax (no jax.shard_map)
-# check_rep=False already yields local grads and skips replication checks.
-_HAS_VMA = hasattr(jax, "typeof") and hasattr(lax, "pcast")
-
 
 # ---------------------------------------------------------------------------
 # Mode selection: env > tuning DB > default (mem.policy.resolve's chain).
@@ -323,30 +318,16 @@ def make_state(params: PyTree, tx: optax.GradientTransformation,
 
 
 def _psum_marked(x, bound: tuple[str, ...]):
-    """psum over the axes ``x`` actually varies on (vma-aware on new jax;
-    sized-axes on legacy, where check_rep=False tracks nothing)."""
-    if _HAS_VMA:
-        ax = tuple(a for a in bound if a in jax.typeof(x).vma)
-    else:
-        ax = collectives._sized_axes(bound)
-    # Scalar grad-norm reduction: always under every wire's size floor.
-    return lax.psum(x, ax) if ax else x  # tf-lint: ok[TF115] scalar reduce
-
-
-def _gather_full(shard: jax.Array, bound: tuple[str, ...]) -> jax.Array:
-    """Tiled all-gather of the updated param shard, marked replication-
-    invariant where this jax can express it (every replica gathers the
-    identical full vector)."""
-    return collectives.allgather_invariant(shard, bound)
+    """psum over the axes ``x`` actually varies on."""
+    ax = tuple(a for a in bound if a in jax.typeof(x).vma)
+    return lax.psum(x, ax) if ax else x
 
 
 def sharded_update(tx: optax.GradientTransformation, axes,
                    params: PyTree, opt_state: PyTree,
                    grads: PyTree, *,
-                   wire_format: str = "fp",
                    fusion_threshold: int | None = None,
                    hier: bool = False,
-                   wire_format_dcn: str = "fp",
                    ) -> tuple[PyTree, PyTree, jax.Array]:
     """reduce-scatter → 1/n optimizer update → all-gather.
 
@@ -357,22 +338,7 @@ def sharded_update(tx: optax.GradientTransformation, axes,
     same layout.  The reduce-scatter averages, so the update consumes the
     same global mean gradient as the replicated path.
 
-    ``wire_format="int8-block"`` (tpuframe.parallel.quantwire,
-    arXiv:2506.17615) swaps both gradient-sized collectives for their
-    block-quantized twins.  The scatter quantizes the local gradient —
-    ordinary gradient noise.  The gather CANNOT quantize the raw params:
-    the gathered vector overwrites the replicated master copy, so 8-bit
-    re-gridding there would quantize the *weights* themselves every
-    step.  Instead it gathers the quantized update DELTA
-    (``new_shard - shard``) and adds it to the replicated old params —
-    masters keep full-precision accumulation, the per-step wire error is
-    bounded by one quantization step of the (small) update, and the
-    invariant-old + invariant-gather sum stays replication-invariant.
-    Leaves under ``quantwire.MIN_QUANT_ELEMS`` keep the fp wire on both
-    sides (the derived-budget floors are sized to ignore them).
-
-    ``fusion_threshold`` (fp wire only — ``make_train_step`` rejects the
-    int8 combination) buckets BOTH gradient-sized collectives Horovod-
+    ``fusion_threshold`` buckets BOTH gradient-sized collectives Horovod-
     style (:mod:`tpuframe.parallel.fusion`): padded flat grads pack
     shard-aligned (``fusion.pack_for_scatter``) into ≤threshold-byte
     buffers, ONE reduce-scatter per bucket in, ONE all-gather per bucket
@@ -391,11 +357,7 @@ def sharded_update(tx: optax.GradientTransformation, axes,
     chunk ``j*n_slice + s``): the on-disk order of a sharded opt-state
     dump therefore permutes vs the flat lowering, but the flat
     ``[padded]`` global layout — what elastic resize and checkpoints
-    address — is unchanged.  ``wire_format_dcn="int8-block"`` quantizes
-    the DCN legs alone (scatter payload + update-delta gather; the fp
-    master invariant above holds leg-wise), gated per leaf on the
-    CHUNK clearing ``quantwire.MIN_QUANT_ELEMS`` — the chunk is what
-    rides the wire.  Single-slice (or ``n_inner == 1``) meshes
+    address — is unchanged.  Single-slice (or ``n_inner == 1``) meshes
     degenerate to the flat lowering."""
     bound = collectives._bound_axes(axes)
     if not bound:
@@ -404,16 +366,12 @@ def sharded_update(tx: optax.GradientTransformation, axes,
         updates, new_opt = tx.update(grads, opt_state, params)
         return (optax.apply_updates(params, updates), new_opt,
                 optax.global_norm(grads))
-    n = 1
-    for a in bound:
-        n *= lax.axis_size(a)
+    n = lax.axis_size(bound)
 
     from tpuframe.parallel import hier as hier_lib
-    from tpuframe.parallel import quantwire
 
     inner, has_slice = hier_lib.split_axes(bound)
-    n_inner = quantwire._axis_prod(collectives._sized_axes(inner)) \
-        if inner else 1
+    n_inner = lax.axis_size(inner)
     # Two-stage only when both levels are real; otherwise the flat
     # lowering IS the hierarchy (one level is trivial).
     two_stage = bool(hier) and has_slice and n_inner > 1
@@ -425,40 +383,18 @@ def sharded_update(tx: optax.GradientTransformation, axes,
         pad = _padded(flat.size, n) - flat.size
         return jnp.pad(flat, (0, pad)) if pad else flat
 
-    def quantized(g):
-        return (wire_format == "int8-block"
-                and _padded(_size(g), n) >= quantwire.MIN_QUANT_ELEMS)
-
-    def dcn_quantized(g):
-        # Gate on the CHUNK — the payload the DCN legs actually carry.
-        return (two_stage and wire_format_dcn == "int8-block"
-                and _padded(_size(g), n) // n_inner
-                >= quantwire.MIN_QUANT_ELEMS)
-
     # Grads in: ONE reduce-scatter per leaf (operand = padded grad bytes
     # — the wire cost the dp-zero1 CommBudget declares), averaging over
-    # the world.  Zero padding reduces to zero.  On the int8 wire the
-    # operand is the s8 payload + scales instead (~1/4 the bytes).
+    # the world.  Zero padding reduces to zero.
     # With ``fusion_threshold`` the leaves pack into shard-aligned
     # buckets first — one scatter per bucket, all issued before any
     # shard is unpacked.
-    def scatter_fp(flat):
+    def scatter(flat):
         if two_stage:
             return hier_lib.scatter_mean(flat, inner)
         return collectives.reduce_scatter(flat, bound, average=True)
 
-    def scatter(g):
-        if two_stage:
-            return hier_lib.scatter_mean(
-                flat_pad(g), inner,
-                wire_format_dcn=("int8-block" if dcn_quantized(g)
-                                 else "fp"))
-        if quantized(g):
-            return quantwire.reduce_scatter_mean(flat_pad(g), bound)
-        return collectives.reduce_scatter(flat_pad(g), bound, average=True)
-
-    fused = (fusion_threshold is not None and wire_format == "fp"
-             and wire_format_dcn == "fp")
+    fused = fusion_threshold is not None
     if fused:
         from tpuframe.parallel import fusion
 
@@ -468,9 +404,9 @@ def sharded_update(tx: optax.GradientTransformation, axes,
         issued = []
         for bucket in buckets:
             if len(bucket) == 1:
-                issued.append(scatter_fp(g_flat[bucket[0]]))
+                issued.append(scatter(g_flat[bucket[0]]))
             else:
-                issued.append(scatter_fp(
+                issued.append(scatter(
                     fusion.pack_for_scatter([g_flat[i] for i in bucket],
                                             n)))
         g_out = [None] * len(g_leaves)
@@ -484,7 +420,7 @@ def sharded_update(tx: optax.GradientTransformation, axes,
                 g_out[i] = part
         gshard = jax.tree.unflatten(g_def, g_out)
     else:
-        gshard = jax.tree.map(scatter, grads)
+        gshard = jax.tree.map(lambda g: scatter(flat_pad(g)), grads)
     # Params are replicated, so each replica's shard is a free local
     # slice at the same row-major linear index the scatter used.
     def param_shard(t):
@@ -503,27 +439,14 @@ def sharded_update(tx: optax.GradientTransformation, axes,
     grad_norm = jnp.sqrt(_psum_marked(sq, bound))
 
     # Params out: tiled all-gather (result = padded param bytes), then
-    # un-pad and fold back to the original shapes.  On the int8 wire the
-    # update DELTA is gathered quantized and added to the replicated old
-    # params (see docstring — masters never lose precision).
-    def gather_fp(shard):
+    # un-pad and fold back to the original shapes.
+    def gather(shard):
         if two_stage:
             return hier_lib.gather(shard, inner)
-        return _gather_full(shard, bound)
+        return collectives.allgather_invariant(shard, bound)
 
-    def regather(old_shard, shard, like):
-        if two_stage and dcn_quantized(like):
-            # Two-stage delta gather: quantized over DCN, fp over ICI.
-            delta = hier_lib.gather_delta(shard - old_shard, inner)
-            full = flat_pad(like) + delta.astype(like.dtype)
-        elif two_stage:
-            full = hier_lib.gather(shard, inner)
-        elif quantized(like):
-            delta = quantwire.all_gather(shard - old_shard, bound)
-            full = flat_pad(like) + delta.astype(like.dtype)
-        else:
-            full = _gather_full(shard, bound)
-        return full[:_size(like)].reshape(like.shape)
+    def regather(shard, like):
+        return gather(shard)[:_size(like)].reshape(like.shape)
 
     if fused:
         # Params out, bucketed: the same buckets the scatter used (grads
@@ -534,9 +457,9 @@ def sharded_update(tx: optax.GradientTransformation, axes,
         gathered = []
         for bucket in buckets:
             if len(bucket) == 1:
-                gathered.append(gather_fp(s_leaves[bucket[0]]))
+                gathered.append(gather(s_leaves[bucket[0]]))
             else:
-                gathered.append(gather_fp(
+                gathered.append(gather(
                     jnp.concatenate([s_leaves[i] for i in bucket])))
         p_out = [None] * len(p_leaves)
         for full, bucket in zip(gathered, buckets):
@@ -552,7 +475,7 @@ def sharded_update(tx: optax.GradientTransformation, axes,
                     p_leaves[i].shape)
         new_params = jax.tree.unflatten(s_def, p_out)
     else:
-        new_params = jax.tree.map(regather, pshard, new_pshard, params)
+        new_params = jax.tree.map(regather, new_pshard, params)
     return new_params, new_opt, grad_norm
 
 

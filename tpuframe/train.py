@@ -153,13 +153,6 @@ class Harness:
     # (mode, resolution source) from tpuframe.parallel.zero1.resolve —
     # ("replicated", "default") when nothing elected weight-update sharding.
     weight_update: tuple = ("replicated", "default")
-    # (format, resolution source) from tpuframe.parallel.quantwire.resolve
-    # — ("fp", "default") when nothing elected a quantized wire.
-    wire_format: tuple = ("fp", "default")
-    # (format, resolution source) for the cross-slice DCN leg from
-    # tpuframe.parallel.quantwire.resolve_legs — ("fp", "default") when
-    # nothing elected a quantized DCN wire (needs hier="hier").
-    wire_format_dcn: tuple = ("fp", "default")
     # (mode, resolution source) from tpuframe.parallel.hier.resolve —
     # ("flat", "default") when nothing elected two-level collectives.
     hier: tuple = ("flat", "default")
@@ -185,20 +178,11 @@ def _resolved_fusion(cfg: TrainConfig) -> tuple:
     :func:`_lm_reduce_axis`, so the explicit-fusion step mode and its
     local-loss requirement cannot disagree about whether fusion is on."""
     from tpuframe.parallel import fusion as fusion_lib
-    from tpuframe.parallel import quantwire
 
     model_tag = cfg.model.replace("-", "_")
-    program = f"train_{model_tag}_b{cfg.global_batch}"
-    threshold, source = fusion_lib.resolve(program=program,
-                                           family="fusion_threshold")
-    if threshold is not None and source != "env":
-        (wf, wf_src), _ = quantwire.resolve_legs(
-            program=program, family=f"wire_format_{model_tag}")
-        if wf != "fp" and wf_src == "env":
-            # An explicit env-elected quantized wire owns the gradient
-            # path; the advisory DB-elected bucket threshold yields.
-            threshold, source = None, "default"
-    return threshold, source
+    return fusion_lib.resolve(
+        program=f"train_{model_tag}_b{cfg.global_batch}",
+        family="fusion_threshold")
 
 
 def build_harness(cfg: TrainConfig) -> Harness:
@@ -374,33 +358,12 @@ def build_harness(cfg: TrainConfig) -> Harness:
                  or cfg.grad_reduce == "adasum")):
         weight_update, wu_source = "replicated", "default"
 
-    # Gradient-path wire format (int8-block quantized collectives): same
-    # resolution shape — TPUFRAME_WIRE_FORMAT env wins, else the DB's
-    # offline wire_format_* sweep winner (generation-gated), else full
-    # precision.  Same fallback discipline too: on configs the quantized
-    # wire cannot serve (pp, auto-SPMD sharded state, no mesh, adasum) a
-    # DB-elected format falls back silently while an explicit env ask
-    # gets make_train_step's specific error.
-    from tpuframe.parallel import quantwire
-
-    (wire_format, wf_source), (wire_format_dcn, wfd_source) = \
-        quantwire.resolve_legs(
-            program=f"train_{model_tag}_b{cfg.global_batch}",
-            family=f"wire_format_{model_tag}",
-            family_dcn="hier_collectives")
-    if (wire_format != "fp" and wf_source != "env"
-            and (use_pp or use_sharded_state or mesh is None
-                 or cfg.grad_reduce == "adasum")):
-        wire_format, wf_source = "fp", "default"
-
     # Hierarchical two-level collectives: TPUFRAME_HIER env wins, else
     # the DB's offline hier_collectives sweep winner (generation-gated),
     # else flat.  Same fallback discipline: on configs the two-level
     # lowering cannot serve (pp, auto-SPMD sharded state, no mesh,
-    # adasum, a program-wide quantized wire, sequence sharding) a
-    # DB-elected mode demotes silently while an explicit env ask gets
-    # make_train_step's specific error.  The DCN-leg wire format rides
-    # the lowering: without hier it demotes to fp the same way.
+    # adasum, sequence sharding) a DB-elected mode demotes silently
+    # while an explicit env ask gets make_train_step's specific error.
     from tpuframe.parallel import hier as hier_lib
 
     hier_mode, hier_source = hier_lib.resolve(
@@ -408,12 +371,8 @@ def build_harness(cfg: TrainConfig) -> Harness:
         family=hier_lib.DB_FAMILY)
     if (hier_mode != "flat" and hier_source != "env"
             and (use_pp or use_sharded_state or mesh is None
-                 or cfg.grad_reduce == "adasum" or wire_format != "fp"
-                 or cfg.shard_seq)):
+                 or cfg.grad_reduce == "adasum" or cfg.shard_seq)):
         hier_mode, hier_source = "flat", "default"
-    if (wire_format_dcn != "fp" and wfd_source != "env"
-            and hier_mode != "hier"):
-        wire_format_dcn, wfd_source = "fp", "default"
 
     # GPipe pp takes no gradient-fusion modifier; the knob resolves (and
     # can be DB-elected) only on the shard_map branch below.
@@ -444,10 +403,6 @@ def build_harness(cfg: TrainConfig) -> Harness:
             raise ValueError("TPUFRAME_WEIGHT_UPDATE=zero1 is the plain-DP "
                              "shard_map path; the pipeline step owns its "
                              "own stage-sharded update")
-        if wire_format != "fp":
-            raise ValueError("TPUFRAME_WIRE_FORMAT=int8-block is the "
-                             "plain-DP shard_map path; the pipeline step "
-                             "owns its own cross-stage communication")
         if hier_mode != "flat":
             raise ValueError("TPUFRAME_HIER=hier is the plain-DP "
                              "shard_map path; the pipeline step owns its "
@@ -501,8 +456,7 @@ def build_harness(cfg: TrainConfig) -> Harness:
         # Gradient-fusion bucket threshold: same resolution shape as the
         # other knobs — TPUFRAME_FUSION_THRESHOLD env wins, else the
         # DB's generation-gated fusion_threshold sweep winner, else
-        # per-leaf (the helper also yields a DB-elected threshold to an
-        # env-elected quantized wire).  A DB-elected threshold serves
+        # per-leaf.  A DB-elected threshold serves
         # the shard_map gradient path only: where the step ignores the
         # knob (unmapped jit, auto-SPMD sharded state) it demotes
         # silently.
@@ -510,18 +464,6 @@ def build_harness(cfg: TrainConfig) -> Harness:
         if (fusion_threshold is not None and ft_source != "env"
                 and (mesh is None or use_sharded_state)):
             fusion_threshold, ft_source = None, "default"
-        if (wire_format != "fp" and wf_source != "env"
-                and (fusion_threshold or cfg.grad_reduce == "adasum")):
-            # Explicit-fusion mode reduces bucket-by-bucket inside the
-            # step; the quantized wire only serves the implicit/zero1
-            # paths.  A DB-elected format demotes silently here too.
-            wire_format, wf_source = "fp", "default"
-        if (wire_format_dcn != "fp" and wfd_source != "env"
-                and fusion_threshold):
-            # The quantized DCN leg rides the per-leaf hier lowering;
-            # bucketed fusion concatenates leaves past the block
-            # heuristics, so a DB-elected DCN format demotes silently.
-            wire_format_dcn, wfd_source = "fp", "default"
         train_step = step_lib.make_train_step(
             loss_fn, tx, mesh, batch_partition=step_part,
             reduce_axes=reduce_axes, state_shardings=state_shardings,
@@ -531,9 +473,7 @@ def build_harness(cfg: TrainConfig) -> Harness:
             compiler_options=xla_opts,
             remat_policy=step_policy,
             weight_update=weight_update,
-            wire_format=wire_format,
-            hier=hier_mode,
-            wire_format_dcn=wire_format_dcn)
+            hier=hier_mode)
         eval_step = step_lib.make_eval_step(
             make_metric_fn(cfg, model), mesh, batch_partition=step_part,
             reduce_axes=reduce_axes, state_shardings=state_shardings)
@@ -567,8 +507,6 @@ def build_harness(cfg: TrainConfig) -> Harness:
                    manager=manager, start_step=start_step,
                    remat_policy=(remat_policy, remat_source),
                    weight_update=(weight_update, wu_source),
-                   wire_format=(wire_format, wf_source),
-                   wire_format_dcn=(wire_format_dcn, wfd_source),
                    hier=(hier_mode, hier_source),
                    fusion_threshold=(fusion_threshold, ft_source),
                    pspec=(spec.canonical() if spec is not None else None,
@@ -1155,20 +1093,10 @@ def _train_impl(cfg: TrainConfig, threads: contextlib.ExitStack, *,
             source=h.weight_update[1],
             n_shards=(zero1_lib.world_size(h.mesh)
                       if h.mesh is not None else 1))
-        # Wire-format provenance, same contract: which gradient-path
-        # wire the run actually compiled with and who elected it — the
-        # analyzer joins this with the roofline's comm model to check
-        # the predicted byte drop landed.
-        # Both fabric legs ride the one record: ``format``/``source`` is
-        # the in-slice ICI leg (the historical single-fabric field pair),
-        # ``format_dcn``/``source_dcn`` the cross-slice DCN leg, and
-        # ``hier``/``hier_source`` says whether the two-level lowering
-        # that separates the legs was actually compiled in.
-        events_lib.emit("wire_format", format=h.wire_format[0],
-                        source=h.wire_format[1],
-                        format_dcn=h.wire_format_dcn[0],
-                        source_dcn=h.wire_format_dcn[1],
-                        hier=h.hier[0], hier_source=h.hier[1])
+        # Two-level-collective provenance, same contract: whether the
+        # lowering that keeps full gradient bytes on ICI was compiled in
+        # and who elected it.
+        events_lib.emit("hier", mode=h.hier[0], source=h.hier[1])
         # Gradient-fusion provenance, same contract: which bucket
         # threshold the step actually compiled with (None = per-leaf)
         # and who elected it — the analyzer joins this with the

@@ -5,7 +5,6 @@
     python -m tpuframe.tune sweep --remat               # remat policy search
     python -m tpuframe.tune sweep --serve               # serving decode grid
     python -m tpuframe.tune sweep --zero1               # weight-update sharding
-    python -m tpuframe.tune sweep --wire                # wire-format search
     python -m tpuframe.tune sweep --fusion              # fusion bucket grid
     python -m tpuframe.tune sweep --hier                # two-level collectives
     python -m tpuframe.tune show                        # ranked DB contents
@@ -64,11 +63,6 @@ def _cmd_sweep(args) -> int:
                            report_path=args.report,
                            batch=args.zero1_batch)
         return 0
-    if args.wire:
-        search.wire_sweep(args.topology, db_path=args.db,
-                          report_path=args.report,
-                          batch=args.wire_batch)
-        return 0
     if args.fusion:
         search.fusion_sweep(args.topology, db_path=args.db,
                             report_path=args.report,
@@ -106,7 +100,7 @@ def _cmd_hier_probe(args) -> int:
 
     payload = search._hier_probe_row(args.topology, args.slices,
                                      args.program, args.batch, args.mode,
-                                     args.hier, args.wire_format_dcn)
+                                     args.hier)
     with open(args.out, "w") as f:
         json.dump(payload, f)
     return 0
@@ -188,12 +182,6 @@ def main(argv=None) -> int:
                          "ZeRO-1) over the donated ResNet-50 + BERT train "
                          "steps (weight_update_* families)")
     sw.add_argument("--zero1-batch", type=int, default=512)
-    sw.add_argument("--wire", action="store_true",
-                    help="sweep gradient-path wire formats (fp vs "
-                         "int8-block quantized collectives) over the "
-                         "donated ResNet-50 DP + BERT ZeRO-1 train steps "
-                         "(wire_format_* families)")
-    sw.add_argument("--wire-batch", type=int, default=512)
     sw.add_argument("--fusion", action="store_true",
                     help="sweep gradient-fusion bucket thresholds over "
                          "the donated ResNet-50 DP train step, ranked by "
@@ -202,9 +190,9 @@ def main(argv=None) -> int:
     sw.add_argument("--fusion-batch", type=int, default=512)
     sw.add_argument("--hier", action="store_true",
                     help="sweep two-level collectives on a compile-only "
-                         "MULTI-slice topology (flat vs hier x fp vs "
-                         "int8-block DCN leg), ranked on step + ICI + "
-                         "DCN ms (hier_collectives family)")
+                         "MULTI-slice topology (flat vs hier), ranked "
+                         "on step + ICI + DCN ms (hier_collectives "
+                         "family)")
     sw.add_argument("--hier-batch", type=int, default=512)
     sw.add_argument("--hier-slices", type=int, default=2,
                     help="slice count for the compile-only multi-slice "
@@ -253,7 +241,6 @@ def main(argv=None) -> int:
     hp.add_argument("--batch", type=int, default=512)
     hp.add_argument("--mode", default="replicated")
     hp.add_argument("--hier", default="flat")
-    hp.add_argument("--wire-format-dcn", default="fp")
     hp.add_argument("--out", required=True)
     hp.set_defaults(fn=_cmd_hier_probe)
 

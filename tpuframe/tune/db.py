@@ -142,10 +142,6 @@ class Record:
             env["TPUFRAME_REMAT_POLICY"] = str(cfg["remat_policy"])
         if "weight_update" in cfg:
             env["TPUFRAME_WEIGHT_UPDATE"] = str(cfg["weight_update"])
-        if "wire_format" in cfg:
-            env["TPUFRAME_WIRE_FORMAT"] = str(cfg["wire_format"])
-        if "wire_format_dcn" in cfg:
-            env["TPUFRAME_WIRE_FORMAT_DCN"] = str(cfg["wire_format_dcn"])
         if "hier" in cfg:
             env["TPUFRAME_HIER"] = str(cfg["hier"])
         if "fusion_threshold" in cfg:
@@ -417,57 +413,6 @@ def resolve_weight_update(program: str,
         return None
     mode = rec.config.get("weight_update")
     return str(mode) if mode else None
-
-
-def resolve_wire_format(program: str,
-                        family: str | None = None) -> str | None:
-    """Gradient-path collective wire format for ``program``: None unless
-    the DB has a swept ``wire_format_*`` winner for the target
-    generation.  Callers apply ``TPUFRAME_WIRE_FORMAT`` themselves FIRST
-    via :func:`tpuframe.parallel.quantwire.resolve` — when the env var is
-    set this returns None so the override is unambiguous."""
-    if os.environ.get("TPUFRAME_WIRE_FORMAT", "").strip():
-        return None
-    gen = target_generation()
-    if gen is None:
-        return None
-    db = _open_for_resolution()
-    if db is None:
-        return None
-    rec = db.best(program=program, generation=gen)
-    if (rec is None or "wire_format" not in rec.config) \
-            and family is not None:
-        rec = db.best(family=family, generation=gen)
-    if rec is None:
-        return None
-    fmt = rec.config.get("wire_format")
-    return str(fmt) if fmt else None
-
-
-def resolve_wire_format_dcn(program: str,
-                            family: str | None = None) -> str | None:
-    """Wire format of the cross-slice (DCN) leg of the two-level
-    lowering for ``program``: None unless the DB has a swept
-    ``hier_collectives`` winner for the target generation.  Callers
-    apply ``TPUFRAME_WIRE_FORMAT_DCN`` themselves FIRST via
-    :func:`tpuframe.parallel.quantwire.resolve_legs` — when the env var
-    is set this returns None so the override is unambiguous."""
-    if os.environ.get("TPUFRAME_WIRE_FORMAT_DCN", "").strip():
-        return None
-    gen = target_generation()
-    if gen is None:
-        return None
-    db = _open_for_resolution()
-    if db is None:
-        return None
-    rec = db.best(program=program, generation=gen)
-    if (rec is None or "wire_format_dcn" not in rec.config) \
-            and family is not None:
-        rec = db.best(family=family, generation=gen)
-    if rec is None:
-        return None
-    fmt = rec.config.get("wire_format_dcn")
-    return str(fmt) if fmt else None
 
 
 def resolve_hier(program: str,

@@ -16,19 +16,18 @@ the analysis-v3 cost stack:
     legally-interleavable compute),
   - liveness peak-HBM vs the generation's capacity (``fits``).
 
-The objective is ``predicted_total_ms = step + t_ici + t_dcn`` — the
-same step-plus-wire objective the §20 wire sweep ranks on, extended
-with the DCN column.  The winner is persisted to ``tune_db.json``
-(family ``plan_spec``) under the standard env > DB > default
-resolution, so ``train.py`` consumes a planned spec unless
+The objective is ``predicted_total_ms = step + t_ici + t_dcn``.  The
+winner is persisted to ``tune_db.json`` (family ``plan_spec``) under
+the standard env > DB > default resolution, so ``train.py`` consumes a planned spec unless
 ``TPUFRAME_SPEC`` overrides it.
 
 The pinned, schema-versioned report (``perf/results/plan_report_*``)
 plus :func:`check`'s seeded ranking-drift positive make the planner a
 gate leg, not a demo: the checked-in ranking must be re-derivable from
 the checked-in rows, and the report must statically reproduce the
-pinned PERF verdicts (§18 replicated-vs-zero1 bytes, §20 fp-vs-int8
-totals, §23 DCN dominance on the composed spec) from cost models alone.
+pinned PERF verdicts (§18 replicated-vs-zero1 bytes, §23 DCN dominance
+on the composed spec, §28 the two-level lowering's DCN cut) from cost
+models alone.
 
 Everything here is CPU-host only; jax is imported lazily (:func:`check`
 runs in the analysis gate, which must stay cheap when the report is
@@ -92,16 +91,13 @@ def enumerate_candidates(n_devices: int, n_slices: int = 1) -> list:
     Specs are written with the ``dp=*`` wildcard so one grid serves any
     world size; degrees that cannot fit ``n_devices`` are recorded as
     skips by the sweep (the spec is for a different world), never
-    silently dropped.  Modifier candidates (zero1 / int8-block / adasum /
-    bucketed fusion) ride the plain-dp spec — they are step modifiers,
+    silently dropped.  Modifier candidates (zero1 / adasum / bucketed
+    fusion) ride the plain-dp spec — they are step modifiers,
     not mesh axes."""
     tail = f";slices={n_slices}" if n_slices > 1 else ""
     cands = [
         {"spec": "dp=*" + tail},
         {"spec": "dp=*" + tail, "weight_update": "zero1"},
-        {"spec": "dp=*" + tail, "wire_format": "int8-block"},
-        {"spec": "dp=*" + tail, "weight_update": "zero1",
-         "wire_format": "int8-block"},
         {"spec": "dp=*" + tail, "grad_reduce": "adasum"},
         # Bucketed-fusion variants: the staged overlapped gradient pass
         # at the registry threshold (strategies._FUSED_REGISTRY_THRESHOLD
@@ -125,15 +121,11 @@ def enumerate_candidates(n_devices: int, n_slices: int = 1) -> list:
         cands.append({"spec": f"dp=2,fsdp=2;slices={n_slices}"})
         # §28 two-level candidates: the hierarchical lowering (in-slice
         # reduce-scatter → cross-slice exchange of 1/n_inner → in-slice
-        # all-gather) and its int8-block DCN leg, alone and composed
-        # with ZeRO-1.  Only meaningful with a slice axis to cross.
+        # all-gather), alone and composed with ZeRO-1.  Only
+        # meaningful with a slice axis to cross.
         cands.append({"spec": "dp=*" + tail, "hier": "hier"})
-        cands.append({"spec": "dp=*" + tail, "hier": "hier",
-                      "wire_format_dcn": "int8-block"})
         cands.append({"spec": "dp=*" + tail, "weight_update": "zero1",
                       "hier": "hier"})
-        cands.append({"spec": "dp=*" + tail, "weight_update": "zero1",
-                      "hier": "hier", "wire_format_dcn": "int8-block"})
     return cands
 
 
@@ -160,7 +152,7 @@ def _row(rows: list, name: str) -> dict | None:
 
 
 def compute_verdicts(rows: list) -> dict:
-    """Re-derive the four pinned PERF verdicts from the candidate rows.
+    """Re-derive the three pinned PERF verdicts from the candidate rows.
 
     Pure arithmetic over the report — no jax, no recompile — so the
     gate can re-check them against the stored booleans forever.  Each
@@ -182,27 +174,6 @@ def compute_verdicts(rows: list) -> dict:
     else:
         v["holds"] = None
     verdicts["zero1_bytes"] = v
-
-    fp = _row(rows, "spec:dp=*")
-    int8 = _row(rows, "spec:dp=*+int8-block")
-    v = {"perf_section": 20,
-         "claim": "at this scale the fp wire beats int8-block on the "
-                  "step+wire total: the quantize arithmetic lands in "
-                  "the step roofline and costs more than the saved "
-                  "bytes — the totals decide, the bytes alone do not"}
-    if fp and int8:
-        ratio = (int8["comm_bytes"] / fp["comm_bytes"]
-                 if fp["comm_bytes"] else None)
-        v.update(fp_total_ms=fp["predicted_total_ms"],
-                 int8_total_ms=int8["predicted_total_ms"],
-                 fp_comm_bytes=fp["comm_bytes"],
-                 int8_comm_bytes=int8["comm_bytes"],
-                 wire_bytes_ratio=round(ratio, 3) if ratio else None,
-                 holds=(fp["predicted_total_ms"]
-                        < int8["predicted_total_ms"]))
-    else:
-        v["holds"] = None
-    verdicts["wire_bytes"] = v
 
     composed = None
     for r in rows:
@@ -226,26 +197,19 @@ def compute_verdicts(rows: list) -> dict:
 
     flat2 = _row(rows, "spec:dp=*;slices=2")
     hier2 = _row(rows, "spec:dp=*;slices=2+hier")
-    hier_i8 = _row(rows, "spec:dp=*;slices=2+hier+dcn-int8")
     v = {"perf_section": 28,
          "claim": "the two-level lowering crushes the DCN term: +hier "
                   "moves <= 1/n_inner of the flat cross-slice bytes "
-                  "over DCN (t_dcn follows), and the int8-block DCN "
-                  "leg cuts strictly deeper"}
+                  "over DCN (t_dcn follows)"}
     if flat2 and hier2 and flat2.get("dcn_bytes"):
         ratio = hier2["dcn_bytes"] / flat2["dcn_bytes"]
-        holds = ratio <= 0.5 and hier2["t_dcn_ms"] < flat2["t_dcn_ms"]
         v.update(flat_dcn_bytes=flat2["dcn_bytes"],
                  hier_dcn_bytes=hier2["dcn_bytes"],
                  dcn_bytes_ratio=round(ratio, 4),
                  flat_t_dcn_ms=flat2["t_dcn_ms"],
-                 hier_t_dcn_ms=hier2["t_dcn_ms"])
-        if hier_i8:
-            r8 = hier_i8["dcn_bytes"] / flat2["dcn_bytes"]
-            v.update(int8_dcn_bytes=hier_i8["dcn_bytes"],
-                     int8_dcn_bytes_ratio=round(r8, 4))
-            holds = holds and r8 < ratio
-        v["holds"] = holds
+                 hier_t_dcn_ms=hier2["t_dcn_ms"],
+                 holds=(ratio <= 0.5
+                        and hier2["t_dcn_ms"] < flat2["t_dcn_ms"]))
     else:
         v["holds"] = None
     verdicts["hier_dcn"] = v
@@ -287,12 +251,10 @@ def plan(topology: str = "v5e:2x2", *, slice_counts=(1, 2),
             audit = strategies.audit_spec(
                 cand["spec"], n_devices=n, devices=devices,
                 weight_update=cand.get("weight_update", "replicated"),
-                wire_format=cand.get("wire_format"),
                 seq_mode=cand.get("seq_mode"),
                 grad_reduce=cand.get("grad_reduce"),
                 fusion_threshold=cand.get("fusion_threshold"),
-                hier=cand.get("hier"),
-                wire_format_dcn=cand.get("wire_format_dcn"))
+                hier=cand.get("hier"))
             base = {"name": audit.name, "spec": cand["spec"],
                     "slices": n_slices, "n_devices": n,
                     "compile_topology": compile_topo,

@@ -243,10 +243,9 @@ def comm_ms(generation: str, kind: str, nbytes: float,
             n_devices: int) -> float:
     """Predicted ICI milliseconds for one collective: ring model,
     ``factor * (n-1)/n * bytes / ici_bw``.  ``nbytes`` must be the op's
-    bytes as PARSED FROM THE COMPILED HLO (``hlo_audit``'s ruler — an s8
-    payload counts 1 byte/element), never re-derived from the program's
-    accumulation dtype: a quantized wire moves a quarter of the f32
-    bytes and the prediction has to see that."""
+    bytes as PARSED FROM THE COMPILED HLO (``hlo_audit``'s ruler — a
+    bf16 payload counts 2 bytes/element), never re-derived from the
+    program's accumulation dtype."""
     hw = get_hardware(generation)
     if hw.ici_bytes_per_s <= 0 or n_devices <= 1:
         return 0.0
@@ -261,7 +260,7 @@ def dcn_ms(generation: str, kind: str, nbytes: float,
     same ring model as :func:`comm_ms` but over the slice count and the
     per-chip DCN share — ``factor * (s-1)/s * bytes / dcn_bw``.  Like
     the ICI model, ``nbytes`` is the op's bytes as parsed from the
-    compiled HLO (quantized wires count their actual payload)."""
+    compiled HLO."""
     hw = get_hardware(generation)
     if hw.dcn_bytes_per_s <= 0 or n_slices <= 1:
         return 0.0
@@ -287,8 +286,7 @@ def comm_score(generation: str, report, n_devices: int) -> dict:
     ``report`` is an ``hlo_audit.CollectiveReport`` (or anything with
     ``bytes_by_kind()``).  Wire-dtype awareness comes from the report
     itself: its byte totals were counted off the optimized HLO's result
-    shapes, so an int8-block program's a2a/all-gather rows carry ~1/4
-    the bytes of the f32 all-reduce they replaced.  ``t_ici_ms`` totals
+    shapes.  ``t_ici_ms`` totals
     are a LOWER bound (assumes zero overlap loss, full ring bandwidth).
     """
     by_kind = report.bytes_by_kind()
@@ -401,16 +399,10 @@ def check_tables() -> list:
         if not hw.ici_bytes_per_s > 0:
             problems.append(f"hardware table {gen}: non-positive ICI peak")
     # Comm-model anchor: ResNet-50's 102.23 MB f32 grad all-reduce on a
-    # v5e 2x2 ring is 2 * 3/4 * 1.0223e8 / 200e9 = 0.767 ms, and the same
-    # gradient on the int8-block wire (bytes/4 by the HLO ruler) predicts
-    # exactly a quarter of that — the wire-dtype awareness is the invariant.
+    # v5e 2x2 ring is 2 * 3/4 * 1.0223e8 / 200e9 = 0.767 ms.
     t_f32 = comm_ms("v5e", "all-reduce", 1.0223e8, 4)
-    t_s8 = comm_ms("v5e", "all-reduce", 1.0223e8 / 4, 4)
     if abs(t_f32 - 0.767) > 0.005:
         problems.append(f"v5e comm anchor drifted: {t_f32:.4f} != 0.767 ms")
-    if abs(t_s8 * 4 - t_f32) > 1e-9:
-        problems.append("comm model is not linear in wire bytes — "
-                        "int8 prediction must be f32/4")
     # DCN anchor (mirrors the ICI one): the same 102.23 MB grad
     # all-reduce crossing 2 slices is 2 * 1/2 * 1.0223e8 / 6.25e9 =
     # 16.357 ms — ~21x the 4-chip ICI ring, which is the whole point of

@@ -412,10 +412,9 @@ def remat_sweep(topology: str = "v5e:2x2", *, db_path: str | None = None,
 
 
 def _zero1_step_compile(topo_devices, program: str, batch: int,
-                        weight_update: str, wire_format: str = "fp",
+                        weight_update: str,
                         fusion_threshold: int | None = None,
-                        slices: int = 1, hier: str = "flat",
-                        wire_format_dcn: str = "fp"):
+                        slices: int = 1, hier: str = "flat"):
     """AOT-compile one donated train step over the FULL topology under one
     weight-update mode.  Unlike the remat sweep's single-chip rig, the
     collective swap is the whole point here — the reduce-scatter /
@@ -546,10 +545,8 @@ def _zero1_step_compile(topo_devices, program: str, batch: int,
 
     step = step_lib.make_train_step(loss_fn, tx, mesh, donate=True,
                                     weight_update=weight_update,
-                                    wire_format=wire_format,
                                     fusion_threshold=fusion_threshold,
-                                    hier=hier,
-                                    wire_format_dcn=wire_format_dcn)
+                                    hier=hier)
     lowered = step.lower(state, batch_structs)
     if fusion_threshold is not None:
         # The staged pass owns bucketing: hand the XLA all-reduce
@@ -564,7 +561,7 @@ def _zero1_step_compile(topo_devices, program: str, batch: int,
         compiled = lowered.compile()
     desc = {"program": f"train_{program}_b{batch}", "n_chips": n,
             "global_batch": batch, "donate": True,
-            "weight_update": weight_update, "wire_format": wire_format}
+            "weight_update": weight_update}
     if fusion_threshold is not None:
         desc["fusion_threshold"] = int(fusion_threshold)
     # Only stamp the hierarchical fields on multi-slice compiles so the
@@ -573,7 +570,6 @@ def _zero1_step_compile(topo_devices, program: str, batch: int,
     if slices > 1:
         desc["slices"] = int(slices)
         desc["hier"] = hier
-        desc["wire_format_dcn"] = wire_format_dcn
     return compiled, desc, opt_bytes, census
 
 
@@ -684,115 +680,6 @@ def zero1_sweep(topology: str = "v5e:2x2", *, db_path: str | None = None,
     return report
 
 
-def wire_sweep(topology: str = "v5e:2x2", *, db_path: str | None = None,
-               report_path: str | None = None, batch: int = 512,
-               bert_batch: int = 256, log=None) -> dict:
-    """Offline wire-format search: AOT-compile the donated ResNet-50
-    (plain DP) and BERT (ZeRO-1) train steps once per
-    ``tpuframe.parallel.quantwire`` format over the full topology, rank
-    on the roofline's predicted step time PLUS the ICI comm model's
-    predicted collective time, and persist every candidate to the
-    ``wire_format_*`` DB families.  The comm bytes per row come from the
-    compiled HLO itself (``hlo_audit`` — an s8 payload counts one byte
-    per element), which is what makes the int8-block rows honest: the
-    quantized wire's ~4x byte drop shows up exactly where the program
-    put it (dp's grad all-reduce; ZeRO-1's param all-gather, the +9%
-    BERT leg of PERF §18)."""
-    import jax  # noqa: F401 — fail fast before holding the lock
-    from jax.experimental import topologies
-
-    from tpuframe.analysis import hlo_audit
-
-    hold_aot_lock()
-    os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
-    gen = roofline.generation_from_topology(topology)
-    topo = topologies.get_topology_desc(topology, platform="tpu")
-    n = len(topo.devices)
-    # dp exercises the all-reduce -> quantized a2a+ag swap; dp-zero1
-    # exercises the rs+ag -> quantized a2a + s8 delta-gather swap.
-    configs = (("resnet50", batch, "replicated"),
-               ("bert", bert_batch, "zero1"))
-    _log(f"wire sweep on {topology} ({n} chips): "
-         f"{[(p, m) for p, _, m in configs]} x ('fp', 'int8-block')", log)
-
-    db_path = db_path or tune_db.default_db_path()
-    db = tune_db.TuningDB.open(db_path) if os.path.exists(db_path) \
-        else tune_db.TuningDB(db_path)
-    report = {"topology": topology, "generation": gen, "n_chips": n,
-              "objective": "predicted_ms + t_ici_ms (comm model on "
-                           "HLO-parsed wire bytes)",
-              "wire_format": {"rows": [], "compile_errors": []}}
-
-    for program, b, mode in configs:
-        baseline = {}
-        for fmt in ("fp", "int8-block"):
-            try:
-                compiled, desc, _opt_bytes, _census = _zero1_step_compile(
-                    topo.devices, program, b, mode, wire_format=fmt)
-            except Exception as e:  # noqa: BLE001 — record, keep sweeping
-                row = {"program": program, "wire_format": fmt,
-                       "weight_update": mode,
-                       "error": f"{type(e).__name__}: {e}"[:300]}
-                report["wire_format"]["compile_errors"].append(row)
-                _log(f"  {program}/{fmt}: COMPILE ERROR "
-                     f"{row['error'][:80]}", log)
-                continue
-            pred = roofline.score_compiled(compiled, gen)
-            pred["source"] = "compiled"
-            coll = hlo_audit.parse_collectives(compiled.as_text())
-            comm = roofline.comm_score(gen, coll.filter(1024), n)
-            pred["comm"] = comm
-            total_ms = round(pred["predicted_ms"] + comm["t_ici_ms"], 3)
-            pred["predicted_total_ms"] = total_ms
-            row = {"program": program, "wire_format": fmt,
-                   "weight_update": mode, "global_batch": b,
-                   "predicted_ms": pred["predicted_ms"],
-                   "t_ici_ms": comm["t_ici_ms"],
-                   "predicted_total_ms": total_ms,
-                   "comm_bytes": comm["comm_bytes"],
-                   "comm_rows": comm["rows"], "bound": pred["bound"]}
-            if fmt == "fp":
-                baseline = {"comm_bytes": comm["comm_bytes"],
-                            "total_ms": total_ms}
-            if baseline.get("comm_bytes"):
-                row["wire_bytes_ratio_vs_fp"] = round(
-                    comm["comm_bytes"] / baseline["comm_bytes"], 3)
-            db.add({"program": desc["program"],
-                    "family": f"wire_format_{program}",
-                    "fingerprint": tune_db.fingerprint(desc),
-                    "topology": topology, "generation": gen,
-                    "config": {"wire_format": fmt, "batch": b,
-                               "weight_update": mode},
-                    "predicted": pred})
-            report["wire_format"]["rows"].append(row)
-            _log(f"  {program}/{fmt}: {row['predicted_total_ms']} ms "
-                 f"total ({row['predicted_ms']} step + {row['t_ici_ms']} "
-                 f"ICI), {comm['comm_bytes'] / 1e6:.2f} MB on the wire",
-                 log)
-
-    rows = report["wire_format"]["rows"]
-    winners = {}
-    for program, _, _ in configs:
-        prog_rows = [r for r in rows if r["program"] == program]
-        prog_rows.sort(
-            key=lambda r: r.get("predicted_total_ms") or float("inf"))
-        if prog_rows:
-            winners[program] = prog_rows[0]
-    report["winners"] = winners
-    db.save()
-    _log(f"tuning DB: {db.path} ({len(db.data['records'])} records)", log)
-    if report_path is None:
-        tag = topology.replace(":", "_").replace("x", "")
-        report_path = os.path.join(tune_db.repo_root(), "perf", "results",
-                                   f"wire_report_{tag}.json")
-    os.makedirs(os.path.dirname(report_path), exist_ok=True)
-    with open(report_path, "w") as f:
-        json.dump(report, f, indent=1, sort_keys=True)
-        f.write("\n")
-    _log(f"report: {report_path}", log)
-    return report
-
-
 def hier_sweep(topology: str = "v5e:2x2", *, slices: int = 2,
                db_path: str | None = None, report_path: str | None = None,
                batch: int = 512, zero1_batch: int = 256, log=None) -> dict:
@@ -801,19 +688,16 @@ def hier_sweep(topology: str = "v5e:2x2", *, slices: int = 2,
     program note in ``_zero1_step_compile`` for why not the conv/BERT
     pair the other sweeps use) on a compile-only MULTI-SLICE topology
     (``pspec.topology_devices`` — PJRT ``num_slices``, no chip needed)
-    once per (hier, wire_format_dcn) candidate, attribute every
+    once per lowering (flat, hier), attribute every
     collective's wire bytes to its fabric
     with shardflow's replica-group splitter, price the two columns with
     ``roofline.comm_split_score`` (ICI over the device ring, DCN over
     the slice ring — the ~32x bandwidth gap is the whole game), and
     persist every candidate to the ``hier_collectives`` DB family.
 
-    Candidates: flat/fp (the baseline everything is ratioed against),
-    hier/fp (PERF §23's two-level lowering — DCN carries 1/n_inner of
-    the bytes), and hier/int8-block (EQuARX's quantized wire on the DCN
-    leg only — ICI stays fp).  flat/int8-block is structurally invalid
-    (the DCN wire format IS the cross-slice leg; pspec rejects it) and
-    is recorded as skipped rather than silently absent.
+    Candidates: flat (the baseline everything is ratioed against) and
+    hier (PERF §23's two-level lowering — DCN carries 1/n_inner of the
+    bytes).
 
     DB rows store the comm-aware total (step + ICI + DCN ms) as their
     ``predicted_ms`` so ``db.best`` / ``resolve_hier`` elect the
@@ -837,7 +721,7 @@ def hier_sweep(topology: str = "v5e:2x2", *, slices: int = 2,
     os.environ.setdefault("TPU_SKIP_MDS_QUERY", "1")
     gen = roofline.generation_from_topology(topology)
     n = roofline.n_chips_from_topology(topology) * max(int(slices), 1)
-    candidates = (("flat", "fp"), ("hier", "fp"), ("hier", "int8-block"))
+    candidates = ("flat", "hier")
     configs = (("lm", batch, "replicated"),
                ("lm", zero1_batch, "zero1"))
     _log(f"hier sweep on {topology} x{slices} slices ({n} chips): "
@@ -851,15 +735,11 @@ def hier_sweep(topology: str = "v5e:2x2", *, slices: int = 2,
               "objective": "t_step_ms + t_ici_ms + t_dcn_ms "
                            "(comm_split_score on shardflow's "
                            "replica-group fabric attribution)",
-              "skipped": [{"hier": "flat", "wire_format_dcn": "int8-block",
-                           "reason": "structurally invalid — the DCN "
-                                     "wire format is the cross-slice "
-                                     "leg of the two-level lowering"}],
               "hier": {"rows": [], "compile_errors": []}}
 
     for program, b, mode in configs:
         baseline = {}
-        for hier_mode, fmt in candidates:
+        for hier_mode in candidates:
             payload, err, rc = None, None, 0
             for attempt in (1, 2):
                 with tempfile.NamedTemporaryFile(suffix=".json",
@@ -869,8 +749,7 @@ def hier_sweep(topology: str = "v5e:2x2", *, slices: int = 2,
                        "_hier-probe", "--topology", topology,
                        "--slices", str(slices), "--program", program,
                        "--batch", str(b), "--mode", mode,
-                       "--hier", hier_mode, "--wire-format-dcn", fmt,
-                       "--out", out_path]
+                       "--hier", hier_mode, "--out", out_path]
                 try:
                     proc = subprocess.run(cmd, capture_output=True,
                                           text=True, timeout=480)
@@ -885,7 +764,7 @@ def hier_sweep(topology: str = "v5e:2x2", *, slices: int = 2,
                     err = _crash_reason(stderr, rc)
                     if rc != -1:
                         break  # deterministic failure — retry won't help
-                    _log(f"  {program}/{hier_mode}/{fmt}: wedged compile "
+                    _log(f"  {program}/{hier_mode}: wedged compile "
                          f"(attempt {attempt}), "
                          + ("retrying" if attempt == 1 else "giving up"),
                          log)
@@ -894,17 +773,17 @@ def hier_sweep(topology: str = "v5e:2x2", *, slices: int = 2,
                         os.unlink(out_path)
             if payload is None:
                 row = {"program": program, "hier": hier_mode,
-                       "wire_format_dcn": fmt, "weight_update": mode,
+                       "weight_update": mode,
                        "returncode": rc, "error": err}
                 report["hier"]["compile_errors"].append(row)
-                _log(f"  {program}/{hier_mode}/{fmt}: COMPILE ERROR "
+                _log(f"  {program}/{hier_mode}: COMPILE ERROR "
                      f"{(err or '')[:80]}", log)
                 continue
             row, desc, pred = (payload["row"], payload["desc"],
                                payload["pred"])
             css = pred["comm_split"]
             total_ms = row["predicted_total_ms"]
-            if hier_mode == "flat" and fmt == "fp":
+            if hier_mode == "flat":
                 baseline = {"dcn_bytes": css["dcn_bytes"],
                             "t_dcn_ms": css["t_dcn_ms"],
                             "total_ms": total_ms}
@@ -918,12 +797,12 @@ def hier_sweep(topology: str = "v5e:2x2", *, slices: int = 2,
                     "family": "hier_collectives",
                     "fingerprint": tune_db.fingerprint(desc),
                     "topology": topology, "generation": gen,
-                    "config": {"hier": hier_mode, "wire_format_dcn": fmt,
+                    "config": {"hier": hier_mode,
                                "batch": b, "weight_update": mode,
                                "slices": slices},
                     "predicted": pred})
             report["hier"]["rows"].append(row)
-            _log(f"  {program}/{hier_mode}/{fmt}: "
+            _log(f"  {program}/{hier_mode}: "
                  f"{row['predicted_total_ms']} ms total "
                  f"({row['t_step_ms']} step + {row['t_ici_ms']} ICI + "
                  f"{row['t_dcn_ms']} DCN), "
@@ -954,7 +833,7 @@ def hier_sweep(topology: str = "v5e:2x2", *, slices: int = 2,
 
 
 def _hier_probe_row(topology: str, slices: int, program: str, batch: int,
-                    mode: str, hier: str, wire_format_dcn: str) -> dict:
+                    mode: str, hier: str) -> dict:
     """Compile + score ONE two-level-collective candidate; returns the
     report row, its DB descriptor, and the comm-aware predicted dict as
     one JSON payload.
@@ -971,8 +850,7 @@ def _hier_probe_row(topology: str, slices: int, program: str, batch: int,
     devices = pspec.topology_devices(topology, slices=slices)
     n = len(devices)
     compiled, desc, _opt_bytes, _census = _zero1_step_compile(
-        devices, program, batch, mode, slices=slices,
-        hier=hier, wire_format_dcn=wire_format_dcn)
+        devices, program, batch, mode, slices=slices, hier=hier)
     hlo = compiled.as_text()
     pred = roofline.score_compiled(compiled, gen)
     pred["source"] = "compiled"
@@ -992,8 +870,7 @@ def _hier_probe_row(topology: str, slices: int, program: str, batch: int,
     pred["comm_split"] = css
     pred["t_step_ms"] = pred["predicted_ms"]
     pred["predicted_ms"] = total_ms  # comm-aware rank (see hier_sweep)
-    row = {"program": program, "hier": hier,
-           "wire_format_dcn": wire_format_dcn, "weight_update": mode,
+    row = {"program": program, "hier": hier, "weight_update": mode,
            "global_batch": batch,
            "t_step_ms": pred["t_step_ms"],
            "t_ici_ms": css["t_ici_ms"],
