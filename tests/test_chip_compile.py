@@ -137,19 +137,32 @@ def test_fused_conv_bn_bwd_compiles_for_v5e(v5e, h, w, k, c_out):
     _compile_grad(loss, a, wt, g, g)
 
 
-def test_decode_attention_compiles_for_v5e(v5e):
-    """LMEngine's decode attention at the 124M width: 4 slots, a 512-entry
-    ring of columns, 12 heads of 64, bf16 — XLA by design (query length
-    1), and nothing ring-sized beside the rings."""
+@pytest.mark.parametrize("shape,dtype,mosaic", [
+    ((64, 12, 64, 2048), jnp.bfloat16, True),    # the serving cell's rings
+    ((128, 12, 64, 2048), jnp.float32, True),    # tiles of 8 rows, 128 slots
+    ((4, 12, 64, 512), jnp.bfloat16, True),      # chip_smoke's engine
+    ((4, 2, 128, 256), jnp.bfloat16, True),      # a head of whole lanes
+    ((4, 12, 64, 512), jnp.bfloat16, False),     # the einsums, standing in
+])
+def test_decode_attention_compiles_for_v5e(v5e, monkeypatch, shape, dtype,
+                                           mosaic):
+    """Decode attention at the 124M width, bf16 and float32: the Mosaic
+    kernel over the blocks the slots hold (one call for K and V, the rings
+    left in HBM as they lie), or — where no Mosaic is asked for — the
+    einsums; either way nothing ring-sized beside the rings."""
     from tpuframe.ops import attention as attn_ops
 
-    q = jax.ShapeDtypeStruct((4, 1, 12, 64), jnp.bfloat16, sharding=v5e)
-    kv = jax.ShapeDtypeStruct((4, 12, 64, 512), jnp.bfloat16, sharding=v5e)
-    lengths = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=v5e)
+    if mosaic:
+        monkeypatch.setenv("TPUFRAME_PALLAS_INTERPRET", "0")
+    b, n, d, _ = shape
+    q = jax.ShapeDtypeStruct((b, 1, n, d), dtype, sharding=v5e)
+    kv = jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+    lengths = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=v5e)
     c = jax.jit(lambda q, k, v, n: attn_ops.decode_attention(
         q, k, v, lengths=n)).lower(q, kv, kv, lengths).compile()
-    assert "tpu_custom_call" not in c.as_text()
-    assert c.memory_analysis().temp_size_in_bytes < 4 * 12 * 64 * 512 * 2
+    assert c.as_text().count("tpu_custom_call") == int(mosaic)
+    ring_bytes = math.prod(shape) * jnp.dtype(dtype).itemsize
+    assert c.memory_analysis().temp_size_in_bytes < ring_bytes // 8
 
 
 @pytest.mark.parametrize("shape,dtype", [
@@ -177,17 +190,15 @@ def test_ring_store_compiles_for_v5e(v5e, shape, dtype):
     assert m.temp_size_in_bytes < ring_bytes // 8
 
 
-def test_serve_decode_step_stores_in_one_pass_for_v5e(v5e, monkeypatch):
+@pytest.fixture(scope="module")
+def serve_decode_program(v5e):
     """The serving cell's decode program (64 slots, ring 2048, 12 layers of
-    12 x 64, vocabulary 50257, bf16): the KV store is 24 kernel calls on
-    the donated rings — no per-slot ``while`` loop (a scatter's expansion:
-    24 loops of 64 iterations before PR 30), no scatter, no ring-sized
-    copy or temporary."""
+    12 x 64, vocabulary 50257, bf16), compiled for the described chip:
+    ``(compiled, its text, the cache's spec, the model's config)``."""
     from tpuframe.models.transformer_lm import LMConfig, TransformerLM
     from tpuframe.serve import engine as engine_lib
     from tpuframe.serve import kv_cache as kv
 
-    monkeypatch.setenv("TPUFRAME_PALLAS_INTERPRET", "0")   # lower Mosaic
     cfg = LMConfig(vocab_size=50257, hidden_size=768, num_layers=12,
                    num_heads=12, intermediate_size=3072, max_seq=2048,
                    dtype="bfloat16")
@@ -202,13 +213,31 @@ def test_serve_decode_step_stores_in_one_pass_for_v5e(v5e, monkeypatch):
     params = jax.tree.map(lambda a: sds(a.shape, a.dtype),
                           variables["params"])
     ring = sds(spec.layer_shape(), jnp.dtype(spec.dtype))
-    c = jax.jit(engine_lib.make_decode_fn(model),
-                donate_argnums=(1, 2, 3)).lower(
-        params, sds((64, 1), jnp.int32), sds((64,), jnp.int32),
-        ((ring, ring),) * cfg.num_layers).compile()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPUFRAME_PALLAS_INTERPRET", "0")   # lower Mosaic
+        mp.setenv("TPUFRAME_TUNE_GEN", "v5e")
+        mp.setenv("TPUFRAME_TUNE_DB", "off")
+        c = jax.jit(engine_lib.make_decode_fn(model),
+                    donate_argnums=(1, 2, 3)).lower(
+            params, sds((64, 1), jnp.int32), sds((64,), jnp.int32),
+            ((ring, ring),) * cfg.num_layers).compile()
+    return c, c.as_text(), spec, cfg
 
-    text = c.as_text()
-    assert text.count("tpu_custom_call") == 2 * cfg.num_layers
+
+def _kernel_calls(text, name):
+    """The program's Mosaic calls whose kernel is ``name``."""
+    return re.findall(rf"^\s*%{name}[.\d]* = .*custom-call\(.*"
+                      r'custom_call_target="tpu_custom_call"', text,
+                      flags=re.M)
+
+
+def test_serve_decode_step_stores_in_one_pass_for_v5e(serve_decode_program):
+    """The serving cell's decode program: the KV store is 24 kernel calls
+    on the donated rings — no per-slot ``while`` loop (a scatter's
+    expansion: 24 loops of 64 iterations before PR 30), no scatter, no
+    ring-sized copy or temporary."""
+    c, text, spec, cfg = serve_decode_program
+    assert len(_kernel_calls(text, "ring_store")) == 2 * cfg.num_layers
     assert " while(" not in text and " scatter(" not in text
     ring_elems = math.prod(spec.layer_shape())
     for line in text.splitlines():
@@ -220,3 +249,22 @@ def test_serve_decode_step_stores_in_one_pass_for_v5e(v5e, monkeypatch):
     assert spec.total_bytes() == 4_831_838_208
     assert m.alias_size_in_bytes >= spec.total_bytes()
     assert m.temp_size_in_bytes < 64 << 20
+
+
+def test_serve_decode_step_attends_over_held_blocks_for_v5e(
+        serve_decode_program):
+    """The same program's attention: 12 kernel calls, one a layer for K
+    and V both, that take the rings as the stores leave them — and no
+    fusion left that makes ``[64, 12, 1, 2048]`` scores, which is what
+    reading every column of every ring looked like (24 loop fusions of
+    268 us before PR 32).  Every Mosaic call of the program is one of the
+    two kernels."""
+    c, text, spec, cfg = serve_decode_program
+    calls = _kernel_calls(text, "decode_attention")
+    assert len(calls) == cfg.num_layers
+    for call in calls:
+        assert len(re.findall(r"%ring_store[.\d]*", call)) == 2, call
+    assert text.count('custom_call_target="tpu_custom_call"') \
+        == 3 * cfg.num_layers
+    scores = re.compile(r"\[64,12,1,2048\]|\[64,12,2048\]")
+    assert not [line for line in text.splitlines() if scores.search(line)]

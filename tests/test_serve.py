@@ -18,6 +18,7 @@ Covers the PR's contracts end to end on the 8-device virtual CPU mesh:
 
 import json
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -195,14 +196,20 @@ class _FakeEngine:
     def __init__(self, slots=2, buckets=(8, 16), eos_id=None):
         self.slots = slots
         self.prompt_buckets = buckets
+        self.capacity = 2 * max(buckets)
         self.eos_id = eos_id
         self._active = {}
+        self.released = []
 
     def prefill(self, prompt):
         return 100 + len(prompt), ("pcache", len(prompt)), len(prompt)
 
     def insert(self, slot, pcache, length, first_token):
         self._active[slot] = first_token
+
+    def release(self, slot):
+        del self._active[slot]
+        self.released.append(slot)
 
     def decode_step(self):
         out = np.zeros(self.slots, np.int32)
@@ -283,6 +290,151 @@ class TestScheduler:
         assert [r.rid for r in sched.completed] == [0, 1, 2]
         for r in sched.completed:
             assert len(r.tokens) == 1 and r.done_t is not None
+
+
+    def test_retire_gives_the_slot_up_on_the_engine(self):
+        eng = _FakeEngine(slots=2)
+        sched = Scheduler(eng)
+        sched.submit(Request(rid=0, prompt=[1, 2], max_new_tokens=3))
+        sched.submit(Request(rid=1, prompt=[1, 2], max_new_tokens=2))
+        sched.submit(Request(rid=2, prompt=[1], max_new_tokens=1))
+        while sched.has_work():
+            sched.step()
+        # rid 1 leaves slot 1 first; rid 2 finishes at its prefill there
+        # and leaves it again; rid 0 leaves slot 0 last
+        assert eng.released == [1, 1, 0]
+        assert eng._active == {}
+
+    def test_step_counts_the_blocks_the_live_slots_hold(self):
+        """``sched.step`` carries ``kv_blocks`` — what the step's decode
+        attention finds below the live slots' lengths, in lane blocks —
+        and the two counters add it up beside the rings' whole."""
+        from tpuframe.obs import metrics, timeline
+        from tpuframe.serve.kv_cache import KV_BLOCK
+
+        eng = _FakeEngine(slots=3, buckets=(128, 512))   # capacity 1024
+        sched = Scheduler(eng)
+        sched.submit(Request(rid=0, prompt=[7] * 127, max_new_tokens=4))
+        sched.submit(Request(rid=1, prompt=[7] * 300, max_new_tokens=2))
+        before = metrics.counters("decode.")
+        t = time.monotonic()
+        steps = 0
+        while sched.has_work():
+            sched.step()
+            steps += 1
+        spans = [s for s in timeline.spans(t0=t) if s.name == "sched.step"]
+        # step 1 decodes over 127 + 1 and 300 + 1 columns: 1 + 3 blocks;
+        # rid 1 is gone after it, and rid 0's 129th column opens a block
+        assert [s.args["kv_blocks"] for s in spans] == [4, 2, 2]
+        assert [s.args["active"] for s in spans] == [1, 1, 0]
+        after = metrics.counters("decode.")
+        live = after["decode.kv_blocks_live"] \
+            - before.get("decode.kv_blocks_live", 0)
+        ring = after["decode.kv_blocks_ring"] \
+            - before.get("decode.kv_blocks_ring", 0)
+        assert live == 8
+        assert ring == steps * eng.slots * eng.capacity // KV_BLOCK == 72
+        # a step with nothing to decode counts nothing
+        sched.step()
+        assert timeline.last("sched.step").args["kv_blocks"] == 0
+        assert metrics.counters("decode.") == after
+
+    def test_kv_blocks_stop_at_the_rings_capacity(self):
+        from tpuframe.obs import timeline
+
+        eng = _FakeEngine(slots=1, buckets=(128,))        # capacity 256
+        sched = Scheduler(eng)
+        sched.submit(Request(rid=0, prompt=[7] * 128, max_new_tokens=200))
+        seen = set()
+        while sched.has_work():
+            sched.step()
+            seen.add(timeline.last("sched.step").args["kv_blocks"])
+        assert seen == {2}      # 129 .. 327 columns asked of a ring of 256
+
+
+# ---------------------------------------------------------------------------
+# The real engine's slots: held lengths, release, idle neighbours.
+# ---------------------------------------------------------------------------
+
+class TestReleasedSlots:
+    @pytest.fixture(scope="class")
+    def engine(self):
+        from tpuframe.serve.engine import LMEngine
+
+        return LMEngine(TINY, slots=3, prompt_buckets=(8, 16),
+                        decode_block=8, max_context=24,
+                        enable_persistent_cache=False)
+
+    @staticmethod
+    def _admit(engine, slot, prompt):
+        first, pcache, length = engine.prefill(prompt)
+        engine.insert(slot, pcache, length, first)
+        return first
+
+    def test_release_zeroes_the_length_and_it_stays(self, engine):
+        engine.reset()
+        self._admit(engine, 0, [3, 1, 4, 1, 5])
+        self._admit(engine, 1, [9, 2, 6])
+        for _ in range(2):
+            engine.decode_step()
+        # a slot that never held a request has not moved
+        assert np.asarray(engine._lengths).tolist() == [7, 5, 0]
+        engine.release(0)
+        assert np.asarray(engine._lengths).tolist() == [0, 5, 0]
+        for _ in range(3):
+            engine.decode_step()
+        assert np.asarray(engine._lengths).tolist() == [0, 8, 0]
+        assert "release" in engine.compiled_programs()
+        with pytest.raises(ValueError, match="out of range"):
+            engine.release(3)
+
+    def test_lengths_keep_counting_past_the_capacity(self, engine):
+        """Holding idle slots at 0 leaves a live slot's count alone: it
+        runs on past the ring's capacity (24), the ring wraps."""
+        engine.reset()
+        self._admit(engine, 2, list(range(1, 17)))
+        for _ in range(12):
+            toks = engine.decode_step()
+        assert np.asarray(engine._lengths).tolist() == [0, 0, 28]
+        assert 0 <= int(toks[2]) < TINY.vocab_size
+
+    def test_a_live_slots_stream_is_the_same_beside_idle_neighbours(
+            self, engine):
+        prompt = [5, 3, 8, 2, 7, 1]
+
+        def stream(slot, before=None):
+            engine.reset()
+            if before is not None:
+                before()
+            toks = [self._admit(engine, slot, prompt)]
+            for _ in range(6):
+                toks.append(int(engine.decode_step()[slot]))
+            return toks
+
+        def a_retired_neighbour():
+            # slot 0 held a request for a while and gave it up; slot 2
+            # never held one
+            self._admit(engine, 0, [4, 4, 4, 4, 4, 4, 4, 4, 4])
+            for _ in range(3):
+                engine.decode_step()
+            engine.release(0)
+
+        alone = stream(0)
+        assert stream(1, a_retired_neighbour) == alone
+        assert stream(2) == alone
+
+    def test_scheduler_leaves_retired_slots_at_zero(self, engine):
+        engine.reset()
+        sched = Scheduler(engine)
+        sched.submit(Request(rid=0, prompt=[1, 2, 3], max_new_tokens=2))
+        sched.submit(Request(rid=1, prompt=[4, 5, 6, 7], max_new_tokens=6))
+        while sched.has_work():
+            sched.step()
+            live = [r is not None for r in sched.active]
+            lengths = np.asarray(engine._lengths)
+            assert [bool(n) for n in lengths] == live
+        assert np.asarray(engine._lengths).tolist() == [0, 0, 0]
+        assert [len(r.tokens) for r in sched.completed] == [2, 6]
 
 
 # ---------------------------------------------------------------------------

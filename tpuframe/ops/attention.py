@@ -72,14 +72,16 @@ def decode_attention(
     cached token is a column, the capacity axis is minor.  Causality is a
     *length mask*, not a triangle: the ring holds exactly the tokens the
     new position may attend, padded to its bucketed capacity, so the mask
-    is ``arange(S_kv) < lengths`` per sequence.  The flash kernel has
-    nothing to keep out of HBM at query length 1 (scores are [B, N, 1,
-    S_kv]), so this is the einsum formulation, which XLA runs as two loop
-    fusions a layer that each read one whole ring once, at the chip's
-    bandwidth.  That is all of every ring every step, whatever the slots
-    hold: reading only the occupied part takes a kernel of its own
-    (ROADMAP S9).  Same math as :func:`_xla_attention` with a key mask.
+    is ``arange(S_kv) < lengths`` per sequence.  Where the rings tile and
+    Mosaic is at hand this is the kernel of ``ops.decode_attention``,
+    which reads only the lane blocks below each sequence's ``lengths``;
+    elsewhere the einsum formulation stands in — two loop fusions that
+    each read one whole ring, whatever it holds — and the run's
+    ``kernel_impl`` record says which ran.  Same math as
+    :func:`_xla_attention` with a key mask.
     """
+    from tpuframe.ops import decode_attention as kernel, kernel_impl
+
     if q.ndim != 4 or q.shape[1] != 1:
         raise ValueError(f"decode_attention wants q [B, 1, N, D]; "
                          f"got {q.shape}")
@@ -89,6 +91,23 @@ def decode_attention(
         raise ValueError(f"decode_attention wants rings [B, N, D, S_kv] = "
                          f"[{b}, {n}, {d}, S_kv]; got {k_cache.shape}, "
                          f"{v_cache.shape}")
+    if not kernel.supported(q, k_cache) or v_cache.dtype != k_cache.dtype:
+        why = (f"q {q.shape} {q.dtype} rings {k_cache.shape} "
+               f"{k_cache.dtype} do not tile")
+    else:
+        why = kernel_impl.no_mosaic()
+    if why is not None:
+        kernel_impl.record("decode_attention", "xla", why)
+        return _xla_decode_attention(q, k_cache, v_cache, lengths)
+    interpret = kernel_impl.resolve_interpret(
+        "decode_attention", None, kernel.describe(k_cache))
+    return kernel.decode_attention(q, k_cache, v_cache, lengths,
+                                   interpret=interpret)
+
+
+def _xla_decode_attention(q, k_cache, v_cache, lengths):
+    """The composition the kernel replaces: every column of every ring."""
+    d, s_kv = q.shape[-1], k_cache.shape[-1]
     scale = 1.0 / jnp.sqrt(d).astype(q.dtype)
     scores = jnp.einsum("bqnd,bndk->bnqk", q * scale, k_cache,
                         preferred_element_type=jnp.float32)

@@ -40,6 +40,19 @@ def interpret_default() -> tuple[bool, str]:
     return backend != "tpu", f"backend={backend}"
 
 
+def no_mosaic() -> str | None:
+    """``backend=<name>`` where there is no Mosaic and nobody asked for the
+    interpreter, else None.  For an op whose XLA composition is that
+    backend's faster program, and the one the partitioner can split over
+    sharded slots: it runs the composition there and records this reason."""
+    if interpret_env() is not None:
+        return None
+    import jax
+
+    backend = jax.default_backend()
+    return None if backend == "tpu" else f"backend={backend}"
+
+
 def resolve_interpret(op: str, interpret: bool | None,
                       detail: str = "") -> bool:
     """Whether ``op``'s kernel runs under the interpreter: the caller's

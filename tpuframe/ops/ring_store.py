@@ -68,16 +68,11 @@ def ring_store(ring: jax.Array, rows: jax.Array, idx: jax.Array, *,
     capacity)``."""
     from tpuframe.ops import kernel_impl
 
-    why = None
     if not supported(ring, rows):
         why = (f"ring {ring.shape} {ring.dtype} rows {rows.shape} "
                f"{rows.dtype} does not tile")
-    elif (interpret is None and kernel_impl.interpret_env() is None
-          and jax.default_backend() != "tpu"):
-        # No Mosaic here and nobody asked for the interpreter: the
-        # composition is this backend's faster program, and the one the
-        # partitioner can split over sharded slots.
-        why = f"backend={jax.default_backend()}"
+    else:
+        why = kernel_impl.no_mosaic() if interpret is None else None
     if why is not None:
         kernel_impl.record("ring_store", "xla", why)
         return _xla_store(ring, rows, idx)

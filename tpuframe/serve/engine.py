@@ -18,14 +18,19 @@ time against the closed set of bucketed shapes from ``serve.kv_cache``:
               (ops.attention.decode_attention).  Cache, lengths and
               token buffers are DONATED: the executable updates HBM in
               place.  A step's traffic is the params once, the lane
-              blocks the store touches, and the WHOLE of every ring —
-              attention reads all ``capacity`` entries of all slots
-              whatever they hold, which is more than the touched KV the
-              roofline bound (tune/roofline.decode_score) models.
+              blocks the store touches, and the lane blocks of each
+              ring at or below its slot's length (on a chip, where the
+              attention kernel runs; its einsum stand-in reads every
+              ring whole).  A slot that holds no request has length 0,
+              stays there, and costs one block.
   insert      (cache, lengths, toks, pcache, slot, len, tok) -> updated
               one program total — copies a finished prefill's
               single-slot cache into the shared decode cache at a
               traced slot index (continuous batching's admission op).
+  release     (lengths, slot) -> lengths
+              one program total — a retired slot's length back to 0,
+              which is what keeps the decode step from advancing it
+              and from attending over what the request left behind.
 
 The scheduler/loadgen layers above call these executables and are
 forbidden (lint TF109) from calling ``jit``/``.apply`` themselves — a
@@ -69,9 +74,11 @@ def make_prefill_fn(model, spec: kv.CacheSpec):
 
 def make_decode_fn(model):
     """The decode step program: one token for every slot, ring KV store,
-    greedy argmax.  ``lengths`` advances for every slot (inactive slots
-    decode garbage the scheduler ignores — branchless beats a per-slot
-    cond on TPU, and the ring write keeps wraparound safe)."""
+    greedy argmax.  ``lengths`` advances where it is non-zero: a slot that
+    holds a request (``insert`` writes a length of at least 1) moves on,
+    one that holds none stays at 0 until the next ``insert`` (it still
+    decodes garbage the scheduler ignores — branchless beats a per-slot
+    cond on TPU — over a ring of one valid column)."""
     import jax.numpy as jnp
 
     def decode_fn(params, tokens, lengths, layers):
@@ -79,7 +86,7 @@ def make_decode_fn(model):
             {"params": params}, tokens, kv_cache=layers,
             cache_length=lengths, decode=True)
         nxt = jnp.argmax(logits[:, 0, :], axis=-1).astype(jnp.int32)
-        return nxt[:, None], lengths + 1, layers
+        return nxt[:, None], lengths + (lengths > 0), layers
 
     return decode_fn
 
@@ -103,6 +110,15 @@ def make_insert_fn(num_layers: int):
     if num_layers < 1:
         raise ValueError("need at least one layer")
     return insert_fn
+
+
+def release_fn(lengths, slot):
+    """Retirement: the slot's length back to 0, at a *traced* slot index."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    return lax.dynamic_update_slice(lengths, jnp.zeros((1,), jnp.int32),
+                                    (slot,))
 
 
 class LMEngine:
@@ -176,6 +192,9 @@ class LMEngine:
             cache_sds, sds((slots,), i32), sds((slots, 1), i32),
             pcache_sds, sds((), i32), sds((), i32), sds((), i32)).compile()
 
+        self._release = jax.jit(release_fn, donate_argnums=0).lower(
+            sds((slots,), i32), sds((), i32)).compile()
+
         self.reset()
 
     # --- state -------------------------------------------------------------
@@ -191,11 +210,17 @@ class LMEngine:
     def slots(self) -> int:
         return self.spec.slots
 
+    @property
+    def capacity(self) -> int:
+        """KV entries a slot's ring holds."""
+        return self.spec.capacity
+
     def compiled_programs(self) -> dict:
         """The AOT table, for census/tests: name -> compiled."""
         table = {f"prefill_{b}": c for b, c in self._prefill.items()}
         table["decode"] = self._decode
         table["insert"] = self._insert
+        table["release"] = self._release
         return table
 
     def swap_params(self, new_params) -> None:
@@ -269,6 +294,19 @@ class LMEngine:
                 self._layers, self._lengths, self._tokens, pcache,
                 jnp.asarray(slot, jnp.int32), jnp.asarray(length, jnp.int32),
                 jnp.asarray(first_token, jnp.int32))
+
+    def release(self, slot: int) -> None:
+        """Give ``slot`` up: its request has retired.  The slot's length
+        goes to 0, so decode steps neither advance it nor read its ring
+        beyond one block, until the next ``insert``."""
+        import jax.numpy as jnp
+
+        if not 0 <= slot < self.spec.slots:
+            raise ValueError(f"slot {slot} out of range "
+                             f"[0, {self.spec.slots})")
+        with timeline.span("engine.release", slot=slot):
+            self._lengths = self._release(self._lengths,
+                                          jnp.asarray(slot, jnp.int32))
 
     def decode_step(self) -> np.ndarray:
         """One decode step over every slot.  Returns the new token per

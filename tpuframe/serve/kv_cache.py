@@ -42,6 +42,9 @@ from dataclasses import dataclass
 # bounded at 2x worst-case.
 DEFAULT_DECODE_BLOCK = 128
 DEFAULT_PROMPT_BUCKETS = (128, 256, 512)
+# Columns of one lane block of a ring: the unit decode attention reads a
+# slot's ring in (ops/decode_attention.py), and the scheduler counts in.
+KV_BLOCK = 128
 
 
 @dataclass(frozen=True)

@@ -104,6 +104,7 @@ class FakeEngine:
                  vocab_size: int = 256):
         self.slots = slots
         self.prompt_buckets = tuple(sorted(prompt_buckets))
+        self.capacity = 2 * max(self.prompt_buckets)
         self.eos_id = eos_id
         self.step_delay_s = step_delay_s
         self.vocab_size = vocab_size
@@ -116,6 +117,9 @@ class FakeEngine:
 
     def insert(self, slot, pcache, length, first_token) -> None:
         self._last[slot] = int(first_token)
+
+    def release(self, slot) -> None:
+        self._last[slot] = 0
 
     def decode_step(self):
         if self.step_delay_s > 0:

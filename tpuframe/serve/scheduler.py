@@ -16,7 +16,8 @@ Per step, in order:
                lanes compute garbage, which costs less than a recompile
                or a per-slot branch).
   3. retire  — requests that hit ``max_new_tokens`` or the EOS id leave
-               their slot free for the next admit.
+               their slot free for the next admit, and the engine is
+               told (``release``): an idle slot's ring is not read.
 
 Observability rides obs v2: a typed ``serve_step`` event per step and a
 ``serve_request`` event per retirement (TTFT/TPOT, token counts) — the
@@ -37,8 +38,10 @@ from dataclasses import dataclass, field
 
 from tpuframe.obs import events as obs_events
 from tpuframe.obs import exporter as obs_exporter
+from tpuframe.obs import metrics as obs_metrics
 from tpuframe.obs import timeline, tracing
 from tpuframe.obs.goodput import _pct
+from tpuframe.serve.kv_cache import KV_BLOCK
 
 
 @dataclass
@@ -151,8 +154,9 @@ class Scheduler:
         with timeline.span("sched.step") as step_span:
             admitted = self._admit()
 
-            produced = 0
+            produced = kv_blocks = 0
             if any(r is not None for r in self.active):
+                kv_blocks = self._count_kv_blocks()
                 toks = self.engine.decode_step()
                 now = self._clock()
                 with timeline.span("sched.retire"):
@@ -172,7 +176,7 @@ class Scheduler:
                 step=self.step_count,
                 active=sum(r is not None for r in self.active),
                 admitted=admitted, produced=produced,
-                queued=len(self.pending))
+                queued=len(self.pending), kv_blocks=kv_blocks)
             step_span.set(**counts)
         obs_events.emit(
             "serve_step", wall_ms=round(1e3 * (self._clock() - t0), 3),
@@ -180,6 +184,22 @@ class Scheduler:
         return produced + admitted
 
     # -- internals ----------------------------------------------------------
+
+    def _count_kv_blocks(self) -> int:
+        """The ``KV_BLOCK``-column blocks the coming decode step's
+        attention finds in the live slots' rings (prompt plus tokens so
+        far, the ring's capacity at most), summed; counted beside the
+        blocks of all the rings, so a run's ratio of the two counters is
+        the share of the cache its decode steps had to read.  (An idle
+        slot costs the kernel one block more each.)"""
+        capacity = self.engine.capacity
+        live = sum(-(-min(len(r.prompt) + len(r.tokens), capacity)
+                     // KV_BLOCK)
+                   for r in self.active if r is not None)
+        obs_metrics.bump("decode.kv_blocks_live", live)
+        obs_metrics.bump("decode.kv_blocks_ring",
+                         self.engine.slots * -(-capacity // KV_BLOCK))
+        return live
 
     def _admit(self) -> int:
         """Fill free slots from the pending FIFO.  A request that
@@ -229,6 +249,7 @@ class Scheduler:
     def _retire(self, slot: int) -> None:
         req = self.active[slot]
         self.active[slot] = None
+        self.engine.release(slot)
         if req.done_t is None:
             req.done_t = self._clock()
         self.completed.append(req)
