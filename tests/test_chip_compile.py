@@ -97,6 +97,51 @@ def test_flash_rule_choice_compiles_for_v5e(v5e, shape, s_kv, dtype, causal,
     assert c.as_text().count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
+def test_flash_window_grouped_heads_compile_for_v5e(v5e, window):
+    """The afmoe block's attention at one chip's share: 8192 tokens, 32
+    query heads of 128 over 4 K/V heads read where they lie, with and
+    without the 2048-token window; dK and dV come out per K/V head."""
+    from tpuframe.ops import flash_attention as fa
+
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 128), jnp.bfloat16, sharding=v5e)
+    k = jax.ShapeDtypeStruct((1, 8192, 4, 128), jnp.bfloat16, sharding=v5e)
+    assert fa.supported(q, k)
+
+    def loss(q, k, v):
+        return fa.flash_mha(q, k, v, causal=True, window=window,
+                            interpret=False).astype(jnp.float32).sum()
+
+    c = _compile_grad(loss, q, k, k)
+    assert c.as_text().count("tpu_custom_call") >= 3
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_moe_grouped_product_compiles_for_v5e(v5e):
+    """The grouped matrix product of the expert layer at Trinity-Mini's
+    widths, 16 experts of 2048 -> 1024 held: forward, the rows' and the
+    weights' backward products, over a buffer of 8192 tokens' picks."""
+    from tpuframe.ops import moe
+
+    held, h, i, tile = 16, 2048, 1024, moe.TILE_ROWS
+    n_tiles, _ = moe.buffer_tiles(8192, 8, held, 128, 2.0)
+    x = jax.ShapeDtypeStruct((n_tiles * tile, h), jnp.bfloat16, sharding=v5e)
+    w_in = jax.ShapeDtypeStruct((held, h, 2 * i), jnp.bfloat16, sharding=v5e)
+    w_out = jax.ShapeDtypeStruct((held, i, h), jnp.bfloat16, sharding=v5e)
+    te = jax.ShapeDtypeStruct((n_tiles,), jnp.int32, sharding=v5e)
+    used = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=v5e)
+
+    def loss(x, w_in, w_out, te, used):
+        hid = moe.gmm(x, w_in, te, used, tile, False)
+        return moe.gmm(hid[:, :i] * hid[:, i:], w_out, te, used, tile,
+                       False).astype(jnp.float32).sum()
+
+    c = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        x, w_in, w_out, te, used).compile()
+    # the last forward product is dead under grad-of-sum: five calls left
+    assert c.as_text().count("tpu_custom_call") >= 5
+
+
 def test_flash_row_stats_are_lane_major_for_v5e(v5e):
     """The layout the chip takes (PERF.md §12.2): [bn, 1, s] residuals, not
     the [bn, s, 1] one that pads 128x in HBM."""

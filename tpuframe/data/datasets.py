@@ -469,7 +469,8 @@ def _synthetic_token_pairs(n, seq_len, vocab_size, *, seed):
 
 def lm_text(data_dir: str | None = None, *, seq_len: int = 2048,
             vocab_size: int = 32000, synthetic_size: int = 256,
-            padded_docs: bool = False, pad_id: int = 0):
+            padded_docs: bool = False, pad_id: int = 0,
+            uniform_ids: bool = False):
     """Next-token-prediction chunks: input_ids [N, S], labels [N, S] int32
     (labels pre-shifted on the host so the loss is positionwise — no
     cross-shard shift is needed when the sequence dim is sharded over the
@@ -484,6 +485,12 @@ def lm_text(data_dir: str | None = None, *, seq_len: int = 2048,
     ``ignore_index`` convention, which the harness LM losses honor (zero
     loss AND zero gradient there, means over valid tokens only).  The
     fine-tuning data shape, vs the packed-stream pretraining shape.
+
+    ``uniform_ids`` (synthetic only): every id drawn uniformly and
+    independently — nothing to learn, but every sequence holds the whole
+    vocabulary in equal measure, where the affine recurrence walks one
+    short cycle of it (a few hundred distinct ids in 8192 tokens); what a
+    router's load, and so an expert layer's work, should be measured on.
     """
     if padded_docs:
         if data_dir is not None:
@@ -503,8 +510,10 @@ def lm_text(data_dir: str | None = None, *, seq_len: int = 2048,
             lbl = np.stack([stream[i*seq_len+1:(i+1)*seq_len+1] for i in range(lo, hi)])
             return ArrayDataset({"input_ids": ids, "labels": lbl})
         return chunk(0, split), chunk(split, n)
-    return (_synthetic_lm(synthetic_size, seq_len, vocab_size, seed=8),
-            _synthetic_lm(max(synthetic_size // 8, 8), seq_len, vocab_size, seed=9))
+    return (_synthetic_lm(synthetic_size, seq_len, vocab_size, seed=8,
+                          uniform=uniform_ids),
+            _synthetic_lm(max(synthetic_size // 8, 8), seq_len, vocab_size,
+                          seed=9, uniform=uniform_ids))
 
 
 def _synthetic_lm_docs(n, seq_len, vocab_size, *, pad_id, seed):
@@ -525,11 +534,16 @@ def _synthetic_lm_docs(n, seq_len, vocab_size, *, pad_id, seed):
     return ArrayDataset({"input_ids": ids, "labels": labels})
 
 
-def _synthetic_lm(n, seq_len, vocab_size, *, seed):
+def _synthetic_lm(n, seq_len, vocab_size, *, seed, uniform=False):
     """Deterministic affine-recurrence token stream: x_{t+1} =
     (a*x_t + b) mod V with occasional noise — next-token loss can fall well
-    below log(V), so "loss decreases" tests measure learning, not chance."""
+    below log(V), so "loss decreases" tests measure learning, not chance.
+    ``uniform``: independent uniform ids instead."""
     rng = np.random.default_rng(seed)
+    if uniform:
+        ids = rng.integers(0, vocab_size, size=(n, seq_len + 1))
+        return ArrayDataset({"input_ids": ids[:, :-1].astype(np.int32),
+                             "labels": ids[:, 1:].astype(np.int32)})
     starts = rng.integers(0, vocab_size, size=n)
     a, b = 31, 17
     ids = np.empty((n, seq_len + 1), np.int64)

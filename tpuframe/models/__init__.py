@@ -11,6 +11,7 @@ choices (MXU wants large bf16 matmuls; see task guidance + pallas_guide).
 
 from typing import Any, Callable
 
+from tpuframe.models.afmoe import Afmoe, AfmoeConfig
 from tpuframe.models.convnet import ConvNet
 from tpuframe.models.resnet import (ResNet, ResNet18, ResNet34,
                                     ResNet50, ResNet101, ResNet152)
@@ -44,6 +45,19 @@ def _lm_adapter(cls):
     return build
 
 
+def _afmoe(dtype=None, tiny=False, **kwargs):
+    """Registry adapter: flag-style kwargs → AfmoeConfig → Afmoe (lists
+    from a JSON config become the tuples a frozen config hashes)."""
+    import numpy as np
+
+    if dtype is not None:
+        kwargs.setdefault("dtype", str(np.dtype(dtype)))
+    if "layer_types" in kwargs:
+        kwargs["layer_types"] = tuple(kwargs["layer_types"])
+    return Afmoe(AfmoeConfig.tiny(**kwargs) if tiny
+                 else AfmoeConfig(**kwargs))
+
+
 # transformer-lm-pp: the pipeline-parallel variant (layer-stacked blocks;
 # trained via tpuframe.parallel.pp_lm on a data x pipe mesh).
 _transformer_lm = _lm_adapter(TransformerLM)
@@ -60,6 +74,7 @@ _REGISTRY: dict[str, Callable[..., Any]] = {
     "bert-base": _bert_base,
     "transformer-lm": _transformer_lm,
     "transformer-lm-pp": _transformer_lm_pp,
+    "afmoe": _afmoe,
 }
 
 
@@ -71,6 +86,8 @@ def get_model(name: str, **kwargs):
 
 
 __all__ = [
+    "Afmoe",
+    "AfmoeConfig",
     "ConvNet",
     "LMConfig",
     "ScanBlockLM",

@@ -25,6 +25,18 @@ import jax
 
 _counters: dict[str, int] = {}
 _counters_lock = threading.Lock()
+# Counters kept elsewhere (on the device, by a jitted step) and fetched
+# only when asked for by name: ``{prefix: fn() -> {name: int}}``.
+_sources: dict = {}
+
+
+def register_source(prefix: str, fn) -> None:
+    """Counters under ``prefix`` come from ``fn()`` when :func:`counters`
+    is read with a prefix that falls under it, and cost nothing until
+    then.  A read without a prefix (the flight recorder's dump, the
+    exporter's scrape, ``run_end``) never calls ``fn``: those run on
+    threads and at moments at which a device transfer may block."""
+    _sources[prefix] = fn
 
 
 def bump(name: str, n: int = 1) -> None:
@@ -44,11 +56,18 @@ def bump(name: str, n: int = 1) -> None:
 def counters(prefix: str | None = None) -> dict[str, int]:
     """Snapshot of counters, optionally filtered to ``prefix``."""
     with _counters_lock:
-        return {k: v for k, v in _counters.items()
-                if prefix is None or k.startswith(prefix)}
+        out = dict(_counters)
+    for own, fn in list(_sources.items()):
+        if prefix is not None and prefix.startswith(own):
+            out.update(fn())
+    return {k: v for k, v in out.items()
+            if prefix is None or k.startswith(prefix)}
 
 
 def reset_counters(prefix: str | None = None) -> None:
+    for own in [p for p in _sources
+                if prefix is None or p.startswith(prefix)]:
+        del _sources[own]
     with _counters_lock:
         if prefix is None:
             _counters.clear()

@@ -21,16 +21,22 @@ import jax.numpy as jnp
 
 def multihead_attention(
     q: jax.Array,  # [B, S, N, D]
-    k: jax.Array,  # [B, S, N, D]
-    v: jax.Array,  # [B, S, N, D]
+    k: jax.Array,  # [B, S, N_kv, D]: N_kv divides N (grouped-query heads)
+    v: jax.Array,  # [B, S, N_kv, D]
     *,
     mask: jax.Array | None = None,  # [B, S] 1=keep or broadcastable [B,1,S,S]
     causal: bool = False,
+    window: int | None = None,  # with causal: key j attends iff i - j < window
     dropout_rate: float = 0.0,
     dropout_rng: jax.Array | None = None,
     impl: str | None = None,
 ) -> jax.Array:
+    """Query head ``h`` reads K/V head ``h // (N / N_kv)``.  The flash
+    kernels read the shared head where it lies; the XLA composition
+    repeats K and V."""
     impl = impl or os.environ.get("TPUFRAME_ATTN_IMPL", "xla")
+    if window is not None and not causal:
+        raise ValueError("a sliding window needs causal=True")
     if impl == "pallas":
         from tpuframe.ops import flash_attention, kernel_impl
 
@@ -41,15 +47,22 @@ def multihead_attention(
         elif mask is not None and mask.ndim != 2:
             why = "mask is not a [B, S] key mask"
         else:
-            return flash_attention.flash_mha(q, k, v, mask=mask, causal=causal)
+            return flash_attention.flash_mha(q, k, v, mask=mask, causal=causal,
+                                             window=window)
         # The XLA composition stands in; said once, never silently.
         kernel_impl.record("flash_attention", "xla", why)
         impl = "xla"
     if impl != "xla":
         raise ValueError(f"unknown attention impl {impl!r}")
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     if causal:
         s_q, s_kv = q.shape[1], k.shape[1]
-        tri = jnp.tril(jnp.ones((s_q, s_kv), bool))[None, None]
+        tri = jnp.tril(jnp.ones((s_q, s_kv), bool))
+        if window is not None:
+            tri = jnp.logical_and(tri, jnp.triu(tri, 1 - window))
+        tri = tri[None, None]
         if mask is not None:
             pad = mask[:, None, None, :] if mask.ndim == 2 else mask
             tri = jnp.logical_and(tri, pad.astype(bool))

@@ -117,7 +117,8 @@ class Tiles(NamedTuple):
 # or not (PERF.md §6, PR 26: 36 launches cost the LM cell's set-up 8 s).
 # (``lane``, the row statistics' layout, is static too: it is read from the
 # environment, which a jit cache key does not see.)
-_STATIC = ("scale", "causal", "tiles", "lane", "interpret", "precision")
+_STATIC = ("scale", "causal", "tiles", "lane", "interpret", "precision",
+           "window", "group")
 
 
 def _blocks_of(seq: int) -> list[int]:
@@ -264,32 +265,57 @@ def _div(x, n: int):
     return jax.lax.div(x, jnp.int32(n))
 
 
-def _k_ranges(causal, row0, n_rows, col0, sub, n_sub):
+def _k_ranges(causal, row0, n_rows, col0, sub, n_sub, window=None):
     """Of the ``n_sub`` sub-blocks of ``sub`` columns from ``col0``, what
-    rows [row0, row0 + n_rows) need: ``(n_plain, n_need)``.  Sub-blocks
-    [0, n_plain) lie wholly on or below the diagonal (no per-element mask),
-    [n_plain, n_need) straddle it, the rest lie above and are never
-    touched.  Non-causal: all plain."""
+    rows [row0, row0 + n_rows) need: ``(n_first, n_lo, n_plain, n_need)``.
+    Sub-blocks [n_lo, n_plain) lie wholly on or below the diagonal and
+    wholly inside the window (no per-element mask); [n_first, n_lo)
+    straddle the window's far edge and [n_plain, n_need) the diagonal;
+    those before ``n_first`` (every row's window has passed them) and from
+    ``n_need`` on (above the diagonal) are never touched.  Non-causal: all
+    plain.  Without a window ``n_first`` and ``n_lo`` are the static 0."""
     if not causal:
-        return n_sub, n_sub
+        return 0, 0, n_sub, n_sub
     n_plain = jnp.minimum(_div(jnp.maximum(row0 + 1 - col0, 0), sub), n_sub)
     n_need = jnp.minimum(
         _div(jnp.maximum(row0 + n_rows - col0, 0) + sub - 1, sub), n_sub)
-    return n_plain, n_need
+    if window is None:
+        return 0, 0, n_plain, n_need
+    # row i sees columns (i - window, i]: the first row's window opens at
+    # row0 - window + 1, the last row's at row0 + n_rows - window
+    n_first = jnp.minimum(
+        _div(jnp.maximum(row0 - window + 1 - col0, 0), sub), n_need)
+    n_lo = jnp.minimum(
+        _div(jnp.maximum(row0 + n_rows - window - col0, 0) + sub - 1, sub),
+        n_need)
+    return n_first, n_lo, jnp.maximum(n_plain, n_lo), n_need
 
 
-def _q_ranges(causal, col0, n_cols, row0, sub, n_sub):
+def _q_ranges(causal, col0, n_cols, row0, sub, n_sub, window=None):
     """The dkv kernel's view: of the ``n_sub`` sub-blocks of ``sub`` rows
     from ``row0``, what columns [col0, col0 + n_cols) reach:
-    ``(t_first, t_plain)``.  Sub-blocks [t_first, t_plain) straddle the
-    diagonal, [t_plain, n_sub) lie wholly on or below it, those before
-    t_first lie above.  Non-causal: all plain."""
+    ``(t_first, t_plain, t_hi, t_end)``.  Sub-blocks [t_first, t_plain)
+    straddle the diagonal, [t_plain, t_hi) lie wholly on or below it and
+    wholly inside the window, [t_hi, t_end) straddle the window's far
+    edge; those before ``t_first`` lie above the diagonal and from
+    ``t_end`` on past every column's window.  Non-causal: all plain.
+    Without a window ``t_hi`` and ``t_end`` are the static ``n_sub``."""
     if not causal:
-        return 0, 0
+        return 0, 0, n_sub, n_sub
     t_first = jnp.minimum(_div(jnp.maximum(col0 - row0, 0), sub), n_sub)
     t_plain = jnp.minimum(
         _div(jnp.maximum(col0 + n_cols - 1 - row0, 0) + sub - 1, sub), n_sub)
-    return t_first, t_plain
+    if window is None:
+        return t_first, t_plain, n_sub, n_sub
+    # column j is seen by rows [j, j + window): the first column's last
+    # row is col0 + window - 1, the last column's col0 + n_cols + window - 2
+    t_end = jnp.clip(
+        _div(jnp.maximum(col0 + n_cols + window - 1 - row0, 0) + sub - 1,
+             sub), t_first, n_sub)
+    t_plain = jnp.minimum(t_plain, t_end)
+    t_hi = jnp.clip(_div(jnp.maximum(col0 + window - row0, 0), sub),
+                    t_plain, t_end)
+    return t_first, t_plain, t_hi, t_end
 
 
 def _sub_loop(lo, hi, body):
@@ -308,8 +334,10 @@ def _at(t, sub):
     return t * sub if isinstance(t, int) else pl.multiple_of(t * sub, sub)
 
 
-def _keep(mask_row, need_tri, row0, col0, shape):
-    """[rows, cols] bool of the positions that attend, or None for all."""
+def _keep(mask_row, need_tri, row0, col0, shape, window=None):
+    """[rows, cols] bool of the positions that attend, or None for all.
+    ``need_tri`` asks for the causal geometry: the triangle and, where
+    there is a window, its far edge."""
     keep = None
     if mask_row is not None:
         keep = jnp.broadcast_to(mask_row != 0, shape)
@@ -317,28 +345,55 @@ def _keep(mask_row, need_tri, row0, col0, shape):
         rows = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
         cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
         tri = row0 + rows >= col0 + cols
+        if window is not None:
+            tri = jnp.logical_and(tri, row0 + rows - window < col0 + cols)
         keep = tri if keep is None else jnp.logical_and(keep, tri)
     return keep
 
 
-def _k_block_of(causal, block_q, block_k, n_kv):
+def _k_block_of(causal, block_q, block_k, n_kv, window=None):
     """``f(i, j)``: the K/V block that grid step (q block i, kv step j)
     fetches.  Above the diagonal that is the last block row i needs, not
     block j: the pipeline sees an unchanged index and issues no DMA for a
-    step that computes nothing."""
+    step that computes nothing.  Likewise before the first block a window
+    leaves row i."""
     if not causal:
         return lambda i, j: j
-    return lambda i, j: jnp.minimum(
-        j, jnp.minimum(_div((i + 1) * block_q - 1, block_k), n_kv - 1))
+    last = lambda i: jnp.minimum(  # noqa: E731
+        _div((i + 1) * block_q - 1, block_k), n_kv - 1)
+    if window is None:
+        return lambda i, j: jnp.minimum(j, last(i))
+    return lambda i, j: jnp.clip(
+        j, _div(jnp.maximum(i * block_q - window + 1, 0), block_k), last(i))
 
 
-def _q_block_of(causal, block_q, block_k, n_q):
+def _q_block_of(causal, block_q, block_k, n_q, window=None):
     """``f(j, i)``: the dkv kernel's mirror of :func:`_k_block_of` — before
-    the diagonal, the first Q block that reaches K/V block j."""
+    the diagonal, the first Q block that reaches K/V block j; past the
+    window, the last."""
     if not causal:
         return lambda j, i: i
-    return lambda j, i: jnp.maximum(
-        i, jnp.minimum(_div(j * block_k, block_q), n_q - 1))
+    first = lambda j: jnp.minimum(  # noqa: E731
+        _div(j * block_k, block_q), n_q - 1)
+    if window is None:
+        return lambda j, i: jnp.maximum(i, first(j))
+    return lambda j, i: jnp.clip(
+        i, first(j),
+        jnp.minimum(_div((j + 1) * block_k + window - 2, block_q), n_q - 1))
+
+
+def _kv_head_of(group: int):
+    """``f(b)``: the folded K/V head that folded query head ``b`` reads.
+    Heads fold batch-major, so ``group`` consecutive query heads share one
+    K/V head and K and V are never repeated in HBM."""
+    if group == 1:
+        return lambda b: b
+    return lambda b: _div(b, group)
+
+
+def _window_kw(window) -> dict:
+    """The kernels' ``window`` keyword, left out where there is none."""
+    return {} if window is None else {"window": window}
 
 
 # ---------------------------------------------------------------------------
@@ -350,7 +405,8 @@ def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref,  # inputs
                 o_ref, lse_ref,                 # outputs
                 acc_ref, m_ref, l_ref,          # scratch
                 *, scale: float, causal: bool, tiles: Tiles,
-                n_kv: int, lane_lse: bool = False, precision=None):
+                n_kv: int, lane_lse: bool = False, precision=None,
+                window: int | None = None):
     block_q, block_k, sub = tiles
     qi = pl.program_id(1)
     kv = pl.program_id(2)
@@ -368,7 +424,7 @@ def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref,  # inputs
             q_ref[0], k_ref[0, pl.ds(c, sub), :], (((1,), (1,)), ((), ())),
             precision=precision, preferred_element_type=jnp.float32) * scale
         keep = _keep(None if mask_ref is None else mask_ref[0, t],
-                     need_tri, row0, col0 + c, s.shape)
+                     need_tri, row0, col0 + c, s.shape, window)
         if keep is not None:
             s = jnp.where(keep, s, NEG_INF)
 
@@ -392,9 +448,10 @@ def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref,  # inputs
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    n_plain, n_need = _k_ranges(causal, row0, block_q, col0, sub,
-                                block_k // sub)
-    _sub_loop(0, n_plain, lambda t: step(t, False))
+    n_first, n_lo, n_plain, n_need = _k_ranges(
+        causal, row0, block_q, col0, sub, block_k // sub, window)
+    _sub_loop(n_first, n_lo, lambda t: step(t, True))
+    _sub_loop(n_lo, n_plain, lambda t: step(t, False))
     _sub_loop(n_plain, n_need, lambda t: step(t, True))
 
     @pl.when(kv == n_kv - 1)
@@ -428,20 +485,22 @@ def _mask_operand(mask, tiles: Tiles, n_heads: int, k_block):
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_fwd(q, k, v, mask, *, scale, causal, tiles: Tiles, lane: bool,
-               interpret, precision=None):
+               interpret, precision=None, window=None, group=1):
     bn, s_q, d = q.shape
     s_kv = k.shape[1]
     bq, bk, _ = tiles
     n_q, n_kv = s_q // bq, s_kv // bk
 
-    k_block = _k_block_of(causal, bq, bk, n_kv)
-    kv_spec = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, k_block(i, j), 0))
+    k_block = _k_block_of(causal, bq, bk, n_kv, window)
+    kv_head = _kv_head_of(group)
+    kv_spec = pl.BlockSpec(
+        (1, bk, d), lambda b, i, j: (kv_head(b), k_block(i, j), 0))
     in_specs = [pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
                 kv_spec, kv_spec]
     args = [q, k, v]
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, tiles=tiles, n_kv=n_kv,
-        lane_lse=lane, precision=precision)
+        lane_lse=lane, precision=precision, **_window_kw(window))
     if mask is None:
         kernel = functools.partial(kernel, None)
     else:
@@ -499,7 +558,7 @@ def _recompute_p(q, k, lse, keep, *, scale, precision):
 
 def _bwd_dq_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_acc, *, scale, causal, tiles: Tiles, n_kv,
-                   lane_lse=False, precision=None):
+                   lane_lse=False, precision=None, window=None):
     block_q, block_k, sub = tiles
     qi = pl.program_id(1)
     kv = pl.program_id(2)
@@ -513,7 +572,7 @@ def _bwd_dq_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         c = _at(t, sub)
         k = k_ref[0, pl.ds(c, sub), :]
         keep = _keep(None if mask_ref is None else mask_ref[0, t],
-                     need_tri, row0, col0 + c, (block_q, sub))
+                     need_tri, row0, col0 + c, (block_q, sub), window)
         p = _recompute_p(q_ref[0], k, _rows(lse_ref[0], lane_lse), keep,
                          scale=scale, precision=precision)
         dp = jax.lax.dot_general(                       # dO @ V^T [bq, sub]
@@ -524,9 +583,10 @@ def _bwd_dq_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             precision=precision, preferred_element_type=jnp.float32)
 
-    n_plain, n_need = _k_ranges(causal, row0, block_q, col0, sub,
-                                block_k // sub)
-    _sub_loop(0, n_plain, lambda t: step(t, False))
+    n_first, n_lo, n_plain, n_need = _k_ranges(
+        causal, row0, block_q, col0, sub, block_k // sub, window)
+    _sub_loop(n_first, n_lo, lambda t: step(t, True))
+    _sub_loop(n_lo, n_plain, lambda t: step(t, False))
     _sub_loop(n_plain, n_need, lambda t: step(t, True))
 
     @pl.when(kv == n_kv - 1)
@@ -537,13 +597,16 @@ def _bwd_dq_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _bwd_dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc,
                     *, scale, causal, tiles: Tiles, n_q,
-                    lane_lse=False, precision=None):
+                    lane_lse=False, precision=None, window=None, group=1):
     block_q, block_k, sub = tiles
     kv = pl.program_id(1)
-    qi = pl.program_id(2)
+    step_i = pl.program_id(2)
+    # the last grid dim walks the Q blocks of each of the ``group`` query
+    # heads that read this K/V head in turn, and dK and dV sum over them
+    qi = step_i if group == 1 else jax.lax.rem(step_i, jnp.int32(n_q))
     row0, col0 = qi * block_q, kv * block_k
 
-    @pl.when(qi == 0)
+    @pl.when(step_i == 0)
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -558,7 +621,7 @@ def _bwd_dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             lse = lse_ref[0, pl.ds(r, sub), :]
             delta = delta_ref[0, pl.ds(r, sub), :]
         keep = _keep(None if mask_ref is None else mask_ref[0],
-                     need_tri, row0 + r, col0, (sub, block_k))
+                     need_tri, row0 + r, col0, (sub, block_k), window)
         p = _recompute_p(q, k_ref[0], _rows(lse, lane_lse), keep,
                          scale=scale, precision=precision)  # [sub, bk]
         dv_acc[...] += jax.lax.dot_general(             # P^T @ dO  [bk, d]
@@ -573,11 +636,13 @@ def _bwd_dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             precision=precision, preferred_element_type=jnp.float32)
 
     n_sub = block_q // sub
-    t_first, t_plain = _q_ranges(causal, col0, block_k, row0, sub, n_sub)
+    t_first, t_plain, t_hi, t_end = _q_ranges(causal, col0, block_k, row0,
+                                              sub, n_sub, window)
     _sub_loop(t_first, t_plain, lambda t: step(t, True))
-    _sub_loop(t_plain, n_sub, lambda t: step(t, False))
+    _sub_loop(t_plain, t_hi, lambda t: step(t, False))
+    _sub_loop(t_hi, t_end, lambda t: step(t, True))
 
-    @pl.when(qi == n_q - 1)
+    @pl.when(step_i == group * n_q - 1)
     def _():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -585,15 +650,18 @@ def _bwd_dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_bwd_dq(q, k, v, mask, do, lse, delta, *, scale, causal,
-                  tiles: Tiles, lane: bool, interpret, precision=None):
+                  tiles: Tiles, lane: bool, interpret, precision=None,
+                  window=None, group=1):
     """dq: grid (bn, q blocks, kv blocks), the inner loop walks K/V."""
     bn, s_q, d = q.shape
     bq, bk, _ = tiles
     n_q, n_kv = s_q // bq, k.shape[1] // bk
 
-    k_block = _k_block_of(causal, bq, bk, n_kv)
+    k_block = _k_block_of(causal, bq, bk, n_kv, window)
+    kv_head = _kv_head_of(group)
     q_spec = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))
-    kv_spec = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, k_block(i, j), 0))
+    kv_spec = pl.BlockSpec(
+        (1, bk, d), lambda b, i, j: (kv_head(b), k_block(i, j), 0))
     if lane:
         row_spec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
         rows = [lse[:, None, :], delta[:, None, :]]
@@ -602,7 +670,7 @@ def _flash_bwd_dq(q, k, v, mask, do, lse, delta, *, scale, causal,
         rows = [lse[:, :, None], delta[:, :, None]]
     kernel = functools.partial(
         _bwd_dq_kernel, scale=scale, causal=causal, tiles=tiles, n_kv=n_kv,
-        lane_lse=lane, precision=precision)
+        lane_lse=lane, precision=precision, **_window_kw(window))
     mspec, margs = [], []
     if mask is None:
         kernel = functools.partial(kernel, None)
@@ -625,41 +693,52 @@ def _flash_bwd_dq(q, k, v, mask, do, lse, delta, *, scale, causal,
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _flash_bwd_dkv(q, k, v, mask, do, lse, delta, *, scale, causal,
-                   tiles: Tiles, lane: bool, interpret, precision=None):
-    """dk/dv: grid (bn, kv blocks, q blocks), the inner loop walks Q/dO."""
+                   tiles: Tiles, lane: bool, interpret, precision=None,
+                   window=None, group=1):
+    """dk/dv: grid (K/V heads, kv blocks, q blocks of each query head of
+    the group in turn), the inner loop walks Q/dO."""
     bn, s_q, d = q.shape
+    bn_kv = k.shape[0]
     bq, bk, sub = tiles
     n_q, n_kv = s_q // bq, k.shape[1] // bk
 
-    q_block = _q_block_of(causal, bq, bk, n_q)
-    q_spec = pl.BlockSpec((1, bq, d), lambda b, j, i: (b, q_block(j, i), 0))
+    q_block = _q_block_of(causal, bq, bk, n_q, window)
+    if group == 1:
+        q_at = lambda b, j, i: (b, q_block(j, i))  # noqa: E731
+        kernel_kw = _window_kw(window)
+    else:
+        q_at = lambda b, j, i: (  # noqa: E731
+            b * group + _div(i, n_q),
+            q_block(j, jax.lax.rem(i, jnp.int32(n_q))))
+        kernel_kw = dict(_window_kw(window), group=group)
+    q_spec = pl.BlockSpec((1, bq, d), lambda b, j, i: (*q_at(b, j, i), 0))
     kv_spec = pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))
     if lane:
         # [bn, s_q / sub, 1, sub]: sub-block t of a block is ref[0, t],
         # lane-major as it is used (the key mask's layout in the other two
         # kernels, for the same reason)
         row_spec = pl.BlockSpec((1, bq // sub, 1, sub),
-                                lambda b, j, i: (b, q_block(j, i), 0, 0))
+                                lambda b, j, i: (*q_at(b, j, i), 0, 0))
         rows = [x.reshape(bn, s_q // sub, 1, sub) for x in (lse, delta)]
     else:
         row_spec = pl.BlockSpec((1, bq, 1),
-                                lambda b, j, i: (b, q_block(j, i), 0))
+                                lambda b, j, i: (*q_at(b, j, i), 0))
         rows = [lse[:, :, None], delta[:, :, None]]
     kernel = functools.partial(
         _bwd_dkv_kernel, scale=scale, causal=causal, tiles=tiles, n_q=n_q,
-        lane_lse=lane, precision=precision)
+        lane_lse=lane, precision=precision, **kernel_kw)
     mspec, margs = [], []
     if mask is None:
         kernel = functools.partial(kernel, None)
     else:
-        n_heads = bn // mask.shape[0]
+        n_heads = bn_kv // mask.shape[0]
         mspec = [pl.BlockSpec((1, 1, bk),
                               lambda b, j, i: (b // n_heads, 0, j))]
         margs = [mask[:, None, :]]
     return pl.pallas_call(
         kernel,
         name="flash_bwd_dkv",
-        grid=(bn, n_kv, n_q),
+        grid=(bn_kv, n_kv, group * n_q),
         in_specs=mspec + [q_spec, kv_spec, kv_spec, q_spec, row_spec,
                           row_spec],
         out_specs=[kv_spec, kv_spec],
@@ -673,7 +752,7 @@ def _flash_bwd_dkv(q, k, v, mask, do, lse, delta, *, scale, causal,
 
 
 def _flash_bwd(q, k, v, mask, out, lse, do, *, scale, causal, tiling,
-               interpret, precision=None, dlse=None):
+               interpret, precision=None, dlse=None, window=None, group=1):
     # delta_i = rowsum(dO_i * O_i) — tiny elementwise reduce; let XLA fuse
     # it.  The residual arrays (delta, lse) take the generation-conditional
     # layout (_lse_lane_major): lane-major where the re-layout compiles,
@@ -690,7 +769,8 @@ def _flash_bwd(q, k, v, mask, out, lse, do, *, scale, causal, tiling,
         delta = delta - dlse.astype(jnp.float32)
     _, dq_tiles, dkv_tiles = tiling
     common = dict(scale=scale, causal=causal, lane=_lse_lane_major(),
-                  interpret=interpret, precision=precision)
+                  interpret=interpret, precision=precision,
+                  **_geometry_kw(window, group))
     dq = _flash_bwd_dq(q, k, v, mask, do, lse, delta, tiles=dq_tiles,
                        **common)
     dk, dv = _flash_bwd_dkv(q, k, v, mask, do, lse, delta, tiles=dkv_tiles,
@@ -698,57 +778,81 @@ def _flash_bwd(q, k, v, mask, out, lse, do, *, scale, causal, tiling,
     return dq, dk, dv
 
 
+def _geometry_kw(window, group) -> dict:
+    """The launchers' ``window`` and ``group`` keywords, each left out at
+    its default, so that a call with neither is the jitted launch it has
+    always been."""
+    kw = _window_kw(window)
+    if group != 1:
+        kw["group"] = group
+    return kw
+
+
 # ---------------------------------------------------------------------------
 # public API
 # ---------------------------------------------------------------------------
 
 
-def _fwd(q, k, v, mask, causal, tiling, interpret, precision):
+class _Static(NamedTuple):
+    """What the kernels are specialised on besides the arrays' shapes."""
+    causal: bool
+    tiling: tuple
+    interpret: bool
+    precision: object
+    window: int | None = None   # key j attends iff 0 <= i - j < window
+    group: int = 1              # query heads to a K/V head
+
+
+def _fwd(q, k, v, mask, st: _Static):
     return _flash_fwd(q, k, v, mask, scale=q.shape[-1] ** -0.5,
-                      causal=causal, tiles=tiling[0], lane=_lse_lane_major(),
-                      interpret=interpret, precision=precision)
+                      causal=st.causal, tiles=st.tiling[0],
+                      lane=_lse_lane_major(), interpret=st.interpret,
+                      precision=st.precision,
+                      **_geometry_kw(st.window, st.group))
 
 
-def _bwd(causal, tiling, interpret, precision, res, do, dlse=None):
+def _bwd(st: _Static, res, do, dlse=None):
     q, k, v, mask, out, lse = res
     dq, dk, dv = _flash_bwd(q, k, v, mask, out, lse, do,
-                            scale=q.shape[-1] ** -0.5, causal=causal,
-                            tiling=tiling, interpret=interpret,
-                            precision=precision, dlse=dlse)
+                            scale=q.shape[-1] ** -0.5, causal=st.causal,
+                            tiling=st.tiling, interpret=st.interpret,
+                            precision=st.precision, dlse=dlse,
+                            window=st.window, group=st.group)
     return dq, dk, dv, None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, mask, causal, tiling, interpret, precision):
-    return _fwd(q, k, v, mask, causal, tiling, interpret, precision)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _flash(q, k, v, mask, st):
+    return _fwd(q, k, v, mask, st)[0]
 
 
-def _flash_vjp_fwd(q, k, v, mask, causal, tiling, interpret, precision):
-    out, lse = _fwd(q, k, v, mask, causal, tiling, interpret, precision)
+def _flash_vjp_fwd(q, k, v, mask, st):
+    out, lse = _fwd(q, k, v, mask, st)
     return out, (q, k, v, mask, out, lse)
 
 
 _flash.defvjp(_flash_vjp_fwd, _bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_lse(q, k, v, mask, causal, tiling, interpret, precision):
-    return _fwd(q, k, v, mask, causal, tiling, interpret, precision)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _flash_lse(q, k, v, mask, st):
+    return _fwd(q, k, v, mask, st)
 
 
-def _flash_lse_vjp_fwd(q, k, v, mask, causal, tiling, interpret, precision):
-    out, lse = _fwd(q, k, v, mask, causal, tiling, interpret, precision)
+def _flash_lse_vjp_fwd(q, k, v, mask, st):
+    out, lse = _fwd(q, k, v, mask, st)
     return (out, lse), (q, k, v, mask, out, lse)
 
 
-def _flash_lse_vjp_bwd(causal, tiling, interpret, precision, res, cots):
-    return _bwd(causal, tiling, interpret, precision, res, *cots)
+def _flash_lse_vjp_bwd(st, res, cots):
+    return _bwd(st, res, *cots)
 
 
 _flash_lse.defvjp(_flash_lse_vjp_fwd, _flash_lse_vjp_bwd)
 
 
-def _prepare(op, q, k, mask, block_q, block_k, interpret):
+def _prepare(op, q, k, mask, block_q, block_k, interpret, causal=False,
+             precision=None, window=None):
     """What both entry points do before the kernels: refuse a shape that
     does not tile, choose the tiling, resolve the implementation and
     record it with the tiling and the grids that engaged."""
@@ -758,16 +862,29 @@ def _prepare(op, q, k, mask, block_q, block_k, interpret):
             f"block_q={block_q}, block_k={block_k} blocks; use "
             f"tpuframe.ops.attention.multihead_attention for the fallback")
     b, s_q, n, d = q.shape
-    s_kv = k.shape[1]
+    s_kv, n_kv = k.shape[1], k.shape[2]
+    if n % n_kv:
+        raise ValueError(f"{op}: {n} query heads do not share {n_kv} K/V "
+                         f"heads evenly")
+    if window is not None and not (causal and window > 0):
+        raise ValueError(f"{op}: a window of {window} needs causal=True and "
+                         f"at least one key")
+    if window is not None and window >= s_kv:
+        window = None        # every key below the diagonal is inside it
     tiling = _tiling(s_q, s_kv, d, q.dtype.itemsize, block_q, block_k)
     said = "; ".join(
         f"{name} q{t.block_q} k{t.block_k} sub{t.sub} grid "
         f"{b * n}x{s_q // t.block_q}x{s_kv // t.block_k}"
         for name, t in zip(_KERNELS, tiling))
-    interpret = kernel_impl.resolve_interpret(
-        op.replace("flash_mha", "flash_attention"), interpret, detail=said)
+    name = op.replace("flash_mha", "flash_attention")
+    if window is not None or n != n_kv:
+        said += f"; window {window}, {n // n_kv} query heads a K/V head"
+        if window is not None:
+            name = name.replace("flash_attention", "flash_window_attention")
+    interpret = kernel_impl.resolve_interpret(name, interpret, detail=said)
     mask = None if mask is None else mask.astype(jnp.int32)
-    return mask, tiling, interpret
+    return mask, _Static(causal, tiling, interpret, precision, window,
+                         n // n_kv)
 
 
 def _fold(x):  # [B, S, N, D] → [B*N, S, D]
@@ -790,27 +907,34 @@ def flash_mha_lse(q: jax.Array, k: jax.Array, v: jax.Array, *,
     gradient.  Fully-masked rows report ``lse = NEG_INF`` and zero
     output, so they contribute nothing to a merge.
     """
-    mask, tiling, interpret = _prepare(
-        "flash_mha_lse", q, k, mask, block_q, block_k, interpret)
+    mask, st = _prepare("flash_mha_lse", q, k, mask, block_q, block_k,
+                        interpret, causal, precision)
     b, s_q, n, d = q.shape
-    out, lse = _flash_lse(_fold(q), _fold(k), _fold(v), mask, causal,
-                          tiling, interpret, precision)
+    out, lse = _flash_lse(_fold(q), _fold(k), _fold(v), mask, st)
     return (out.reshape(b, n, s_q, d).transpose(0, 2, 1, 3),
             lse.reshape(b, n, s_q))
 
 
 def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
               mask: jax.Array | None = None, causal: bool = False,
+              window: int | None = None,
               block_q: int | None = None, block_k: int | None = None,
               interpret: bool | None = None,
               precision=None) -> jax.Array:
     """Flash multi-head attention.
 
     Args:
-      q, k, v: ``[batch, seq, heads, head_dim]`` (the attention.py layout).
+      q: ``[batch, seq, heads, head_dim]`` (the attention.py layout).
+      k, v: ``[batch, seq_kv, kv_heads, head_dim]``; ``kv_heads`` divides
+        ``heads``, and query head ``h`` reads K/V head ``h // (heads /
+        kv_heads)`` straight from HBM (grouped-query attention; K and V are
+        never repeated), dK and dV summing their group inside the kernel.
       mask: optional ``[batch, seq_kv]`` key-padding mask, 1 = attend.
       causal: apply a causal (autoregressive) mask; what lies above the
         diagonal is never computed, halving the work.
+      window: with ``causal``, key ``j`` attends to query ``i`` only while
+        ``i - j < window`` (sliding-window attention); blocks wholly past
+        the window are neither fetched nor computed, in all three kernels.
       block_q, block_k: what one grid step owns, for all three kernels;
         left out, :func:`choose_tiles` picks them per kernel from the
         shape (unless ``TPUFRAME_FA_BLOCK_Q/K`` or a measured tuning-DB row
@@ -824,9 +948,8 @@ def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
     Returns ``[batch, seq, heads, head_dim]`` attention output in q's dtype.
     """
-    mask, tiling, interpret = _prepare(
-        "flash_mha", q, k, mask, block_q, block_k, interpret)
+    mask, st = _prepare("flash_mha", q, k, mask, block_q, block_k, interpret,
+                        causal, precision, window)
     b, s_q, n, d = q.shape
-    out = _flash(_fold(q), _fold(k), _fold(v), mask, causal,
-                 tiling, interpret, precision)
+    out = _flash(_fold(q), _fold(k), _fold(v), mask, st)
     return out.reshape(b, n, s_q, d).transpose(0, 2, 1, 3)

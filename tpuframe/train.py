@@ -170,6 +170,22 @@ class Harness:
     elastic_resize: dict | None = None
 
 
+class _PublishingStep:
+    """A train step that hands each new ``model_state`` to ``publish``
+    (a reference, never a transfer); everything else is the step's own."""
+
+    def __init__(self, step, publish):
+        self._step, self._publish = step, publish
+
+    def __call__(self, state, batch):
+        state, metrics = self._step(state, batch)
+        self._publish(state.model_state)
+        return state, metrics
+
+    def __getattr__(self, name):
+        return getattr(self._step, name)
+
+
 def _resolved_fusion(cfg: TrainConfig) -> tuple:
     """The step program's gradient-fusion bucket threshold with its
     provenance: TPUFRAME_FUSION_THRESHOLD env > the tuning DB's
@@ -303,10 +319,17 @@ def build_harness(cfg: TrainConfig) -> Harness:
 
     sample = train_ds[:2]
     rng = jax.random.key(cfg.seed)
-    if _is_text_task(cfg) or _is_lm_task(cfg):
-        variables = model.init(rng, jnp.asarray(sample["input_ids"]))
+    # One program, not an op at a time: a block with a few hundred ops
+    # (afmoe's routing) costs minutes of per-op dispatch otherwise.
+    init = jax.jit(model.init)
+    if _is_lm_task(cfg):
+        # a decoder's parameters do not depend on the sequence's length:
+        # 128 tokens make the same tree from a far smaller program
+        variables = init(rng, jnp.asarray(sample["input_ids"][:, :128]))
+    elif _is_text_task(cfg):
+        variables = init(rng, jnp.asarray(sample["input_ids"]))
     else:
-        variables = model.init(
+        variables = init(
             rng, _maybe_normalize(cfg, jnp.asarray(sample["image"])))
     params = variables["params"]
     model_state = {k: v for k, v in variables.items() if k != "params"}
@@ -477,6 +500,9 @@ def build_harness(cfg: TrainConfig) -> Harness:
         eval_step = step_lib.make_eval_step(
             make_metric_fn(cfg, model), mesh, batch_partition=step_part,
             reduce_axes=reduce_axes, state_shardings=state_shardings)
+        publish = getattr(model, "publish_state", None)
+        if publish is not None:
+            train_step = _PublishingStep(train_step, publish)
 
     manager = None
     start_step = 0
@@ -553,6 +579,9 @@ def make_loss_fn(cfg: TrainConfig, model) -> step_lib.LossFn:
         raxis = _lm_reduce_axis(cfg, for_grad=True)
 
         def loss_fn(params, model_state, batch, rng):
+            # what the model keeps between steps (afmoe's routing counters)
+            # is mutable beside the auxiliary losses it sows
+            kept = [k for k in model_state if k != "aux_loss"]
             if cfg.fused_xent:
                 # Chunked fused head+loss: [B,S,V] logits never hit HBM
                 # (tpuframe.ops.fused_xent); the argmax for token accuracy
@@ -562,7 +591,7 @@ def make_loss_fn(cfg: TrainConfig, model) -> step_lib.LossFn:
                 hidden, sown = model.apply(
                     {"params": params, **model_state}, batch["input_ids"],
                     train=True, rngs={"dropout": rng},
-                    mutable=["aux_loss"], hidden_only=True)
+                    mutable=["aux_loss", *kept], hidden_only=True)
                 loss, acc = fx.mean_xent_and_accuracy(
                     hidden, params["lm_head"]["kernel"], batch["labels"],
                     ignore_index=-100, reduce_axis=raxis)
@@ -571,7 +600,7 @@ def make_loss_fn(cfg: TrainConfig, model) -> step_lib.LossFn:
                 logits, sown = model.apply({"params": params, **model_state},
                                            batch["input_ids"], train=True,
                                            rngs={"dropout": rng},
-                                           mutable=["aux_loss"])
+                                           mutable=["aux_loss", *kept])
                 # ignore_index=-100: the torch/HF convention — padded
                 # label positions (datasets.lm_text padded_docs) carry -100
                 # and contribute neither loss nor gradient; a no-op for
@@ -583,7 +612,9 @@ def make_loss_fn(cfg: TrainConfig, model) -> step_lib.LossFn:
                                                        batch["labels"],
                                                        ignore_index=-100,
                                                        reduce_axis=raxis)}
-            aux_leaves = jax.tree.leaves(sown)
+            if kept:
+                model_state = {**model_state, **{k: sown[k] for k in kept}}
+            aux_leaves = jax.tree.leaves(sown.get("aux_loss", {}))
             if aux_leaves:  # MoE load-balance penalty (tpuframe.ops.moe)
                 aux = sum(aux_leaves) / len(aux_leaves)
                 loss = loss + aux_w * aux
