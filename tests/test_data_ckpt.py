@@ -2,6 +2,7 @@
 cluster exercises host-sharding; golden restore/reshard invariants)."""
 
 import json
+import time
 
 import jax
 import jax.numpy as jnp
@@ -182,6 +183,122 @@ class TestShardedLoader:
         np.testing.assert_array_equal(
             np.asarray(plain["image"].astype(jnp.bfloat16)),
             np.asarray(cast["image"]))
+
+    @pytest.mark.parametrize("case", [
+        "shuffled_epochs", "ordered_epochs", "skip", "from_step",
+        "callers_dataset_untouched", "float_target_stays_f32",
+        "uint8_not_copied", "shard_casts_its_rows_only",
+        "other_dataset_cast_by_batch"])
+    def test_cast_once_is_the_per_batch_cast_bit_for_bit(self, case,
+                                                         monkeypatch):
+        """The float ``cast_keys`` column is rounded once a loader, on the
+        loader's own copy; every batch reads the bits that rounding the
+        gathered float32 rows would give."""
+        from tpuframe.obs import timeline
+
+        bf16 = np.dtype(jnp.bfloat16)
+        rng = np.random.default_rng(7)
+        image = rng.standard_normal((40, 5, 3)).astype(np.float32)
+        columns = {"image": image,
+                   "label": np.arange(40, dtype=np.int32),
+                   "target": rng.standard_normal(40).astype(np.float32)}
+        ds = ArrayDataset(dict(columns))
+
+        def check(batches, idxs):
+            assert len(batches) == len(idxs)
+            for got, idx in zip(batches, idxs):
+                want = ds[idx]
+                assert got["image"].dtype == bf16
+                np.testing.assert_array_equal(
+                    np.asarray(got["image"]).view(np.uint16),
+                    want["image"].astype(bf16).view(np.uint16))
+                np.testing.assert_array_equal(np.asarray(got["label"]),
+                                              want["label"])
+
+        def batch_indices(loader, epoch):
+            order = loader._epoch_order(epoch)
+            return [order[lo:lo + 8] for lo in range(0, 40, 8)]
+
+        if case in ("shuffled_epochs", "ordered_epochs"):
+            loader = ShardedLoader(ds, 8, shuffle=case == "shuffled_epochs",
+                                   seed=3, cast_floats=jnp.bfloat16)
+            for epoch in range(3):
+                idxs = batch_indices(loader, epoch)
+                if case == "ordered_epochs":
+                    assert [list(i) for i in idxs] == [
+                        list(range(lo, lo + 8)) for lo in range(0, 40, 8)]
+                check(list(loader.epoch(epoch)), idxs)
+            assert loader.dataset.columns["image"].dtype == bf16
+        elif case == "skip":
+            loader = ShardedLoader(ds, 8, seed=3, cast_floats=jnp.bfloat16)
+            check(list(loader.epoch(2, skip=3)),
+                  batch_indices(loader, 2)[3:])
+        elif case == "from_step":
+            loader = ShardedLoader(ds, 8, seed=3, cast_floats=jnp.bfloat16)
+            stream = loader.from_step(7)       # epoch 1, batch 2, onward
+            got = [next(stream) for _ in range(6)]
+            loader.close()
+            check(got, batch_indices(loader, 1)[2:]
+                  + batch_indices(loader, 2)[:3])
+        elif case == "callers_dataset_untouched":
+            held = ds.columns
+            loader = ShardedLoader(ds, 8, cast_floats=jnp.bfloat16)
+            list(loader.epoch(0))
+            assert ds.columns is held and ds.columns["image"] is image
+            assert image.dtype == np.float32
+            np.testing.assert_array_equal(image, columns["image"])
+            assert loader.dataset is not ds
+            assert ds[:2]["image"].dtype == np.float32
+            # the columns that were not cast are shared, not copied
+            assert loader.dataset.columns["label"] is ds.columns["label"]
+        elif case == "float_target_stays_f32":
+            loader = ShardedLoader(ds, 8, shuffle=False,
+                                   cast_floats=jnp.bfloat16)
+            assert loader.dataset.columns["target"] is ds.columns["target"]
+            first = next(loader.epoch(0))
+            assert first["target"].dtype == np.float32
+            np.testing.assert_array_equal(np.asarray(first["target"]),
+                                          columns["target"][:8])
+        elif case == "uint8_not_copied":
+            u8 = ArrayDataset({
+                "image": rng.integers(0, 256, (40, 5, 3), dtype=np.uint8),
+                "label": columns["label"]})
+            t = time.monotonic()
+            loader = ShardedLoader(u8, 8, shuffle=False,
+                                   cast_floats=jnp.bfloat16)
+            assert loader.dataset is u8
+            first = next(loader.epoch(0))
+            assert first["image"].dtype == np.uint8
+            assert timeline.spans("loader.cast_column", t0=t) == []
+        elif case == "shard_casts_its_rows_only":
+            monkeypatch.setattr(jax, "process_count", lambda: 2)
+            monkeypatch.setattr(jax, "process_index", lambda: 1)
+            t = time.monotonic()
+            loader = ShardedLoader(ds, 16, shuffle=False,
+                                   cast_floats=jnp.bfloat16)
+            (made,) = timeline.spans("loader.cast_column", t0=t)
+            assert made.args == {"key": "image", "rows": 20,
+                                 "bytes": 20 * 5 * 3 * 2}
+            np.testing.assert_array_equal(
+                loader.dataset.columns["image"].view(np.uint16),
+                image[20:].astype(bf16).view(np.uint16))
+            np.testing.assert_array_equal(loader.dataset.columns["label"],
+                                          columns["label"][20:])
+        else:
+            # a data set that shows no columns: its batches are cast as
+            # they come, by the pass the ``loader.cast`` span times
+            class Rows:
+                def __len__(self):
+                    return len(ds)
+
+                def __getitem__(self, idx):
+                    return ds[idx]
+
+            t = time.monotonic()
+            loader = ShardedLoader(Rows(), 8, shuffle=False,
+                                   cast_floats=jnp.bfloat16)
+            check(list(loader.epoch(0)), batch_indices(loader, 0))
+            assert timeline.spans("loader.cast_column", t0=t) == []
 
 
 class TestGcsAbstraction:

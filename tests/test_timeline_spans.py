@@ -416,6 +416,61 @@ def test_loader_without_a_cast_makes_no_cast_span():
         == [6, 7]                               # numbered within the epoch
 
 
+def test_cast_column_span_and_counter_once_a_loader():
+    """Two epochs of one loader: the image column is cast once (not once
+    an epoch, not once a batch), by the thread that built the loader; the
+    worker's per-batch spans go on as before."""
+    from tpuframe.data import ShardedLoader
+    from tpuframe.data.datasets import ArrayDataset
+
+    ds = ArrayDataset({
+        "image": np.arange(40 * 6, dtype=np.float32).reshape(40, 6),
+        "label": np.arange(40, dtype=np.int32)})
+    before = metrics.counters("loader.").get("loader.bytes_cast_once", 0)
+    t = time.monotonic()
+    loader = ShardedLoader(ds, 8, None, prefetch=2, cast_floats="bfloat16")
+    for epoch in (0, 1):
+        assert len(list(loader.epoch(epoch))) == 5
+    loader.close()
+    (made,) = timeline.spans("loader.cast_column", t0=t)
+    assert made.args == {"key": "image", "rows": 40, "bytes": 40 * 6 * 2}
+    assert made.thread == threading.current_thread().name
+    assert made.parent is None
+    grew = metrics.counters("loader.")["loader.bytes_cast_once"] - before
+    assert grew == made.args["bytes"]
+    # before the first batch was gathered
+    assert made.t1 <= timeline.spans("loader.gather", t0=t)[0].t0
+    for name in ("loader.gather", "loader.cast", "loader.put",
+                 "loader.queue_full"):
+        got = timeline.spans(name, t0=t)
+        assert [s.args["batch"] for s in got] == 2 * [0, 1, 2, 3, 4], name
+        assert {s.thread for s in got} == {"tpuframe-prefetch"}, name
+    for n in range(10):
+        ends = [timeline.spans(name, t0=t)[n] for name in (
+            "loader.gather", "loader.cast", "loader.put",
+            "loader.queue_full")]
+        assert all(a.t1 <= b.t0 for a, b in zip(ends, ends[1:]))
+
+
+def test_loader_without_a_cast_makes_no_cast_column_span():
+    from tpuframe.data import ShardedLoader
+    from tpuframe.data.datasets import ArrayDataset
+
+    ds = ArrayDataset({
+        "image": np.arange(40 * 6, dtype=np.float32).reshape(40, 6)})
+    before = metrics.counters("loader.").get("loader.bytes_cast_once", 0)
+    t = time.monotonic()
+    loader = ShardedLoader(ds, 8, None, prefetch=1)
+    first = next(loader.epoch(0))
+    loader.close()
+    assert np.asarray(first["image"]).dtype == np.float32
+    assert loader.dataset is ds
+    assert timeline.spans("loader.cast_column", t0=t) == []
+    assert timeline.spans("loader.cast", t0=t) == []
+    assert metrics.counters("loader.").get(
+        "loader.bytes_cast_once", 0) == before
+
+
 def test_full_queue_shows_as_queue_full_time():
     """A consumer slower than the worker: the worker's time goes to
     ``loader.queue_full``, the consumer's ``loader.wait`` stays short."""

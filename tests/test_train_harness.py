@@ -105,6 +105,40 @@ class TestEndToEnd:
         assert metrics["step"] == 40
         assert np.isfinite(metrics["loss"]) and metrics["loss"] < 2.0
 
+    def test_bf16_harness_keeps_the_rounded_column_only(self, monkeypatch):
+        """``build_harness`` under bf16 compute: each loader holds its
+        image column in bfloat16, ``model.init`` still saw float32 rows,
+        and once the harness is built nothing keeps the float32 columns
+        alive (the loaders cast their own copies once)."""
+        import gc
+        import weakref
+
+        import jax.numpy as jnp
+
+        refs = []
+        build = train_mod.build_datasets
+
+        def watched(cfg):
+            made = build(cfg)
+            for ds in made:
+                assert ds.columns["image"].dtype == np.float32
+                refs.append(weakref.ref(ds.columns["image"]))
+            return made
+
+        monkeypatch.setattr(train_mod, "build_datasets", watched)
+        h = train_mod.build_harness(get_config("smoke").with_overrides(
+            distributed=False, compute_dtype="bfloat16"))
+        try:
+            for loader in (h.train_loader, h.eval_loader):
+                assert loader.dataset.columns["image"].dtype == jnp.bfloat16
+                assert loader.dataset.columns["label"].dtype == np.int32
+            gc.collect()
+            assert len(refs) == 2 and all(r() is None for r in refs)
+            assert next(iter(h.train_loader))["image"].dtype == jnp.bfloat16
+        finally:
+            h.train_loader.close()
+            h.eval_loader.close()
+
     def test_cifar_resnet18_steps(self):
         cfg = get_config("cifar10_resnet18").with_overrides(
             total_steps=3, global_batch=16, warmup_steps=1, log_every=1,
