@@ -117,6 +117,33 @@ def test_flash_window_grouped_heads_compile_for_v5e(v5e, window):
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+def test_flash_latent_attention_compiles_for_v5e(v5e):
+    """The deepseek_v3 block's attention at one chip's share: 8192 tokens,
+    32 heads whose scores are a 128-deep and a 64-deep product (the rotary
+    key held once a position, ``[1, 8192, 1, 64]``) and whose values are
+    128 wide: forward and both backward kernels, dk_rope out per position."""
+    from tpuframe.ops import flash_attention as fa
+
+    def sds(n, d):
+        return jax.ShapeDtypeStruct((1, 8192, n, d), jnp.bfloat16,
+                                    sharding=v5e)
+
+    args = (sds(32, 128), sds(32, 64), sds(32, 128), sds(1, 64),
+            sds(32, 128))
+    assert fa.mla_supported(*args)
+
+    def loss(*a):
+        return fa.flash_mla(*a, interpret=False).astype(jnp.float32).sum()
+
+    c = _compile_grad(loss, *args)
+    text = c.as_text()
+    for name in ("flash_mla_fwd", "flash_mla_bwd_dq", "flash_mla_bwd_dkv"):
+        assert len(_kernel_calls(text, name)) == 1, name
+    # the rotary key goes in, and its gradient comes out, one row a position
+    assert "bf16[1,8192,64]" in _kernel_calls(text, "flash_mla_bwd_dkv")[0]
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def test_moe_grouped_product_compiles_for_v5e(v5e):
     """The grouped matrix product of the expert layer at Trinity-Mini's
     widths, 16 experts of 2048 -> 1024 held: forward, the rows' and the

@@ -13,6 +13,7 @@ from typing import Any, Callable
 
 from tpuframe.models.afmoe import Afmoe, AfmoeConfig
 from tpuframe.models.convnet import ConvNet
+from tpuframe.models.deepseek_v3 import DeepseekV3, DeepseekV3Config
 from tpuframe.models.resnet import (ResNet, ResNet18, ResNet34,
                                     ResNet50, ResNet101, ResNet152)
 from tpuframe.models.bert import BertConfig, BertForSequenceClassification
@@ -30,38 +31,28 @@ def _bert_base(dtype=None, **kwargs):
     return BertForSequenceClassification(BertConfig.base(**kwargs))
 
 
-def _lm_adapter(cls):
-    """Registry adapter shared by the LM variants: flag-style kwargs →
-    LMConfig → the given module class."""
+def _decoder_adapter(cls, config_cls):
+    """Registry adapter shared by the decoders: flag-style kwargs → the
+    config class (its ``tiny()`` preset under ``tiny=True``; lists from a
+    JSON config become the tuples a frozen config hashes) → the module."""
 
     def build(dtype=None, tiny=False, **kwargs):
         import numpy as np
 
         if dtype is not None:
             kwargs.setdefault("dtype", str(np.dtype(dtype)))
-        cfg = LMConfig.tiny(**kwargs) if tiny else LMConfig(**kwargs)
-        return cls(cfg)
+        kwargs = {k: tuple(v) if isinstance(v, list) else v
+                  for k, v in kwargs.items()}
+        return cls(config_cls.tiny(**kwargs) if tiny
+                   else config_cls(**kwargs))
 
     return build
 
 
-def _afmoe(dtype=None, tiny=False, **kwargs):
-    """Registry adapter: flag-style kwargs → AfmoeConfig → Afmoe (lists
-    from a JSON config become the tuples a frozen config hashes)."""
-    import numpy as np
-
-    if dtype is not None:
-        kwargs.setdefault("dtype", str(np.dtype(dtype)))
-    if "layer_types" in kwargs:
-        kwargs["layer_types"] = tuple(kwargs["layer_types"])
-    return Afmoe(AfmoeConfig.tiny(**kwargs) if tiny
-                 else AfmoeConfig(**kwargs))
-
-
 # transformer-lm-pp: the pipeline-parallel variant (layer-stacked blocks;
 # trained via tpuframe.parallel.pp_lm on a data x pipe mesh).
-_transformer_lm = _lm_adapter(TransformerLM)
-_transformer_lm_pp = _lm_adapter(ScanBlockLM)
+_transformer_lm = _decoder_adapter(TransformerLM, LMConfig)
+_transformer_lm_pp = _decoder_adapter(ScanBlockLM, LMConfig)
 
 
 _REGISTRY: dict[str, Callable[..., Any]] = {
@@ -74,7 +65,8 @@ _REGISTRY: dict[str, Callable[..., Any]] = {
     "bert-base": _bert_base,
     "transformer-lm": _transformer_lm,
     "transformer-lm-pp": _transformer_lm_pp,
-    "afmoe": _afmoe,
+    "afmoe": _decoder_adapter(Afmoe, AfmoeConfig),
+    "deepseek_v3": _decoder_adapter(DeepseekV3, DeepseekV3Config),
 }
 
 
@@ -89,6 +81,8 @@ __all__ = [
     "Afmoe",
     "AfmoeConfig",
     "ConvNet",
+    "DeepseekV3",
+    "DeepseekV3Config",
     "LMConfig",
     "ScanBlockLM",
     "TransformerLM",

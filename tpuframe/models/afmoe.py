@@ -86,13 +86,8 @@ class AfmoeConfig:
             raise ValueError(f"layer_types {types} does not name "
                              f"{self.num_layers} layers' attention")
         object.__setattr__(self, "layer_types", types)
-        held = self.num_experts if self.experts_held is None \
-            else self.experts_held
-        if not 0 < held <= self.num_experts - self.expert_first:
-            raise ValueError(f"experts [{self.expert_first}, "
-                             f"{self.expert_first + held}) are not among "
-                             f"{self.num_experts}")
-        object.__setattr__(self, "experts_held", held)
+        object.__setattr__(self, "experts_held", held_experts(
+            self.num_experts, self.experts_held, self.expert_first))
         if self.num_heads % self.num_kv_heads:
             raise ValueError(f"{self.num_heads} query heads over "
                              f"{self.num_kv_heads} K/V heads")
@@ -114,6 +109,20 @@ class AfmoeConfig:
                     experts_held=4, max_seq=64)
         base.update(kw)
         return cls(**base)
+
+
+def held_experts(num_experts: int, held: int | None, first: int) -> int:
+    """How many experts a chip that holds ``[first, first + held)`` of
+    ``num_experts`` holds (``held`` None: all of them), or a ValueError."""
+    held = num_experts if held is None else held
+    if not 0 < held <= num_experts - first:
+        raise ValueError(f"experts [{first}, {first + held}) are not among "
+                         f"{num_experts}")
+    return held
+
+
+# The norm, the MLP and the expert layer below are also the ``deepseek_v3``
+# block's (models/deepseek_v3.py): ``cfg`` is either decoder's config.
 
 
 class RMSNorm(nn.Module):

@@ -22,8 +22,12 @@ import jax.numpy as jnp
 def multihead_attention(
     q: jax.Array,  # [B, S, N, D]
     k: jax.Array,  # [B, S, N_kv, D]: N_kv divides N (grouped-query heads)
-    v: jax.Array,  # [B, S, N_kv, D]
+    v: jax.Array,  # [B, S, N_kv, D_v]: D_v may differ from D
     *,
+    # a second score term (latent attention): (q_rope [B, S, N, D_r],
+    # k_rope [B, S, N_r, D_r]), N_r dividing N; scores are
+    # (q.k + q_rope.k_rope) / sqrt(D + D_r)
+    rope: tuple[jax.Array, jax.Array] | None = None,
     mask: jax.Array | None = None,  # [B, S] 1=keep or broadcastable [B,1,S,S]
     causal: bool = False,
     window: int | None = None,  # with causal: key j attends iff i - j < window
@@ -31,17 +35,29 @@ def multihead_attention(
     dropout_rng: jax.Array | None = None,
     impl: str | None = None,
 ) -> jax.Array:
-    """Query head ``h`` reads K/V head ``h // (N / N_kv)``.  The flash
-    kernels read the shared head where it lies; the XLA composition
-    repeats K and V."""
+    """Query head ``h`` reads K/V head ``h // (N / N_kv)``, and ``rope``'s
+    key head ``h // (N / N_r)``.  The flash kernels read a shared head
+    where it lies; the XLA composition repeats it."""
     impl = impl or os.environ.get("TPUFRAME_ATTN_IMPL", "xla")
     if window is not None and not causal:
         raise ValueError("a sliding window needs causal=True")
     if impl == "pallas":
         from tpuframe.ops import flash_attention, kernel_impl
 
+        op = "flash_attention" if rope is None else "flash_mla_attention"
         if dropout_rate != 0.0:
             why = "dropout"
+        elif rope is not None:
+            if mask is not None or window is not None:
+                why = "a key mask or a window beside a second score term"
+            elif not flash_attention.mla_supported(q, rope[0], k, rope[1], v):
+                why = (f"shapes q={q.shape} + {rope[0].shape} k={k.shape} + "
+                       f"{rope[1].shape} v={v.shape} do not tile")
+            else:
+                return flash_attention.flash_mla(q, rope[0], k, rope[1], v,
+                                                 causal=causal)
+        elif v.shape[-1] != q.shape[-1]:
+            why = f"v is {v.shape[-1]} wide, q {q.shape[-1]}"
         elif not flash_attention.supported(q, k):
             why = f"shapes q={q.shape} k={k.shape} do not tile"
         elif mask is not None and mask.ndim != 2:
@@ -50,10 +66,15 @@ def multihead_attention(
             return flash_attention.flash_mha(q, k, v, mask=mask, causal=causal,
                                              window=window)
         # The XLA composition stands in; said once, never silently.
-        kernel_impl.record("flash_attention", "xla", why)
+        kernel_impl.record(op, "xla", why)
         impl = "xla"
     if impl != "xla":
         raise ValueError(f"unknown attention impl {impl!r}")
+    if rope is not None:    # one score product over the concatenated widths
+        q_rope, k_rope = rope
+        k_rope = jnp.repeat(k_rope, k.shape[2] // k_rope.shape[2], axis=2)
+        q = jnp.concatenate([q, q_rope], axis=-1)
+        k = jnp.concatenate([k, k_rope], axis=-1)
     group = q.shape[2] // k.shape[2]
     if group > 1:
         k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)

@@ -140,46 +140,53 @@ def _fits(seq: int, cap: int) -> int:
 
 
 def vmem_bytes(kernel: str, tiles: Tiles, head_dim: int,
-               itemsize: int) -> int:
+               itemsize: int, rope_dim: int = 0) -> int:
     """VMEM one grid step of ``kernel`` holds at ``tiles``: every blocked
     operand and result twice (the pipeline double-buffers them), the f32
     accumulators, the row statistics at their wider (sublane-major, 128
     lanes a row) layout, and the score-sized tiles live inside one inner
     step — s and p forward, p, dp and ds backward, in f32, plus the copy
-    cast to the operands' dtype for the second product."""
+    cast to the operands' dtype for the second product.  ``rope_dim``: the
+    width of a second score term's operands (latent attention), which come
+    with their own gradients and accumulators."""
     bq, bk, sub = tiles
     d = -(-head_dim // _LANES) * _LANES       # the minor dim pads to lanes
     q_tile, k_tile = bq * d * itemsize, bk * d * itemsize
+    d_r = -(-rope_dim // _LANES) * _LANES
+    qr_tile, kr_tile = bq * d_r * itemsize, bk * d_r * itemsize
     stat = bq * _LANES * 4
     if kernel == "fwd":
         blocked = 2 * q_tile + 2 * k_tile + stat        # q, o; k, v; lse
+        blocked += qr_tile + kr_tile
         scratch = bq * d * 4 + 2 * stat                 # acc; m, l
         live = bq * sub * (2 * 4 + itemsize)
     elif kernel == "dq":
         blocked = 3 * q_tile + 2 * k_tile + 2 * stat    # q, do, dq; k, v
-        scratch = bq * d * 4
+        blocked += 2 * qr_tile + kr_tile                # q_rope, its dq
+        scratch = bq * (d + d_r) * 4
         live = bq * sub * (3 * 4 + itemsize)
     else:
         blocked = 2 * q_tile + 4 * k_tile + 2 * stat    # q, do; k, v, dk, dv
-        scratch = 2 * bk * d * 4
+        blocked += qr_tile + 2 * kr_tile                # k_rope, its dk
+        scratch = bk * (2 * d + d_r) * 4
         live = sub * bk * (3 * 4 + itemsize)
     return 2 * blocked + scratch + live
 
 
 def _compiler_params(kernel: str, tiles: Tiles, head_dim: int,
-                     itemsize: int) -> pltpu.CompilerParams:
+                     itemsize: int, rope_dim: int = 0) -> pltpu.CompilerParams:
     """The leading two grid dims carry no state from step to step (the
     accumulators live on the last): declaring them parallel lets Mosaic
     schedule and pipeline them freely.  The VMEM limit stays Mosaic's own
     unless the arithmetic says this tiling needs more."""
-    need = vmem_bytes(kernel, tiles, head_dim, itemsize)
+    need = vmem_bytes(kernel, tiles, head_dim, itemsize, rope_dim)
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
         vmem_limit_bytes=need if need > _SCOPED_DEFAULT else None)
 
 
 def choose_tiles(kernel: str, s_q: int, s_kv: int, head_dim: int,
-                 itemsize: int = 2) -> Tiles:
+                 itemsize: int = 2, rope_dim: int = 0) -> Tiles:
     """The tiling of ``kernel`` ("fwd", "dq" or "dkv") for this shape.
 
     The operand the inner loop walks — K and V forward and in dq, Q and dO
@@ -201,7 +208,8 @@ def choose_tiles(kernel: str, s_q: int, s_kv: int, head_dim: int,
         own = _fits(own_len, cap_own)
         for walked in _blocks_of(walked_len):
             t = tiles(own, walked, _fits(walked, cap_sub))
-            if vmem_bytes(kernel, t, head_dim, itemsize) <= VMEM_BUDGET:
+            if vmem_bytes(kernel, t, head_dim, itemsize,
+                          rope_dim) <= VMEM_BUDGET:
                 return t
         if cap_own <= _LANES and cap_sub <= _LANES:
             return t            # the smallest there is
@@ -212,14 +220,16 @@ def choose_tiles(kernel: str, s_q: int, s_kv: int, head_dim: int,
 
 
 def _tiling(s_q: int, s_kv: int, head_dim: int, itemsize: int,
-            block_q: int | None, block_k: int | None) -> tuple:
+            block_q: int | None, block_k: int | None,
+            rope_dim: int = 0) -> tuple:
     """Tiles of (fwd, dq, dkv).  A block the caller gave (or the override
     above) holds for all three kernels; the rule fills what is left."""
     block_q = block_q or _BLOCK_Q_OVERRIDE
     block_k = block_k or _BLOCK_K_OVERRIDE
     out = []
     for kernel in _KERNELS:
-        bq, bk, _ = choose_tiles(kernel, s_q, s_kv, head_dim, itemsize)
+        bq, bk, _ = choose_tiles(kernel, s_q, s_kv, head_dim, itemsize,
+                                 rope_dim)
         bq = min(block_q, s_q) if block_q else bq
         bk = min(block_k, s_kv) if block_k else bk
         walked = bq if kernel == "dkv" else bk
@@ -248,9 +258,15 @@ def supported(q: jax.Array, k: jax.Array | None = None,
     # beyond 256 would blow the per-block VMEM budget.
     if d > 256 or s_q % 8 or s_kv % 8:
         return False
+    return _tiles_whole(_tiling(s_q, s_kv, d, q.dtype.itemsize, block_q,
+                                block_k), s_q, s_kv)
+
+
+def _tiles_whole(tiling, s_q: int, s_kv: int) -> bool:
+    """Every kernel's blocks divide their sequence and are whole lanes (or
+    the one block of a sequence shorter than a lane row)."""
     return all(s % b == 0 and (b < _LANES or b % _LANES == 0)
-               for t in _tiling(s_q, s_kv, d, q.dtype.itemsize, block_q,
-                                block_k)
+               for t in tiling
                for s, b in ((s_q, t.block_q), (s_kv, t.block_k)))
 
 
@@ -406,7 +422,9 @@ def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref,  # inputs
                 acc_ref, m_ref, l_ref,          # scratch
                 *, scale: float, causal: bool, tiles: Tiles,
                 n_kv: int, lane_lse: bool = False, precision=None,
-                window: int | None = None):
+                window: int | None = None, rope=None):
+    """``rope``: the refs ``(q_rope, k_rope)`` of a second score term
+    (latent attention, below), summed with the first before the softmax."""
     block_q, block_k, sub = tiles
     qi = pl.program_id(1)
     kv = pl.program_id(2)
@@ -420,9 +438,8 @@ def _fwd_kernel(mask_ref, q_ref, k_ref, v_ref,  # inputs
 
     def step(t, need_tri):
         c = _at(t, sub)
-        s = jax.lax.dot_general(                          # [bq, sub]
-            q_ref[0], k_ref[0, pl.ds(c, sub), :], (((1,), (1,)), ((), ())),
-            precision=precision, preferred_element_type=jnp.float32) * scale
+        s = _qk(q_ref[0], k_ref[0, pl.ds(c, sub), :], precision,  # [bq, sub]
+                rope and (rope[0][0], rope[1][0, pl.ds(c, sub), :])) * scale
         keep = _keep(None if mask_ref is None else mask_ref[0, t],
                      need_tri, row0, col0 + c, s.shape, window)
         if keep is not None:
@@ -545,11 +562,18 @@ def _rows(ref_block, lane_lse):
     return ref_block.reshape(-1, 1) if lane_lse else ref_block
 
 
-def _recompute_p(q, k, lse, keep, *, scale, precision):
+def _qk(q, k, precision, rope=None):
+    """``q k^T`` in f32, plus ``rope``'s ``(q_rope, k_rope)`` product
+    where the scores have a second term."""
+    dot = lambda a, b: jax.lax.dot_general(  # noqa: E731
+        a, b, (((1,), (1,)), ((), ())), precision=precision,
+        preferred_element_type=jnp.float32)
+    return dot(q, k) if not rope else dot(q, k) + dot(*rope)
+
+
+def _recompute_p(q, k, lse, keep, *, scale, precision, rope=None):
     """Rebuild the probability tile from the saved logsumexp (f32)."""
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), precision=precision,
-        preferred_element_type=jnp.float32) * scale
+    s = _qk(q, k, precision, rope) * scale
     if keep is None:
         return jnp.exp(s - lse)
     p = jnp.exp(jnp.where(keep, s, NEG_INF) - lse)
@@ -558,7 +582,9 @@ def _recompute_p(q, k, lse, keep, *, scale, precision):
 
 def _bwd_dq_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                    dq_ref, dq_acc, *, scale, causal, tiles: Tiles, n_kv,
-                   lane_lse=False, precision=None, window=None):
+                   lane_lse=False, precision=None, window=None, rope=None):
+    """``rope``: the refs ``(q_rope, k_rope, dq_rope, its accumulator)``
+    of a second score term."""
     block_q, block_k, sub = tiles
     qi = pl.program_id(1)
     kv = pl.program_id(2)
@@ -567,14 +593,18 @@ def _bwd_dq_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(kv == 0)
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
+        if rope:
+            rope[3][...] = jnp.zeros_like(rope[3])
 
     def step(t, need_tri):
         c = _at(t, sub)
         k = k_ref[0, pl.ds(c, sub), :]
+        kr = rope and rope[1][0, pl.ds(c, sub), :]
         keep = _keep(None if mask_ref is None else mask_ref[0, t],
                      need_tri, row0, col0 + c, (block_q, sub), window)
         p = _recompute_p(q_ref[0], k, _rows(lse_ref[0], lane_lse), keep,
-                         scale=scale, precision=precision)
+                         scale=scale, precision=precision,
+                         rope=rope and (rope[0][0], kr))
         dp = jax.lax.dot_general(                       # dO @ V^T [bq, sub]
             do_ref[0], v_ref[0, pl.ds(c, sub), :], (((1,), (1,)), ((), ())),
             precision=precision, preferred_element_type=jnp.float32)
@@ -582,6 +612,10 @@ def _bwd_dq_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dq_acc[...] += scale * jax.lax.dot_general(     # ds @ K    [bq, d]
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             precision=precision, preferred_element_type=jnp.float32)
+        if rope:
+            rope[3][...] += scale * jax.lax.dot_general(
+                ds.astype(kr.dtype), kr, (((1,), (0,)), ((), ())),
+                precision=precision, preferred_element_type=jnp.float32)
 
     n_first, n_lo, n_plain, n_need = _k_ranges(
         causal, row0, block_q, col0, sub, block_k // sub, window)
@@ -592,12 +626,18 @@ def _bwd_dq_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(kv == n_kv - 1)
     def _():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+        if rope:
+            rope[2][0] = rope[3][...].astype(rope[2].dtype)
 
 
 def _bwd_dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_acc, dv_acc,
                     *, scale, causal, tiles: Tiles, n_q,
-                    lane_lse=False, precision=None, window=None, group=1):
+                    lane_lse=False, precision=None, window=None, group=1,
+                    rope=None):
+    """``rope``: the refs ``(q_rope, k_rope, dk_rope, its accumulator)`` of
+    a second score term whose key the ``group`` heads share: K and V are
+    then each head's own, and what sums over the group is dk_rope."""
     block_q, block_k, sub = tiles
     kv = pl.program_id(1)
     step_i = pl.program_id(2)
@@ -605,8 +645,14 @@ def _bwd_dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     # heads that read this K/V head in turn, and dK and dV sum over them
     qi = step_i if group == 1 else jax.lax.rem(step_i, jnp.int32(n_q))
     row0, col0 = qi * block_q, kv * block_k
+    if rope:
+        @pl.when(step_i == 0)
+        def _():
+            rope[3][...] = jnp.zeros_like(rope[3])
 
-    @pl.when(step_i == 0)
+    # dK and dV sum over the whole group, or, beside a shared rope key,
+    # over one head
+    @pl.when(qi == 0 if rope else step_i == 0)
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -622,8 +668,10 @@ def _bwd_dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             delta = delta_ref[0, pl.ds(r, sub), :]
         keep = _keep(None if mask_ref is None else mask_ref[0],
                      need_tri, row0 + r, col0, (sub, block_k), window)
+        qr = rope and rope[0][0, pl.ds(r, sub), :]
         p = _recompute_p(q, k_ref[0], _rows(lse, lane_lse), keep,
-                         scale=scale, precision=precision)  # [sub, bk]
+                         scale=scale, precision=precision,  # [sub, bk]
+                         rope=rope and (qr, rope[1][0]))
         dv_acc[...] += jax.lax.dot_general(             # P^T @ dO  [bk, d]
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
             precision=precision, preferred_element_type=jnp.float32)
@@ -634,6 +682,10 @@ def _bwd_dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] += scale * jax.lax.dot_general(     # ds^T @ Q  [bk, d]
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             precision=precision, preferred_element_type=jnp.float32)
+        if rope:
+            rope[3][...] += scale * jax.lax.dot_general(
+                ds.astype(qr.dtype), qr, (((0,), (0,)), ((), ())),
+                precision=precision, preferred_element_type=jnp.float32)
 
     n_sub = block_q // sub
     t_first, t_plain, t_hi, t_end = _q_ranges(causal, col0, block_k, row0,
@@ -642,10 +694,15 @@ def _bwd_dkv_kernel(mask_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     _sub_loop(t_plain, t_hi, lambda t: step(t, False))
     _sub_loop(t_hi, t_end, lambda t: step(t, True))
 
-    @pl.when(step_i == group * n_q - 1)
+    @pl.when(qi == n_q - 1 if rope else step_i == group * n_q - 1)
     def _():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    if rope:
+        @pl.when(step_i == group * n_q - 1)
+        def _():
+            rope[2][0] = rope[3][...].astype(rope[2].dtype)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
@@ -953,3 +1010,277 @@ def flash_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
     b, s_q, n, d = q.shape
     out = _flash(_fold(q), _fold(k), _fold(v), mask, st)
     return out.reshape(b, n, s_q, d).transpose(0, 2, 1, 3)
+
+
+# ---------------------------------------------------------------------------
+# latent attention: a second score term whose key the heads share
+# ---------------------------------------------------------------------------
+#
+#   s_h[i, j] = (q_h[i] . k_h[j] + q_rope_h[i] . k_rope[j]) * scale,  j <= i
+#   o_h = softmax_j(s_h) v_h
+#
+# with q, k ``d`` wide, the rope pair ``d_rope`` wide, v ``d_v`` wide and
+# ``k_rope`` held once a position for ``group`` heads (all of them in a
+# deepseek_v3 block).  The score is two MXU products, d and d_rope deep,
+# summed in f32 before the softmax: no operand is padded to a common width
+# and k_rope is read by index map, never repeated in HBM.  The kernels are
+# the three above, given the extra refs as ``rope``; the launchers differ
+# in their block specs.  The dkv launcher walks, per k_rope head and K
+# block, the Q blocks of each of its ``group`` heads in turn: dK and dV
+# are written once a head, dk_rope once the whole group is summed.
+
+_MLA_STATIC = ("scale", "causal", "tiles", "lane", "interpret", "precision",
+               "group")
+
+
+def _row_spec(lane, bq, at):
+    """Block spec of a row statistic (``[bn, 1, s]`` lane-major, else
+    ``[bn, s, 1]``) for a kernel whose grid step owns ``bq`` rows at
+    ``at(*grid ids) -> (head, block)``."""
+    if lane:
+        return pl.BlockSpec((1, 1, bq), lambda *g: (at(*g)[0], 0, at(*g)[1]))
+    return pl.BlockSpec((1, bq, 1), lambda *g: (*at(*g), 0))
+
+
+def _row_operands(lane, *stats):
+    return [x[:, None, :] if lane else x[:, :, None] for x in stats]
+
+
+def _mla_specs(tiles: Tiles, causal, n_kv, group):
+    """``(at_q(width), at_k(width, shared))``: block specs of a q-side and
+    of a k-side operand for the grid (heads, q blocks, kv blocks); a
+    ``shared`` operand is read at head ``b // group``."""
+    bq, bk, _ = tiles
+    k_block = _k_block_of(causal, bq, bk, n_kv)
+    heads = {False: lambda b: b, True: _kv_head_of(group)}
+    at_q = lambda w: pl.BlockSpec(  # noqa: E731
+        (1, bq, w), lambda b, i, j: (b, i, 0))
+    at_k = lambda w, shared=False: pl.BlockSpec(  # noqa: E731
+        (1, bk, w), lambda b, i, j: (heads[shared](b), k_block(i, j), 0))
+    return at_q, at_k
+
+
+@functools.partial(jax.jit, static_argnames=_MLA_STATIC)
+def _mla_fwd(q, qr, k, kr, v, *, scale, causal, tiles: Tiles, lane: bool,
+             interpret, precision=None, group=1):
+    bn, s_q, d = q.shape
+    d_r, d_v = qr.shape[-1], v.shape[-1]
+    bq, bk, _ = tiles
+    n_q, n_kv = s_q // bq, k.shape[1] // bk
+    at_q, at_k = _mla_specs(tiles, causal, n_kv, group)
+
+    def kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, *rest):
+        _fwd_kernel(None, q_ref, k_ref, v_ref, *rest, scale=scale,
+                    causal=causal, tiles=tiles, n_kv=n_kv, lane_lse=lane,
+                    precision=precision, rope=(qr_ref, kr_ref))
+
+    out, lse = pl.pallas_call(
+        kernel,
+        name="flash_mla_fwd",
+        grid=(bn, n_q, n_kv),
+        in_specs=[at_q(d), at_q(d_r), at_k(d), at_k(d_r, True), at_k(d_v)],
+        out_specs=[at_q(d_v), _row_spec(lane, bq, lambda b, i, j: (b, i))],
+        out_shape=[_sds(q, (bn, s_q, d_v), q.dtype),
+                   _sds(q, (bn, 1, s_q) if lane else (bn, s_q, 1),
+                        jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, d_v), jnp.float32),
+                        pltpu.VMEM((bq, _LANES), jnp.float32),
+                        pltpu.VMEM((bq, _LANES), jnp.float32)],
+        compiler_params=_compiler_params("fwd", tiles, max(d, d_v),
+                                         q.dtype.itemsize, d_r),
+        interpret=interpret,
+    )(q, qr, k, kr, v)
+    return out, (lse[:, 0, :] if lane else lse[:, :, 0])
+
+
+@functools.partial(jax.jit, static_argnames=_MLA_STATIC)
+def _mla_bwd_dq(q, qr, k, kr, v, do, lse, delta, *, scale, causal,
+                tiles: Tiles, lane: bool, interpret, precision=None, group=1):
+    """dq, dq_rope: grid (heads, q blocks, kv blocks)."""
+    bn, s_q, d = q.shape
+    d_r, d_v = qr.shape[-1], v.shape[-1]
+    bq, bk, _ = tiles
+    n_q, n_kv = s_q // bq, k.shape[1] // bk
+    at_q, at_k = _mla_specs(tiles, causal, n_kv, group)
+    row_spec = _row_spec(lane, bq, lambda b, i, j: (b, i))
+
+    def kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, do_ref, lse_ref,
+               delta_ref, dq_ref, dqr_ref, dq_acc, dqr_acc):
+        _bwd_dq_kernel(None, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       dq_ref, dq_acc, scale=scale, causal=causal,
+                       tiles=tiles, n_kv=n_kv, lane_lse=lane,
+                       precision=precision,
+                       rope=(qr_ref, kr_ref, dqr_ref, dqr_acc))
+
+    return pl.pallas_call(
+        kernel,
+        name="flash_mla_bwd_dq",
+        grid=(bn, n_q, n_kv),
+        in_specs=[at_q(d), at_q(d_r), at_k(d), at_k(d_r, True), at_k(d_v),
+                  at_q(d_v), row_spec, row_spec],
+        out_specs=[at_q(d), at_q(d_r)],
+        out_shape=[_sds(q, q.shape, q.dtype), _sds(q, qr.shape, qr.dtype)],
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
+                        pltpu.VMEM((bq, d_r), jnp.float32)],
+        compiler_params=_compiler_params("dq", tiles, max(d, d_v),
+                                         q.dtype.itemsize, d_r),
+        interpret=interpret,
+    )(q, qr, k, kr, v, do, *_row_operands(lane, lse, delta))
+
+
+@functools.partial(jax.jit, static_argnames=_MLA_STATIC)
+def _mla_bwd_dkv(q, qr, k, kr, v, do, lse, delta, *, scale, causal,
+                 tiles: Tiles, lane: bool, interpret, precision=None,
+                 group=1):
+    """dk, dv, dk_rope: grid (k_rope heads, kv blocks, q blocks of each
+    head of the group in turn)."""
+    bn, s_q, d = q.shape
+    d_r, d_v = qr.shape[-1], v.shape[-1]
+    bq, bk, sub = tiles
+    n_q, n_kv = s_q // bq, k.shape[1] // bk
+    q_block = _q_block_of(causal, bq, bk, n_q)
+    head = lambda b, i: b * group + _div(i, n_q)  # noqa: E731
+    q_at = lambda b, j, i: (  # noqa: E731
+        head(b, i), q_block(j, jax.lax.rem(i, jnp.int32(n_q))))
+    at_q = lambda w: pl.BlockSpec(  # noqa: E731
+        (1, bq, w), lambda b, j, i: (*q_at(b, j, i), 0))
+    own = lambda w: pl.BlockSpec(  # noqa: E731
+        (1, bk, w), lambda b, j, i: (head(b, i), j, 0))
+    shared = pl.BlockSpec((1, bk, d_r), lambda b, j, i: (b, j, 0))
+    if lane:    # [bn, s_q / sub, 1, sub]: see _flash_bwd_dkv
+        row_spec = pl.BlockSpec((1, bq // sub, 1, sub),
+                                lambda b, j, i: (*q_at(b, j, i), 0, 0))
+        rows = [x.reshape(bn, s_q // sub, 1, sub) for x in (lse, delta)]
+    else:
+        row_spec = _row_spec(False, bq, q_at)
+        rows = _row_operands(False, lse, delta)
+
+    def kernel(q_ref, qr_ref, k_ref, kr_ref, v_ref, do_ref, lse_ref,
+               delta_ref, dk_ref, dv_ref, dkr_ref, dk_acc, dv_acc, dkr_acc):
+        _bwd_dkv_kernel(None, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                        delta_ref, dk_ref, dv_ref, dk_acc, dv_acc,
+                        scale=scale, causal=causal, tiles=tiles, n_q=n_q,
+                        lane_lse=lane, precision=precision, group=group,
+                        rope=(qr_ref, kr_ref, dkr_ref, dkr_acc))
+
+    return pl.pallas_call(
+        kernel,
+        name="flash_mla_bwd_dkv",
+        grid=(kr.shape[0], n_kv, group * n_q),
+        in_specs=[at_q(d), at_q(d_r), own(d), shared, own(d_v), at_q(d_v),
+                  row_spec, row_spec],
+        out_specs=[own(d), own(d_v), shared],
+        out_shape=[_sds(q, k.shape, k.dtype), _sds(q, v.shape, v.dtype),
+                   _sds(q, kr.shape, kr.dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d_v), jnp.float32),
+                        pltpu.VMEM((bk, d_r), jnp.float32)],
+        compiler_params=_compiler_params("dkv", tiles, max(d, d_v),
+                                         q.dtype.itemsize, d_r),
+        interpret=interpret,
+    )(q, qr, k, kr, v, do, *rows)
+
+
+def _mla_common(q, qr, st: _Static) -> dict:
+    """What the three launchers take alike; the scale is over the whole
+    score width."""
+    return dict(scale=(q.shape[-1] + qr.shape[-1]) ** -0.5, causal=st.causal,
+                lane=_lse_lane_major(), interpret=st.interpret,
+                precision=st.precision, group=st.group)
+
+
+def _mla_fwd_res(q, qr, k, kr, v, st: _Static):
+    return _mla_fwd(q, qr, k, kr, v, tiles=st.tiling[0],
+                    **_mla_common(q, qr, st))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _mla(q, qr, k, kr, v, st):
+    return _mla_fwd_res(q, qr, k, kr, v, st)[0]
+
+
+def _mla_vjp_fwd(q, qr, k, kr, v, st):
+    out, lse = _mla_fwd_res(q, qr, k, kr, v, st)
+    return out, (q, qr, k, kr, v, out, lse)
+
+
+def _mla_vjp_bwd(st: _Static, res, do):
+    q, qr, k, kr, v, out, lse = res
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)
+    common = _mla_common(q, qr, st)
+    dq, dqr = _mla_bwd_dq(q, qr, k, kr, v, do, lse, delta,
+                          tiles=st.tiling[1], **common)
+    dk, dv, dkr = _mla_bwd_dkv(q, qr, k, kr, v, do, lse, delta,
+                               tiles=st.tiling[2], **common)
+    return dq, dqr, dk, dkr, dv
+
+
+_mla.defvjp(_mla_vjp_fwd, _mla_vjp_bwd)
+
+
+def mla_supported(q, q_rope, k, k_rope, v, block_q: int | None = None,
+                  block_k: int | None = None) -> bool:
+    """True when :func:`flash_mla` takes these shapes (else the caller
+    runs the XLA composition, tpuframe.ops.attention)."""
+    if any(x.ndim != 4 for x in (q, q_rope, k, k_rope, v)):
+        return False
+    b, s_q, n, d = q.shape
+    s_kv, d_r, d_v = k.shape[1], q_rope.shape[-1], v.shape[-1]
+    if (k.shape != (b, s_kv, n, d) or v.shape[:3] != (b, s_kv, n)
+            or q_rope.shape != (b, s_q, n, d_r)
+            or k_rope.shape[:2] != (b, s_kv) or k_rope.shape[3] != d_r
+            or n % k_rope.shape[2]):
+        return False
+    if max(d, d_v) > 256 or d_r > _LANES or s_q % 8 or s_kv % 8:
+        return False
+    return _tiles_whole(_tiling(s_q, s_kv, max(d, d_v), q.dtype.itemsize,
+                                block_q, block_k, d_r), s_q, s_kv)
+
+
+def flash_mla(q: jax.Array, q_rope: jax.Array, k: jax.Array,
+              k_rope: jax.Array, v: jax.Array, *, causal: bool = True,
+              block_q: int | None = None, block_k: int | None = None,
+              interpret: bool | None = None, precision=None) -> jax.Array:
+    """Flash attention whose scores have two terms and whose values have a
+    width of their own (the latent attention of a ``deepseek_v3`` block).
+
+    Args:
+      q, k: ``[batch, seq, heads, d]``, each head's own (the "nope" part).
+      q_rope: ``[batch, seq, heads, d_rope]``.
+      k_rope: ``[batch, seq_kv, rope_heads, d_rope]``; ``rope_heads``
+        divides ``heads`` (1: one rotary key a position for all heads) and
+        head ``h`` reads ``k_rope`` head ``h // (heads / rope_heads)`` where
+        it lies; its gradient sums the group inside the dkv kernel.
+      v: ``[batch, seq_kv, heads, d_v]``.
+      causal, block_q, block_k, interpret, precision: as :func:`flash_mha`.
+
+    Scores are ``(q.k + q_rope.k_rope) / sqrt(d + d_rope)``.  Returns
+    ``[batch, seq, heads, d_v]`` in q's dtype.
+    """
+    if not mla_supported(q, q_rope, k, k_rope, v, block_q, block_k):
+        raise ValueError(
+            f"flash_mla: shapes q={q.shape} q_rope={q_rope.shape} "
+            f"k={k.shape} k_rope={k_rope.shape} v={v.shape} do not tile; use "
+            f"tpuframe.ops.attention.multihead_attention for the fallback")
+    b, s_q, n, d = q.shape
+    s_kv, n_r = k.shape[1], k_rope.shape[2]
+    d_r, d_v = q_rope.shape[-1], v.shape[-1]
+    tiling = _tiling(s_q, s_kv, max(d, d_v), q.dtype.itemsize, block_q,
+                     block_k, d_r)
+    def grid(name, t):   # dkv walks each rope-key head's group of heads
+        n_q, n_k = s_q // t.block_q, s_kv // t.block_k
+        return (f"{b * n_r}x{n_k}x{n // n_r * n_q}" if name == "dkv"
+                else f"{b * n}x{n_q}x{n_k}")
+
+    said = "; ".join(
+        f"{name} q{t.block_q} k{t.block_k} sub{t.sub} grid {grid(name, t)}"
+        for name, t in zip(_KERNELS, tiling))
+    said += (f"; score products {d} + {d_r} deep, value products {d_v} wide, "
+             f"k_rope [{b}, {s_kv}, {n_r}, {d_r}] read by {n // n_r} heads")
+    interpret = kernel_impl.resolve_interpret("flash_mla_attention",
+                                              interpret, detail=said)
+    st = _Static(causal, tiling, interpret, precision, None, n // n_r)
+    out = _mla(_fold(q), _fold(q_rope), _fold(k), _fold(k_rope), _fold(v),
+               st)
+    return out.reshape(b, n, s_q, d_v).transpose(0, 2, 1, 3)
