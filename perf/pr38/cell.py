@@ -34,8 +34,11 @@ def main() -> int:
         def load_module(path):
             mod = load(path)
             if hasattr(mod, "Cell"):
+                # the training runner counts steps, the serving one requests
                 mod.Cell.check = lambda self: {
-                    "correct": False, "attempted": self.steps_in_window,
+                    "correct": False,
+                    "attempted": getattr(self, "steps_in_window",
+                                         getattr(self, "attempted", 0)),
                     "failed": 0, "compared": {}}
             return mod
         bench.load_module = load_module

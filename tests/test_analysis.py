@@ -559,6 +559,20 @@ def test_tf111_thread_outside_sanctioned_modules():
         assert source_lint.lint_source(src, sanctioned) == [], sanctioned
 
 
+def test_tf111_sanctions_the_timeline_watcher_and_only_there():
+    # The device watcher of obs/timeline.py waits on results its caller
+    # launched and never launches: sanctioned by path, with no suppression
+    # in the file; the same source anywhere else is a finding.
+    import tpuframe.obs.timeline as timeline_mod
+
+    src = open(timeline_mod.__file__).read()
+    assert "threading.Thread(" in src and "ok[TF111]" not in src
+    assert [f.rule for f in source_lint.lint_source(
+        src, "tpuframe/obs/timeline.py") if f.rule == "TF111"] == []
+    assert [f.rule for f in source_lint.lint_source(
+        src, "tpuframe/obs/metrics.py") if f.rule == "TF111"] == ["TF111"]
+
+
 def test_tf111_bare_thread_import_and_module_level():
     src = textwrap.dedent("""
         from threading import Thread

@@ -1,6 +1,7 @@
 """The host-span primitive of ``obs/timeline.py`` and the three seams that
 use it: the loader's worker, ``Scheduler.step`` and the flash kernels'
-names.  CPU only; a case reads the ring from the moment it starts, since
+names; the device's intervals (``device()``) and the ``clock`` record.
+CPU only; a case reads the ring from the moment it starts, since
 the ring is the process's and is never emptied."""
 
 import json
@@ -241,6 +242,166 @@ def test_step_timeline_exports_the_ring_as_chrome_json(tmp_path):
     assert evs[3]["args"] == {"why": "test"}
     assert evs[0]["tid"] == evs[1]["tid"] != evs[2]["tid"]
     assert doc["threadNames"][str(evs[2]["tid"])] == "t-export-worker"
+
+
+def test_clock_record_pairs_the_ring_clock_with_the_profilers(tmp_path):
+    if len(timeline._ring) < timeline.RING_SPANS:   # nothing dropped yet
+        assert timeline._ring[0].name == "clock"    # the module's first
+    t = time.monotonic()
+    with timeline.profile_trace(str(tmp_path / "trace")):
+        pass
+    (made,) = timeline.spans("clock", t0=t)   # one at the trace's start
+    assert set(made.args) == {"monotonic_ns", "trace_ns"}
+    assert made.t0 == made.t1 == made.args["monotonic_ns"] * 1e-9
+    offset = time.time_ns() - time.monotonic_ns()
+    assert abs(made.args["trace_ns"] - made.args["monotonic_ns"]
+               - offset) < 1e9
+    tl = StepTimeline(str(tmp_path / "t.json"))
+    tl.close()
+    clock = json.load(open(tl.path))["clock"]
+    newest = timeline.spans("clock")[-1].args
+    assert clock == {"ts0_monotonic_ns": round(tl._t0 * 1e9),
+                     "offset_ns": newest["trace_ns"] - newest["monotonic_ns"]}
+
+
+# ---------------------------------------------------------------------------
+# the device's intervals
+# ---------------------------------------------------------------------------
+
+def _wait_for(name: str, t: float, n: int) -> list:
+    """The ``name`` records from ``t`` on, once the watcher has made
+    ``n`` of them (10 s at most)."""
+    deadline = time.monotonic() + 10.0
+    while (len(timeline.spans(name, t0=t)) < n
+           and time.monotonic() < deadline):
+        time.sleep(0.005)
+    return timeline.spans(name, t0=t)
+
+
+class _Result:
+    """An output of a fake asynchronous step: ready at ``ready_at``."""
+
+    def __init__(self, ready_at: float):
+        self.ready_at = ready_at
+
+    def block_until_ready(self):
+        time.sleep(max(self.ready_at - time.monotonic(), 0.0))
+        return self
+
+
+def test_async_step_makes_one_ordered_device_interval_a_call():
+    from tpuframe.train import _HarnessStep
+
+    queue_end = [time.monotonic()]
+
+    def step(state, batch):
+        """200 ms of device work a call, run in order after the last."""
+        queue_end[0] = max(queue_end[0], time.monotonic()) + 0.2
+        return state + 1, {"count": 3, "loss": _Result(queue_end[0])}
+
+    wrapped = _HarnessStep(step)
+    t = time.monotonic()
+    state, ready = 0, []
+    for i in range(5):
+        state, metrics = wrapped(state, None)
+        ready.append(metrics["loss"].ready_at)
+        if i == 2:
+            # the queue runs dry
+            time.sleep(queue_end[0] - time.monotonic() + 0.2)
+    got = _wait_for("device.step", t, 5)
+    time.sleep(0.05)
+    assert timeline.spans("device.step", t0=t) == got    # and no more
+    dispatch = timeline.spans("train.dispatch", t0=t)
+    assert [s.args["step"] for s in got] == [0, 1, 2, 3, 4]
+    assert [s.args["step"] for s in dispatch] == [0, 1, 2, 3, 4]
+    me = threading.current_thread().name
+    for k, (d, s) in enumerate(zip(dispatch, got)):
+        assert (s.thread, s.parent, s.args["by"]) == ("device", None, me)
+        assert s.t0 >= d.t1 and s.t1 >= ready[k] and s.t1 >= s.t0
+    for a, b in zip(got, got[1:]):
+        assert b.t0 >= a.t1                  # in order, no overlap
+    # queued behind the one before, it starts where that one ends; after
+    # the queue ran dry, at its launch (the dispatch span's end)
+    assert got[1].t0 == got[0].t1 and got[2].t0 == got[1].t1
+    assert got[3].t0 == dispatch[3].t1 > got[2].t1
+
+
+def _watchers() -> list:
+    return [th for th in threading.enumerate()
+            if th.name == "tpuframe-device-watch"]
+
+
+def test_stop_watcher_records_what_it_was_handed_and_joins():
+    t = time.monotonic()
+    timeline.device("t.stop", t, _Result(t + 0.05))
+    assert len(_watchers()) == 1
+    timeline.stop_watcher(timeout=10.0)
+    assert _watchers() == []
+    (made,) = timeline.spans("device.t.stop", t0=t)
+    assert made.t1 >= t + 0.05
+    timeline.device("t.stop", time.monotonic(), _Result(0.0))
+    assert len(_watchers()) == 1            # the next one starts it again
+    assert len(_wait_for("device.t.stop", t, 2)) == 2
+    timeline.stop_watcher()
+
+
+class _Failed:
+    def __init__(self, error: Exception):
+        self.error = error
+
+    def block_until_ready(self):
+        raise self.error
+
+
+@pytest.mark.parametrize("error", [
+    RuntimeError("the step failed on the device"),
+    ValueError("a result that cannot be waited on")])
+def test_a_failed_output_makes_no_interval_and_the_watcher_goes_on(error):
+    t = time.monotonic()
+    timeline.device("t.failed", t, _Failed(error))
+    timeline.device("t.next", time.monotonic(), _Result(0.0))
+    assert len(_wait_for("device.t.next", t, 1)) == 1
+    assert timeline.spans("device.t.failed", t0=t) == []
+    assert len(_watchers()) == 1
+
+
+def test_a_watcher_that_ended_is_replaced_by_the_next_device_call(
+        monkeypatch):
+    class _Fatal:
+        def block_until_ready(self):
+            raise SystemExit   # no Exception: it ends the watcher's thread
+
+    monkeypatch.setattr(threading, "excepthook", lambda args: None)
+    timeline.stop_watcher()
+    timeline.device("t.fatal", time.monotonic(), _Fatal())
+    deadline = time.monotonic() + 10.0
+    while _watchers() and time.monotonic() < deadline:
+        time.sleep(0.005)
+    assert _watchers() == []
+    t = time.monotonic()
+    timeline.device("t.after", t, _Result(0.0))
+    assert len(_wait_for("device.t.after", t, 1)) == 1
+    timeline.stop_watcher()
+
+
+def test_device_records_leave_sched_step_self_time_alone():
+    # the device lane nests under no host span, so an interval recorded
+    # while a step's spans are open changes no span's self time
+    t = time.monotonic()
+    for _ in range(3):
+        with timeline.span("sched.step"):
+            with timeline.span("sched.admit"):
+                time.sleep(0.002)
+            timeline.device("t.self", time.monotonic(), _Result(0.0))
+            time.sleep(0.002)
+    devs = _wait_for("device.t.self", t, 3)
+    assert len(devs) == 3
+    assert all((d.thread, d.parent) == ("device", None) for d in devs)
+    steps = timeline.spans("sched.step", t0=t)
+    admits = timeline.spans("sched.admit", t0=t)
+    own = timeline.self_ms("sched.step", t)
+    assert own == pytest.approx([s.ms - a.ms for s, a in zip(steps, admits)],
+                                abs=1e-9)
 
 
 # ---------------------------------------------------------------------------

@@ -68,7 +68,8 @@ Each rule institutionalizes a defect class rounds 4-5 found by hand:
          still applies it) and exempt.
   TF111  background thread outside the sanctioned modules — a
          ``threading.Thread`` created anywhere but ``ckpt/``,
-         ``data/pipeline.py``, ``obs/heartbeat.py`` or ``launch/``.
+         ``data/pipeline.py``, ``obs/heartbeat.py``, ``obs/timeline.py``
+         (its device watcher waits and never launches) or ``launch/``.
          Background threads issuing collectives is the ordering hazard
          ``ckpt/checkpoint.py`` documents (a worker's collective
          interleaving with the main loop's compiled steps); the
@@ -336,9 +337,11 @@ _WU_OPTIMIZER_RECEIVERS = {"tx", "optimizer", "opt", "inner_tx"}
 # sanctioned modules are exactly the ones audited to never do that
 # (ckpt's worker polls sidecar files instead of a barrier; the prefetch
 # thread only device_puts; heartbeat only reads a counter; launch runs
-# before any backend exists).
+# before any backend exists; the timeline's device watcher only waits on
+# results the caller launched, and never launches).
 _THREAD_SANCTIONED_PARTS = ("ckpt/", "data/pipeline.py",
-                            "obs/heartbeat.py", "launch/")
+                            "obs/heartbeat.py", "obs/timeline.py",
+                            "launch/")
 
 # TF112: receivers whose ``.emit("type", ...)`` is the structured event
 # log — the in-tree import aliases for ``tpuframe.obs.events``.  A string
@@ -875,7 +878,8 @@ def _tf_call_rules(ctx: FileContext, node, fn):
         ctx.emit("TF111", node,
                  f"{callee}() outside the sanctioned background-work "
                  f"modules (ckpt/, data/pipeline.py, "
-                 f"obs/heartbeat.py, launch/) — a background thread "
+                 f"obs/heartbeat.py, obs/timeline.py, launch/) — a "
+                 f"background thread "
                  f"that issues collectives interleaves with the main "
                  f"loop's compiled steps (the ordering hazard "
                  f"ckpt/checkpoint.py documents); if the thread "

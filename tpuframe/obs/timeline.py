@@ -15,14 +15,41 @@ process-wide bounded ring on ``time.monotonic`` — the clock of
 ``Scheduler`` and of ``Request.arrival_t`` — that ``spans()``,
 ``durations_ms()`` and ``self_ms()`` read back, with or without a
 profiler.  ``StepTimeline`` exports the ring as Chrome JSON.
+
+The device's side is ``device()``: for each training step the ring also
+holds a ``device.<name>`` interval, on the same clock, on a lane
+(``thread``) of its own called ``"device"``.  The device runs its queue
+in order, so an interval starts at its launch or at the end of the one
+before, whichever is later, and ends when a watcher thread's wait on one
+of its outputs returns: on a v5e chip 1.0-3.3 ms after the step's last
+program.  An interval says that a step was outstanding, not that the
+device was busy: time the device idles inside it (a wait on an input
+transfer, say) is not seen.  Time that no interval covers is time the
+host's queue of steps had run dry.  The serving engine records none:
+from the host, its launch reads about 1.1 ms after the device began and
+its fetch about 2 ms after the device ended, which would count a fifth
+of its window busy that the device spent idle.
+
+A ``clock`` record (``mark_clock``; once a process, again whenever
+``profile_trace`` starts) pairs ``time.monotonic_ns()`` with
+``time.time_ns()``.  The profiler stamps ``TraceAnnotation``s and device
+ops on ``time.time_ns``'s clock (``CLOCK_REALTIME``): an event of an
+``.xplane.pb`` read through ``jax.profiler.ProfileData`` starts at the
+``profile_start_time`` stat of its ``Task Environment`` plane plus its
+``start_ns``, and a ring time ``t`` is ``1e9 * t + trace_ns -
+monotonic_ns`` there.  On a v5e chip a ``tpuframe:engine.decode.fetch``
+or ``tpuframe:train.dispatch`` annotation so placed starts 1.4-2.7 us
+before its ring span's ``t0`` (the annotation opens first).
 """
 
 from __future__ import annotations
 
+import atexit
 import collections
 import contextlib
 import itertools
 import os
+import queue
 import threading
 import time
 from typing import NamedTuple
@@ -66,6 +93,7 @@ def profile_trace(log_dir: str):
     """Trace a window of steps to ``log_dir`` (viewable in
     TensorBoard/perfetto; the analog of one Horovod timeline segment)."""
     jax.profiler.start_trace(log_dir)
+    mark_clock()
     try:
         yield
     finally:
@@ -83,10 +111,15 @@ def profile_trace(log_dir: str):
 # to outlive the window (20 s), the traced seconds (3) and the longest
 # drain the runner allows (60): 83 s * 415 = 34.5k spans.  2**16 keeps
 # 158 s of that traffic (the 20 s lead-in too); a training loop closes
-# under 40 spans/s.
+# under 40 spans/s, one device.step interval a step among them.  At 3.9 ms
+# steps the cell closes about 1,600 spans/s (222 steps and 10.8 requests
+# a second on a v5e chip): 41 s of ring, which holds a traced run's
+# untraced 12 s, its 3 traced seconds and the few seconds its drain steps
+# (the profiler's write-out steps nothing), but not the longest drain.
 RING_SPANS = 1 << 16
 
 ANNOTATION_PREFIX = "tpuframe:"
+DEVICE_THREAD = "device"   # the lane of the device.* intervals
 
 
 class Span(NamedTuple):
@@ -165,6 +198,94 @@ def record(name: str, t0: float, t1: float, **args) -> None:
                       None, args, next(_ids)))
 
 
+def mark_clock() -> None:
+    """A ``clock`` record: ``monotonic_ns`` (the ring's clock) beside
+    ``trace_ns`` (the profiler's, ``time.time_ns``), read together, so
+    that a ring export and a profiler trace can be overlaid."""
+    mono_ns, trace_ns = time.monotonic_ns(), time.time_ns()
+    record("clock", 1e-9 * mono_ns, 1e-9 * mono_ns, monotonic_ns=mono_ns,
+           trace_ns=trace_ns)
+
+
+class _DeviceLane:
+    """The device's queue as the ring sees it: the end of the last
+    ``device.*`` interval, and the watcher that waits on the outputs it
+    was handed, in order.  The watcher only waits: it launches nothing and
+    issues no collective."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._end = float("-inf")
+        self._queue: queue.SimpleQueue | None = None
+        self._thread: threading.Thread | None = None
+
+    def _close(self, name: str, t_launch: float, t_ready: float,
+               args: dict) -> None:
+        with self._lock:
+            t0 = max(t_launch, self._end)
+            t1 = max(t_ready, t0)
+            self._end = t1
+            _ring.append(Span("device." + name, t0, t1, DEVICE_THREAD, None,
+                              args, next(_ids)))
+
+    def watch(self, name: str, t_launch: float, output, args: dict) -> None:
+        with self._lock:
+            if self._thread is None:
+                self._queue = queue.SimpleQueue()
+                self._thread = threading.Thread(
+                    target=self._run, args=(self._queue,), daemon=True,
+                    name="tpuframe-device-watch")
+                self._thread.start()
+            self._queue.put((name, t_launch, output, args))
+
+    def _run(self, work: queue.SimpleQueue) -> None:
+        try:
+            for name, t_launch, output, args in iter(work.get, None):
+                try:
+                    output.block_until_ready()
+                except Exception:  # noqa: BLE001 — its caller sees it
+                    continue       # a failed step makes no interval
+                self._close(name, t_launch, time.monotonic(), args)
+        finally:
+            # a watcher that ends for any cause lets the next watch() start
+            # another, rather than fill a queue nobody drains
+            with self._lock:
+                if self._queue is work:
+                    self._thread = self._queue = None
+
+    def stop(self, timeout: float) -> None:
+        with self._lock:
+            thread, work = self._thread, self._queue
+            self._thread = self._queue = None
+        if thread is not None:
+            work.put(None)
+            thread.join(timeout)
+
+
+_lane = _DeviceLane()
+
+
+def stop_watcher(timeout: float = 1.0) -> None:
+    """Let the watcher record what it was handed, then end its thread: a
+    run's threads end with the run, and at exit before the backend goes.
+    The next ``device()`` starts it again."""
+    _lane.stop(timeout)
+
+
+atexit.register(stop_watcher)
+mark_clock()
+
+
+def device(name: str, t_launch: float, output, **args) -> None:
+    """Record the work launched at ``t_launch`` (``time.monotonic``, read
+    when the executable's call returned) as a ``device.<name>`` interval on
+    the ``"device"`` lane, which a watcher thread closes when ``output`` is
+    ready: one result of the work that the next call does not donate.
+    ``args`` are the record's; ``by`` names the launching thread."""
+    args["by"] = threading.current_thread().name
+    _lane.watch(name, t_launch, output, args)
+
+
 def last(name: str) -> Span | None:
     """The span this thread closed last, if its name is ``name``: how a
     caller reads the duration of the span its callee just made."""
@@ -204,8 +325,11 @@ class StepTimeline:
     SPMD the interesting host phases are coarser: data wait (input pipeline),
     step submit/execute, eval, checkpoint.  ``close`` writes every span the
     ring still holds since this object was made — the harness's phases and
-    the loader's worker alike, one ``tid`` per thread — as a Chrome
-    ``chrome://tracing`` / Perfetto JSON array.
+    the loader's worker alike, one ``tid`` per thread, the ``device.*``
+    intervals on a lane of their own — as a Chrome ``chrome://tracing`` /
+    Perfetto JSON array.  Its ``clock`` key places the export on the
+    profiler's clock: ``ts`` 0 is ``ts0_monotonic_ns``, and a profiler time
+    is a monotonic one plus ``offset_ns``.
 
     Enable via ``TPUFRAME_TIMELINE=/path/trace.json`` (env parity with
     ``HOROVOD_TIMELINE=file.json``) — the harness wires the phases.
@@ -246,7 +370,16 @@ class StepTimeline:
             else:
                 ev.update(ph="X", dur=(s.t1 - s.t0) * 1e6)
             events.append(ev)
+        clocks = spans("clock")
+        if not clocks:            # the ring has outlived its clock record
+            mark_clock()
+            clocks = spans("clock")
+        pair = clocks[-1].args
         with open(self.path, "w") as f:
             json.dump({"traceEvents": events, "displayTimeUnit": "ms",
-                       "threadNames": {str(v): k for k, v in tids.items()}},
+                       "threadNames": {str(v): k for k, v in tids.items()},
+                       "clock": {
+                           "ts0_monotonic_ns": round(self._t0 * 1e9),
+                           "offset_ns": pair["trace_ns"]
+                           - pair["monotonic_ns"]}},
                       f)

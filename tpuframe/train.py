@@ -45,6 +45,7 @@ from tpuframe.obs import exporter as exporter_lib
 from tpuframe.obs import flight as flight_lib
 from tpuframe.obs import goodput as goodput_lib
 from tpuframe.obs import metrics as obs_metrics
+from tpuframe.obs import timeline as timeline_lib
 from tpuframe.obs.timeline import span
 from tpuframe.parallel import bootstrap
 from tpuframe.resilience import faults as faults_lib
@@ -175,11 +176,15 @@ class _HarnessStep:
     trace the flash forwards the block remat keeps (``mem.count_kept``;
     the call reuses the trace), and that hands each new ``model_state``
     to ``publish`` where the model has the hook (a reference, never a
-    transfer); everything else is the step's own."""
+    transfer); everything else is the step's own.  Each call is a
+    ``train.dispatch`` span, and the device's run of the step a
+    ``device.step`` interval (``timeline.device``, watched on a leaf of
+    the step's metrics, which no later call donates)."""
 
     def __init__(self, step, publish=None):
         self._step, self._publish = step, publish
         self._counted = False
+        self._calls = 0
 
     def __call__(self, state, batch):
         if not self._counted:
@@ -188,7 +193,14 @@ class _HarnessStep:
                 mem.count_kept(self._step, state, batch)
             except Exception:  # noqa: BLE001 — a count, never a failed step
                 pass
-        state, metrics = self._step(state, batch)
+        n, self._calls = self._calls, self._calls + 1
+        with span("train.dispatch", step=n):
+            state, metrics = self._step(state, batch)
+        leaf = next((x for x in jax.tree.leaves(metrics)
+                     if hasattr(x, "block_until_ready")), None)
+        if leaf is not None:   # launched when the dispatch span closed
+            timeline_lib.device("step", timeline_lib.last(
+                "train.dispatch").t1, output=leaf, step=n)
         if self._publish is not None:
             self._publish(state.model_state)
         return state, metrics
@@ -913,6 +925,7 @@ def _train_impl(cfg: TrainConfig, threads: contextlib.ExitStack, *,
     h = build_harness(cfg)
     threads.callback(h.train_loader.close)
     threads.callback(h.eval_loader.close)
+    threads.callback(timeline_lib.stop_watcher)
     # An elastic resize may have rescaled global_batch/base_lr inside
     # build_harness — everything below reads the config the harness was
     # actually built with.
